@@ -72,9 +72,10 @@ def make_place_fn(mesh=None, layouts=None) -> Callable[[Any], Any]:
     """One placement path for every feed consumer.
 
     ``mesh`` None → plain ``jax.device_put`` (into ``layouts`` — a pytree
-    of ``jax.experimental.layout.Layout`` matching the batch — when
-    given, so the transfer lands in the layout the compiled step expects
-    and XLA inserts no input relayout copy).  With a mesh: single
+    of ``jax.experimental.layout.Format`` matching the batch, as
+    :func:`input_layouts_for` returns — when given, so the transfer
+    lands in the layout the compiled step expects and XLA inserts no
+    input relayout copy).  With a mesh: single
     process shards the leading axis (``shard_batch``); multi-process
     assembles the global array view (``globalize_batch``).
     """
@@ -93,32 +94,33 @@ def make_place_fn(mesh=None, layouts=None) -> Callable[[Any], Any]:
 
 
 def input_layouts_for(jitted, args, argnum: int = 1):
-    """The compiled input layouts of ``jitted``'s ``argnum``-th argument.
+    """The compiled input formats (layout + sharding) of ``jitted``'s
+    ``argnum``-th argument, as a pytree of
+    ``jax.experimental.layout.Format``.
 
     ``args`` may be real arrays or ``jax.ShapeDtypeStruct`` trees (no
     data needed — lowering is abstract).  Feeding ``device_put`` these
-    layouts makes the host→device transfer deliver device-native tiling
+    formats makes the host→device transfer deliver device-native tiling
     directly, so XLA stops inserting the input relayout copy that the
-    ROOFLINE layout-copy row charges ~1.1 ms/step to.  Returns None when
-    the runtime doesn't expose layouts (older jax) or lowering fails —
-    callers fall back to plain ``device_put``.
+    ROOFLINE layout-copy row charges ~1.1 ms/step to.  A failure to
+    lower or compile raises: it would fail the real dispatch too.
     """
-    try:
-        compiled = jitted.lower(*args).compile()
-        in_args, _kwargs = compiled.input_layouts
-        return in_args[argnum]
-    except Exception as e:  # noqa: BLE001 — layout feed is best-effort
-        logger.debug("input_layouts_for: falling back to plain put (%r)", e)
-        return None
+    in_args, _kwargs = jitted.lower(*args).compile().input_formats
+    return in_args[argnum]
 
 
 def shape_structs(tree):
     """Pytree of arrays → matching ``jax.ShapeDtypeStruct`` tree (for
-    abstract lowering in :func:`input_layouts_for`)."""
+    abstract lowering in :func:`input_layouts_for`).  Device arrays keep
+    their sharding, so params committed to one device (a pinned replica)
+    lower for THAT device and the formats place the batch beside them."""
     import jax
 
     return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), tree
+        lambda x: jax.ShapeDtypeStruct(
+            np.shape(x), x.dtype, sharding=getattr(x, "sharding", None)
+        ),
+        tree,
     )
 
 

@@ -141,8 +141,8 @@ def _load_record_image(rec: Dict) -> np.ndarray:
         # half the flip-augmented epoch on mismatched targets.  The
         # render is deterministic per (uri, flipped, seed), so the LRU
         # key is exactly that triple; at ~17 ms/render (noise
-        # generation) on a 1-core box re-rendering was the e2e eval
-        # bottleneck once the relay pipeline overlapped (disk-backed
+        # generation, one core) re-rendering was the e2e eval
+        # bottleneck once the eval pipeline overlapped (disk-backed
         # datasets get the same effect from the OS page cache).
         # Read-only downstream: prepare_image copies.
         key = (rec["image"], bool(rec.get("flipped")), rec["synthetic_seed"])

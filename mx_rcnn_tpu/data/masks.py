@@ -8,7 +8,7 @@ rasterized ONCE, at roidb-load/batch time, into a small M×M bitmap over
 its own gt box ("box frame"), and the in-graph target op
 (``ops/mask_targets.py::crop_resize_masks``) bilinearly resamples that
 bitmap under each matched roi's S×S grid.  A (B, G, M, M) uint8 tensor
-replaces (B, G, H, W) — ~100× less HBM/relay traffic at M=64 — and the
+replaces (B, G, H, W) — ~100× less HBM and host→device traffic at M=64 — and the
 device-side crop is two matmuls per roi instead of gathers.
 
 Supported ``segmentation`` record formats (the COCO instance formats):

@@ -34,6 +34,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mx_rcnn_tpu.ops.pallas import out_struct
+
 BLOCK = 128
 
 
@@ -204,7 +206,7 @@ def nms_mask_sorted_pallas(
             chunk=chunk,
             max_keep=int(max_keep),
         ),
-        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
+        out_shape=out_struct((1, n_pad), jnp.float32, coords, keep0),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),

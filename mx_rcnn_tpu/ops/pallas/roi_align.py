@@ -34,8 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from mx_rcnn_tpu.ops.pallas import out_struct
 
 
 def _interp_matrix(lo_f, whi, size: int, nbins: int, s: int):
@@ -213,66 +212,88 @@ def _cblk(c: int, largest: int = 512) -> int:
     return c
 
 
-# Per-step working-set budget.  The flagship bf16 C4 configs validated
-# on a real v5e hold 5.6 MB (fwd) / 6.9 MB (bwd) under this accounting
-# and compile+run; the historical over-commit (old 512-cap bwd,
-# ~13.7 MB accounted) failed scoped-VMEM allocation.  8 MB keeps every
-# hardware-validated config resident with margin for Mosaic's
-# double-buffering of the streamed g/out blocks (~1 MB) inside the
-# chip's ~16 MB.
-_VMEM_BUDGET = 8 * 2**20
-
 _RBLK = 8  # rois per grid step; M/K tiles go 14 → 112 of the MXU's 128
 
-
-def _resident_bytes(
-    h: int, w: int, blk: int, esize: int, pooled_max: int = 14
-) -> int:
-    """Worst-case VMEM bytes the blocked kernels hold per step: the
-    resident (H, W, blk) slab (feat dtype) or f32 accumulator PLUS the
-    f32 stacked roi-block intermediate — fwd's cols (RB·PW, H, blk) or
-    bwd's t_blk (H, RB·PW, blk), bounded by max(h, w) on the spatial
-    axis.  The pre-blocking heuristic counted only the slab; the
-    stacked intermediate is the same order of magnitude, so omitting it
-    would re-create exactly the silent over-commit the historical
-    512-cap comment records (fit check passes, Mosaic scoped-VMEM
-    allocation fails).  ``esize``: feat dtype bytes for the fwd slab; the
-    bwd accumulator is always f32, so bwd callers pass 4.
-
-    The stacked intermediate's spatial axis is H in both passes (the
-    kernels contract W on the stacked side), so portrait buckets
-    (H > W) genuinely hold the larger intermediate and size down to a
-    smaller cblk — that is the honest cost of the fixed W-stacked axis
-    order, not over-counting.
-
-    The stacked intermediate is ALWAYS f32: tpu.matmul requires a
-    32-bit accumulator, so even bf16 graphs materialize fwd cols /
-    bwd t_blk in f32 before any cast."""
-    pooled_stack = _RBLK * pooled_max
-    return (h * w * esize + pooled_stack * h * 4) * blk
+# What one grid step may hold in VMEM, and what the kernels tell Mosaic
+# they need (``vmem_limit_bytes``): Mosaic's own default scoped limit is
+# 16 MiB of the v5e's 128 MiB, and the f32 backward at the flagship C4 map
+# (17.5 MiB) and at FPN P3 (16.0 MiB) does not fit it.  24 MiB keeps
+# resident exactly the maps the hardware rounds ran resident (C4 in both
+# orientations, P3-P5 at 7x7) and keeps P3 at 14x14 and P2 on the
+# streaming kernel.
+_VMEM_BUDGET = 24 * 2**20
 
 
-def fits_vmem(h: int, w: int, c: int, pooled_max: int = 14) -> bool:
-    """True iff some channel block keeps the blocked kernels' per-step
-    working set (slab + stacked roi-block intermediate) in budget —
-    checked for the BACKWARD's f32 accumulator (the larger of the two
-    passes), so a map dispatched resident never OOMs in its grad.
-    ``pooled_max``: max(PH, PW) of the pooled output — sizes the stacked
-    roi-block intermediate (ADVICE r4: was hardcoded 14)."""
-    return (
-        _resident_bytes(h, w, _cblk(c, largest=128), 4, pooled_max)
-        <= _VMEM_BUDGET
-    )
+def _pad(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-def _cblk_fit(
-    h: int, w: int, c: int, largest: int, esize: int = 4, pooled_max: int = 14
-) -> int:
-    """Largest channel block whose per-step working set fits the budget."""
+def _sublanes(esize: int) -> int:
+    """Rows of one (sublane, 128-lane) tile: 8 of 32-bit, 16 of bf16 —
+    the second-minor dim of every VMEM block pads to it."""
+    return 8 * (4 // esize)
+
+
+def _fwd_bytes(h: int, w: int, blk: int, esize: int, pooled) -> int:
+    """Upper bound on the scoped VMEM Mosaic allocates for one forward
+    grid step, calibrated against the chip's compiler (its refusals name
+    the size; 32 configurations: C4 landscape/portrait, P3, 38x50; f32
+    and bf16; cblk 128/256; 7x7 and 14x14 — ISSUE 21).
+
+    Exact part: the pipeline DOUBLE-buffers every block, the resident
+    (H, W, blk) feature slab included, and pads second-minor dims to
+    the sublane tile.  Bounded part: Mosaic's internal scratch (the f32
+    stacked ``cols`` intermediate, operand relayouts, HIGHEST-precision
+    splits) measured 1.0-2.6x ``cols``."""
+    ph, pw = pooled
+    sub = _sublanes(esize)
+    feat = h * _pad(w, sub) * blk * esize
+    out = _RBLK * ph * _pad(pw, sub) * blk * esize
+    cols = _RBLK * pw * _pad(h, 8) * blk * 4
+    return 2 * (feat + out) + 26 * cols // 10
+
+
+def _bwd_bytes(h: int, w: int, blk: int, gsize: int, pooled) -> int:
+    """Backward twin of :func:`_fwd_bytes` (same calibration): the
+    double-buffered f32 (W, H, blk) accumulator and cotangent block,
+    plus internal scratch measured 0.7-1.6x (stacked ``t_blk`` + the
+    ``d`` product).  ``gsize``: cotangent dtype bytes."""
+    ph, pw = pooled
+    acc = w * _pad(h, 8) * blk * 4
+    g = _RBLK * ph * _pad(pw, _sublanes(gsize)) * blk * gsize
+    t_blk = h * _RBLK * pw * blk * 4
+    return 2 * (acc + g) + 16 * (t_blk + acc) // 10
+
+
+def fits_vmem(h: int, w: int, c: int, pooled, esize: int) -> bool:
+    """True iff the resident kernels hold this map within budget at
+    their smallest channel block, forward AND backward — so a map
+    dispatched resident never fails to compile in its grad.  ``esize``:
+    feature dtype bytes."""
+    blk = _cblk(c, largest=128)
+    return max(
+        _fwd_bytes(h, w, blk, esize, pooled),
+        _bwd_bytes(h, w, blk, esize, pooled),
+    ) <= _VMEM_BUDGET
+
+
+def _cblk_fit(step_bytes, c: int, largest: int = 256) -> int:
+    """Largest channel block whose per-step VMEM (``step_bytes(blk)``)
+    fits the budget.  Capped at 256, the largest the hardware rounds
+    ran; whether 512 pays where it fits is not measured."""
     blk = _cblk(c, largest)
-    while blk > 128 and _resident_bytes(h, w, blk, esize, pooled_max) > _VMEM_BUDGET:
+    while blk > 128 and step_bytes(blk) > _VMEM_BUDGET:
         blk //= 2
     return blk
+
+
+def _compiler_params(step_bytes: int, semantics) -> pltpu.CompilerParams:
+    # a quarter above the bound: the limit is a cap, not an allocation,
+    # and maps the calibration never saw should compile too
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=step_bytes * 5 // 4,
+    )
 
 
 def _pad_rois(rois, rblk):
@@ -282,7 +303,7 @@ def _pad_rois(rois, rblk):
     (length floors at 1 in _sample_coords), and their outputs are
     sliced away / their cotangents are structurally zero."""
     r = rois.shape[1]
-    rp = -(-r // rblk) * rblk
+    rp = _pad(r, rblk)
     rois_t = rois.astype(jnp.float32).transpose(0, 2, 1)
     if rp != r:
         rois_t = jnp.pad(rois_t, ((0, 0), (0, 0), (0, rp - r)))
@@ -292,12 +313,8 @@ def _pad_rois(rois, rblk):
 def _roi_align_fwd_impl(feat, rois, pooled, scale, s, interpret):
     b, hf, wf, c = feat.shape
     r = rois.shape[1]
-    # 256 cap: the blocked (RB·PW, H, CB) f32 cols intermediate shares
-    # VMEM with the resident feature slab
-    cblk = _cblk_fit(
-        hf, wf, c, largest=256, esize=feat.dtype.itemsize,
-        pooled_max=max(pooled),
-    )
+    esize = feat.dtype.itemsize
+    cblk = _cblk_fit(lambda blk: _fwd_bytes(hf, wf, blk, esize, pooled), c)
     rois_t, rp = _pad_rois(rois, _RBLK)
     grid = (b, c // cblk, rp // _RBLK)
     kernel = partial(_fwd_kernel, pooled=pooled, s=s, scale=scale, rblk=_RBLK)
@@ -305,8 +322,9 @@ def _roi_align_fwd_impl(feat, rois, pooled, scale, s, interpret):
         kernel,
         # every fwd grid step writes a disjoint out block — declaring all
         # three axes parallel lets Mosaic pipeline/overlap grid steps
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
+        compiler_params=_compiler_params(
+            _fwd_bytes(hf, wf, cblk, esize, pooled),
+            ("parallel", "parallel", "parallel"),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -322,7 +340,9 @@ def _roi_align_fwd_impl(feat, rois, pooled, scale, s, interpret):
                 lambda bb, cb, rr, rois_ref: (bb, rr, 0, 0, cb),
             ),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, rp, pooled[0], pooled[1], c), feat.dtype),
+        out_shape=out_struct(
+            (b, rp, pooled[0], pooled[1], c), feat.dtype, rois_t, feat
+        ),
         interpret=interpret,
     )(rois_t, feat)
     return out[:, :r] if rp != r else out
@@ -331,9 +351,8 @@ def _roi_align_fwd_impl(feat, rois, pooled, scale, s, interpret):
 def _roi_align_bwd_impl(feat_shape, feat_dtype, rois, g, pooled, scale, s, interpret):
     b, hf, wf, c = feat_shape
     r = rois.shape[1]
-    # 256 cap: the f32 accumulator block + the stacked t intermediate
-    # must fit the scoped-VMEM budget (512 OOMs at 600x1000/stride-16)
-    cblk = _cblk_fit(hf, wf, c, largest=256, esize=4, pooled_max=max(pooled))
+    gsize = g.dtype.itemsize
+    cblk = _cblk_fit(lambda blk: _bwd_bytes(hf, wf, blk, gsize, pooled), c)
     rois_t, rp = _pad_rois(rois, _RBLK)
     if rp != r:
         g = jnp.pad(g, ((0, 0), (0, rp - r)) + ((0, 0),) * (g.ndim - 2))
@@ -343,8 +362,9 @@ def _roi_align_bwd_impl(feat_shape, feat_dtype, rois, g, pooled, scale, s, inter
         kernel,
         # batch/channel blocks are independent; the roi axis carries the
         # accumulator read-modify-write and must stay sequential
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=_compiler_params(
+            _bwd_bytes(hf, wf, cblk, gsize, pooled),
+            ("parallel", "parallel", "arbitrary"),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -361,7 +381,7 @@ def _roi_align_bwd_impl(feat_shape, feat_dtype, rois, g, pooled, scale, s, inter
             ),
         ),
         # (B, W, H, C): the kernel accumulates transposed (see docstring)
-        out_shape=jax.ShapeDtypeStruct((b, wf, hf, c), jnp.float32),
+        out_shape=out_struct((b, wf, hf, c), jnp.float32, rois_t, g),
         interpret=interpret,
     )(rois_t, g)
     return out.swapaxes(1, 2).astype(feat_dtype)

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mx_rcnn_tpu.ops.pallas import out_struct
 from mx_rcnn_tpu.ops.pallas.roi_align import _sample_coords
 
 
@@ -207,6 +208,16 @@ def _bwd_kernel(rois_ref, g_ref, dfeat_ref, *, pooled, s, scale, hblk,
     jax.lax.fori_loop(0, rblk, body, 0)
 
 
+# Both kernels' blocks are sized by budget (_pick_hblk: 2 MiB of feature
+# rows; _pick_rblk: 4 MiB of roi-block accumulator), so what a grid step
+# holds barely depends on the map: the chip's compiler allocates 8-22 MiB
+# across P2/P3 at VOC and COCO canvases, f32 and bf16, 7x7 and 14x14
+# (double-buffered blocks + the scratch accumulator + Mosaic's own
+# temporaries; f32 forward is the top of the range — ISSUE 21).  That is
+# over Mosaic's default 16 MiB scoped limit, so the kernels state theirs.
+_VMEM_LIMIT = 32 * 2**20
+
+
 def _pick_hblk(w: int, cblk: int, budget: int = 2 * 2**20) -> int:
     h = budget // (w * cblk * 4)
     return max(8, (h // 8) * 8)
@@ -249,6 +260,7 @@ def _fwd_impl(feat, rois, pooled, scale, s, interpret, rblk=None):
     )
     out = pl.pallas_call(
         kernel,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -267,8 +279,8 @@ def _fwd_impl(feat, rois, pooled, scale, s, interpret, rblk=None):
                 pltpu.VMEM((rblk, pooled[1], pooled[0], cblk), jnp.float32)
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(
-            (b, r, pooled[0], pooled[1], c), feat.dtype
+        out_shape=out_struct(
+            (b, r, pooled[0], pooled[1], c), feat.dtype, rois_p, feat
         ),
         interpret=interpret,
     )(rois_p.astype(jnp.float32).transpose(0, 2, 1), feat)
@@ -296,6 +308,7 @@ def _bwd_impl(feat_shape, feat_dtype, rois, g, pooled, scale, s, interpret,
     )
     out = pl.pallas_call(
         kernel,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -310,7 +323,7 @@ def _bwd_impl(feat_shape, feat_dtype, rois, g, pooled, scale, s, interpret,
                 lambda bb, cb, hb, rb, rois_ref: (bb, hb, 0, cb),
             ),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hf, wf, c), jnp.float32),
+        out_shape=out_struct((b, hf, wf, c), jnp.float32, rois_p, g),
         interpret=interpret,
     )(rois_p.astype(jnp.float32).transpose(0, 2, 1), g)
     return out.astype(feat_dtype)

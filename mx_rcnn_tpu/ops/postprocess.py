@@ -3,8 +3,8 @@
 Reference: the HOST loop in ``rcnn/core/tester.py :: pred_eval`` — per
 class: threshold, stack [boxes|score], ``cpu_nms``.  On a weak-host TPU
 deployment that loop is the eval bottleneck twice over: the full
-``(B, R, K)`` + ``(B, R, 4K)`` head outputs cross the relay (76 MB/batch
-at flagship shapes), and the per-class C NMS runs K−1 times per image on
+``(B, R, K)`` + ``(B, R, 4K)`` head outputs cross the device→host link
+(76 MB/batch at flagship shapes), and the per-class C NMS runs K−1 times per image on
 one core.  Here the whole thing is a batched device program — decode →
 clip → per-class NMS (vmap over classes × images, the Pallas kernel on
 TPU) — and only the per-class keep lists (≈0.5 MB/batch) come back.

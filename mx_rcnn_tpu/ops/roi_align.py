@@ -246,8 +246,8 @@ def extract_roi_features_batched(
 
     if mode == "roi_align" and valid_hw is None and use_pallas():
         if fits_vmem(
-            feat.shape[1], feat.shape[2], feat.shape[3],
-            pooled_max=max(pooled),
+            feat.shape[1], feat.shape[2], feat.shape[3], pooled,
+            feat.dtype.itemsize,
         ):
             from mx_rcnn_tpu.ops.pallas.roi_align import roi_align_pallas
 

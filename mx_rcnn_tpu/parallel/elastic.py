@@ -230,9 +230,9 @@ class ElasticLoop:
     Recovery contract: a fault at stream step S inside a window anchored
     at W costs re-executing steps [W, S] on the survivor mesh — with the
     default ``aux_interval=1`` the anchor IS the poison step, so exactly
-    one step replays.  The replay is bit-identical to a fresh run
-    started on the small mesh from the emergency checkpoint (the chaos
-    bench asserts this bytewise with ``deterministic=True`` steps).
+    one step replays.  The replay equals a fresh run started on the
+    small mesh from the emergency checkpoint (the chaos bench compares
+    the two final states).
 
     Call :meth:`flush` then :meth:`checkpoint_boundary` wherever the
     trainer checkpoints; regrow happens only there, behind the monitor's
@@ -446,7 +446,6 @@ def make_elastic_factory(
     devices=None,
     accum_steps: int = 1,
     donate: bool = True,
-    deterministic: bool = False,
 ) -> Callable[[Tuple[int, ...]], ElasticContext]:
     """Real shard_map substrate for :class:`ElasticLoop`.
 
@@ -478,7 +477,6 @@ def make_elastic_factory(
         )
         step_fn = make_parallel_train_step(
             model, tx, mesh, accum_steps=accum_steps, donate=donate,
-            deterministic=deterministic,
         )
 
         def place_batch(batch):

@@ -26,11 +26,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mx_rcnn_tpu.core.train import TrainState, make_train_step
 
-# jax promoted shard_map out of jax.experimental; accept either spelling
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def make_mesh(
     n_data: Optional[int] = None, n_model: int = 1, devices=None
@@ -104,7 +99,6 @@ def take_replica_rows(batch: Dict, n_active: int, n_base: int) -> Dict:
 
 def make_parallel_train_step(
     model, tx, mesh: Mesh, accum_steps: int = 1, donate: bool = True,
-    deterministic: bool = False,
 ):
     """The DP train step: per-chip compute + pmean on grads/metrics.
 
@@ -115,10 +109,7 @@ def make_parallel_train_step(
     many microbatches before its gradient joins the all-reduce).
     ``donate`` mirrors ``make_train_step``'s knob (same default: the
     input state is donated; rollback paths re-place from host
-    snapshots, never reuse a donated buffer).  ``deterministic`` mirrors
-    it too: on CPU it pins the legacy run-order-stable XLA runtime so
-    two runs over identical inputs compare BITWISE — required by the
-    elastic chaos bench's shrink-equivalence check.
+    snapshots, never reuse a donated buffer).
     """
     inner = make_train_step(model, tx, pmean_axis="data", accum_steps=accum_steps)
 
@@ -126,14 +117,15 @@ def make_parallel_train_step(
     batch_spec = P("data")
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(state_spec, batch_spec, state_spec, P()),
         out_specs=(state_spec, state_spec),
-        # the rep checker can't see through the optimizer update that the
-        # pmean-ed grads keep the state replicated; test_dp_grads_match_
-        # single_device asserts that invariant numerically instead
-        check_rep=False,
+        # check_vma stays ON (the default): it is what makes autodiff
+        # psum the replicated params' cotangents (core/train.py divides
+        # by the axis size and relies on it).  With it off this step
+        # trains on unsynchronised gradients — test_dp_grads_match_
+        # single_device fails by 16% of a kernel's elements
     )
     def sharded_step(state: TrainState, batch, rng, lr_scale):
         # sampling decorrelation across chips: batches carrying per-image
@@ -144,15 +136,7 @@ def make_parallel_train_step(
             rng = jax.random.fold_in(rng, jax.lax.axis_index("data"))
         return inner(state, batch, rng, lr_scale)
 
-    jit_kwargs: Dict[str, object] = {
-        "donate_argnums": (0,) if donate else ()
-    }
-    # same rationale as make_train_step: the default CPU thunk runtime
-    # reassociates reductions across threads, so even one executable on
-    # identical inputs drifts ~1e-7 run-to-run
-    if deterministic and jax.default_backend() == "cpu":
-        jit_kwargs["compiler_options"] = {"xla_cpu_use_thunk_runtime": False}
-    jitted = jax.jit(sharded_step, **jit_kwargs)
+    jitted = jax.jit(sharded_step, donate_argnums=(0,) if donate else ())
 
     def step(state: TrainState, batch, rng, lr_scale=1.0):
         # lr_scale: one-step effective-LR override (replicated scalar) —

@@ -1061,14 +1061,19 @@ def launch_backends(argv_base: List[str], n: int,
     """Spawn ``n`` backend processes from ``argv_base`` (which must
     accept ``--port_file PATH``), wait for each to announce its port,
     and return the live handles.  On any startup failure everything
-    already launched is torn down."""
+    already launched is torn down.
+
+    Children inherit this process's environment plus ``env`` — the
+    platform they run on is the CALLER's choice, never defaulted here.
+    A chip belongs to one process: on a one-chip machine at most one
+    backend can own it, so CPU fleets (stubs, tests) pass
+    ``env={"JAX_PLATFORMS": "cpu"}`` explicitly."""
     import tempfile
 
     procs: List[Tuple[subprocess.Popen, str]] = []
     out: List[BackendProc] = []
     tmpdir = tempfile.mkdtemp(prefix="fleet_backends_")
     full_env = dict(os.environ)
-    full_env.setdefault("JAX_PLATFORMS", "cpu")
     if env:
         full_env.update(env)
     try:
@@ -1132,7 +1137,10 @@ def spawn_stub_backends(n: int, service_ms: float = 25.0,
         "--linger_ms", str(linger_ms),
         "--max_queue", str(max_queue),
     ]
-    return launch_backends(argv, n, startup_timeout=startup_timeout)
+    # stubs sleep instead of running a model: CPU by construction, so a
+    # parent that holds the chip can still spawn them
+    return launch_backends(argv, n, startup_timeout=startup_timeout,
+                           env={"JAX_PLATFORMS": "cpu"})
 
 
 def _backend_main(argv: Optional[Sequence[str]] = None) -> int:

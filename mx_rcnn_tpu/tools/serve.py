@@ -42,6 +42,7 @@ import json
 import logging
 import threading
 import time
+from typing import NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -128,7 +129,11 @@ def run_fleet(p, args):
     """--fleet N: spawn N backend processes (this same command with
     --backend), put a :class:`FleetGateway` over them, and drive the
     load through the gateway — the multi-host serve path with the real
-    model stack in every process."""
+    model stack in every process.  Children inherit this process's
+    environment, platform included: a chip belongs to one process, so
+    on a one-chip machine N > 1 only works with ``JAX_PLATFORMS=cpu``
+    exported (one process per chip on a multi-chip host is the 4-chip
+    replica item in ROADMAP.md)."""
     import sys
 
     from mx_rcnn_tpu.serve.fleet import FleetGateway, launch_backends
@@ -191,10 +196,8 @@ def run_fleet(p, args):
         logger.info("wrote %s", args.out)
 
 
-def main():
-    from mx_rcnn_tpu.utils.platform import cli_bootstrap
-
-    cli_bootstrap()
+def parse_args(argv=None):
+    """→ (parser, args); the parser rides along for ``p.error``."""
     p = argparse.ArgumentParser(description="Serving load test")
     p.add_argument("--network", default="resnet50",
                    choices=["vgg", "resnet", "resnet50", "resnet152",
@@ -292,11 +295,26 @@ def main():
                    help="(backend mode) write the bound frontend port "
                    "here — how a spawning gateway finds this process")
     p.add_argument("--out", default=None, help="write the report JSON here")
-    args = p.parse_args()
+    return p, p.parse_args(argv)
 
-    if args.fleet > 0:
-        return run_fleet(p, args)
 
+class ServeStack(NamedTuple):
+    """What :func:`build_stack` assembles from the parsed arguments."""
+
+    registry: ModelRegistry
+    runner: object  # ServeRunner, or a ReplicaPool of them
+    engine: ServingEngine
+    sizes: tuple
+    load_models: Optional[list]
+    tenant_names: Optional[list]
+    cascade_router: object
+
+
+def build_stack(p, args) -> ServeStack:
+    """Model(s) → registry → runner or pool → engine, exactly as the
+    arguments say; nothing is started or warmed yet (``with
+    stack.engine:`` does both).  Shared by :func:`main` and
+    ``chip_smoke.py`` so the smoke serves through the CLI's own path."""
     if args.small:
         cfg = small_config(args.network)
         sizes = ((72, 96), (96, 128), (64, 80))
@@ -395,6 +413,21 @@ def main():
         cascade_router = engine.attach_cascade(policy)
         logger.info("cascade: %s -> %s (min_score %.2f)",
                     policy.cheap, policy.flagship, policy.min_score)
+    return ServeStack(registry, runner, engine, sizes, load_models,
+                      tenant_names, cascade_router)
+
+
+def main():
+    from mx_rcnn_tpu.utils.platform import cli_bootstrap
+
+    cli_bootstrap()
+    p, args = parse_args()
+
+    if args.fleet > 0:
+        return run_fleet(p, args)
+
+    (registry, runner, engine, sizes, load_models, tenant_names,
+     cascade_router) = build_stack(p, args)
     logger.info(
         "warming up %d bucket(s) x %d model(s) x %d replica(s)...",
         len(runner.ladder), len(registry.model_ids()), args.replicas,

@@ -128,7 +128,7 @@ class SimulatedCrash(RuntimeError):
 class InjectedPredictFault(RuntimeError):
     """Raised by the serve-phase injector inside a replica's predict — a
     RuntimeError, so real retry/failover handling treats it exactly like
-    a device/relay fault."""
+    a device fault."""
 
 
 class InjectedSwapFault(RuntimeError):

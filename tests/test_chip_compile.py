@@ -1,0 +1,127 @@
+"""The main path's Pallas kernels, compiled by the TPU's own compiler at
+real widths — for a v5e that is DESCRIBED, not attached.
+
+Interpret mode (tests/test_pallas_roi_align.py, tests/test_pallas_nms.py)
+checks what the kernels compute; it cannot see what Mosaic refuses: a
+block over the scoped-VMEM limit, an unaligned slice, a kernel that does
+not fit.  These compiles can, in about two seconds each and with no chip
+time (ISSUE 21: the f32 resident backward had passed every interpret
+test and was refused at the flagship C4 map and at FPN P3).
+
+Nothing runs, so nothing here says a result or a time.  All cases live in
+THIS file and the topology is described inside a fixture: one process at
+a time may load the TPU's library, xdist gives a file to one worker, and
+a call at import would make the workers collect different tests.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mx_rcnn_tpu.ops.nms import batched_class_nms
+from mx_rcnn_tpu.ops.pallas.nms import nms_mask_sorted_pallas
+from mx_rcnn_tpu.ops.pallas.roi_align import fits_vmem, roi_align_pallas
+from mx_rcnn_tpu.ops.pallas.roi_align_stream import roi_align_stream
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """Sharding on one device of a described v5e:2x2; skips where the
+    compiler cannot describe one.  The persistent cache is off around
+    these compiles: an entry written for a described chip cannot be read
+    back without one, and the next run would warn and recompile."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs to /tmp
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, one_chip, *shapes) -> str:
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes
+    ]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# (kernel, feature map, rois per image, pooled, spatial scale)
+_ROI_ALIGN_CASES = [
+    # flagship C4, bench batch, landscape — f32 here was refused (17.5 MiB)
+    pytest.param(roi_align_pallas, (8, 38, 64, 1024), 128, (14, 14), 1 / 16,
+                 id="resident-c4-landscape-14"),
+    pytest.param(roi_align_pallas, (2, 64, 38, 1024), 128, (14, 14), 1 / 16,
+                 id="resident-c4-portrait-14"),
+    # FPN P3 under the 7x7 box head — f32 here was refused (16.02 MiB)
+    pytest.param(roi_align_pallas, (2, 76, 128, 256), 512, (7, 7), 1 / 8,
+                 id="resident-p3-7"),
+    # FPN P2: over the resident budget at any dtype
+    pytest.param(roi_align_stream, (2, 152, 256, 256), 512, (7, 7), 1 / 4,
+                 id="stream-p2-7"),
+    pytest.param(roi_align_stream, (2, 152, 256, 256), 512, (14, 14), 1 / 4,
+                 id="stream-p2-14"),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("kernel,feat,n_rois,pooled,scale", _ROI_ALIGN_CASES)
+def test_roi_align_fwd_bwd_compiles(one_chip, kernel, feat, n_rois, pooled,
+                                    scale, dtype):
+    """Forward AND backward (the loss keeps the forward alive) of the
+    kernel the dispatcher would pick for this map."""
+    resident = kernel is roi_align_pallas
+    assert fits_vmem(*feat[1:], pooled, jnp.dtype(dtype).itemsize) == resident
+
+    def fwd_bwd(f, rois):
+        def loss(x):
+            out = kernel(x, rois, pooled, scale, 2)
+            return (out.astype(jnp.float32) ** 2).sum()
+
+        return jax.value_and_grad(loss)(f)
+
+    text = _compiled_text(
+        fwd_bwd, one_chip,
+        (feat, dtype), ((feat[0], n_rois, 4), jnp.float32),
+    )
+    assert text.count("tpu_custom_call") >= 2  # fwd + bwd kernels
+
+
+@pytest.mark.parametrize("n,max_keep", [(12000, 2000), (6000, 300)],
+                         ids=["train-12000-2000", "test-6000-300"])
+def test_sorted_nms_compiles(one_chip, n, max_keep):
+    """The proposal path's NMS at the train and test top-N."""
+    text = _compiled_text(
+        lambda boxes, valid: nms_mask_sorted_pallas(
+            boxes, valid, 0.7, max_keep=max_keep
+        ),
+        one_chip, ((n, 4), jnp.float32), ((n,), jnp.bool_),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_vmapped_class_nms_compiles(one_chip, monkeypatch):
+    """The device postprocess's per-class NMS: the kernel under two vmaps
+    (images x classes), reached through ops.nms as the serve graph does.
+    ``use_pallas`` asks the backend, which is the CPU here, so the test
+    steers it."""
+    monkeypatch.setenv("MX_RCNN_TPU_PALLAS", "1")
+    text = _compiled_text(
+        jax.vmap(lambda b, s: batched_class_nms(b, s, 0.3, 100)),
+        one_chip, ((4, 20, 300, 4), jnp.float32), ((4, 20, 300), jnp.float32),
+    )
+    assert "tpu_custom_call" in text

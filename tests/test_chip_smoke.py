@@ -1,0 +1,106 @@
+"""CPU rehearsal of ``chip_smoke.py``: the same phase functions, the same
+checks, at the tiny configurations of tests/test_train_cli.py and
+``tools/serve.py --small`` — so a wrong path, argument or counter is found
+here and not on chip time.  What only the chip can show (the kernels, the
+layout feed, the real widths) is chip_smoke's own job."""
+
+import dataclasses
+import json
+import os
+import subprocess
+import sys
+from types import SimpleNamespace
+
+import pytest
+
+import chip_smoke
+from mx_rcnn_tpu.config import generate_config
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _tiny_generate_config(network, dataset):
+    cfg = generate_config(network, dataset)
+    return cfg.replace(
+        SHAPE_BUCKETS=((96, 96),),
+        TRAIN=dataclasses.replace(
+            cfg.TRAIN, RPN_PRE_NMS_TOP_N=256, RPN_POST_NMS_TOP_N=32,
+            BATCH_ROIS=16, RPN_BATCH_SIZE=32,
+        ),
+        dataset=dataclasses.replace(
+            cfg.dataset, SCALES=((96, 96),), MAX_GT_BOXES=8
+        ),
+    )
+
+
+@pytest.fixture
+def tiny_train_argv(tmp_path, monkeypatch):
+    """chip_smoke's train arguments cut to the tiny config: one image per
+    virtual device (global batch 8 under conftest's 8 devices), a gentle
+    LR (the default diverges the tiny model within steps)."""
+    from mx_rcnn_tpu.tools import train_end2end as cli
+
+    monkeypatch.setattr(cli, "generate_config", _tiny_generate_config)
+    return [
+        "--network", "resnet50", "--dataset", "PascalVOC",
+        "--synthetic", "16", "--epochs", "1", "--frequent", "1",
+        "--batch_images", "1", "--lr", "0.0005", "--max_steps", "2",
+        "--prefix", str(tmp_path / "ckpt"),
+    ]
+
+
+def test_train_phase_applies_every_step(tiny_train_argv):
+    state, report = chip_smoke.train_phase(tiny_train_argv)
+    assert report["steps"] == report["steps_applied"] == 2
+    assert int(state.step) == 2
+    assert [step for step, _loss in report["losses"]] == [0, 1]
+
+
+def test_train_phase_fails_when_the_guard_skips(tiny_train_argv, monkeypatch):
+    """A NaN step that the guard absorbs ends ``train_net`` cleanly — the
+    smoke must not."""
+    monkeypatch.setenv("MX_RCNN_FAULTS", "nan_loss@1")
+    with pytest.raises(RuntimeError, match="applied to the optimizer|guard"):
+        chip_smoke.train_phase(tiny_train_argv)
+
+
+def test_kernels_phase_in_interpret_mode():
+    """Same inputs, same references, same bounds as on the chip — the
+    Pallas interpreter standing in for Mosaic."""
+    errs = chip_smoke.kernels_phase(interpret=True)
+    assert set(errs) == {
+        f"{kernel}_{dtype}_{pass_}"
+        for kernel in ("resident", "stream")
+        for dtype in ("f32", "bf16") for pass_ in ("fwd", "bwd")
+    }
+    assert max(v for k, v in errs.items() if "f32" in k) < 1e-5
+
+
+def test_serve_phase_small_config():
+    report = chip_smoke.serve_phase([
+        "--small", "--max_batch", "2", "--requests", "8",
+        "--concurrency", "4", "--seed", "0",
+    ])
+    assert report["outcomes"]["ok"] == 8
+    assert report["engine"]["compile"]["misses"] == 2  # small ladder
+
+
+def test_exits_nonzero_without_a_tpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO_ROOT, "chip_smoke.py")],
+        env=env, cwd=REPO_ROOT, capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "needs a TPU" in proc.stderr
+
+
+def test_result_line_is_the_contract():
+    fake = (SimpleNamespace(platform="tpu", device_kind="TPU v5 lite"),)
+    assert chip_smoke.result_line(fake) == (
+        '{"ok": true, "device": {"platform": "tpu", '
+        '"kind": "TPU v5 lite", "count": 1}}'
+    )
+    assert json.loads(chip_smoke.result_line(fake * 4))["device"]["count"] == 4

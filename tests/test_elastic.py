@@ -439,7 +439,7 @@ def test_real_mesh_shrink_bitwise(monkeypatch, tmp_path):
 
     set_faults(monkeypatch, "device_lost@1.4")
     td = str(tmp_path)
-    factory = make_elastic_factory(model, tx, deterministic=True)
+    factory = make_elastic_factory(model, tx)
     loop = ElasticLoop(
         factory, 8,
         checkpoint_fn=lambda s, i, m: save_checkpoint(td, s, 0, i, meta=m),

@@ -510,8 +510,7 @@ def test_bf16_parity_gate_and_precision_signatures():
     from mx_rcnn_tpu.serve.runner import ServeRunner
 
     model, params, cfg = _tiny_box_model()
-    runner = ServeRunner(model, params, cfg, max_batch=1,
-                         deterministic=True, precision="bfloat16")
+    runner = ServeRunner(model, params, cfg, max_batch=1, precision="bfloat16")
     runner.warmup()
     report = runner.parity[f"{runner.default_model}:bf16"]
     assert report["checked"] and report["ok"]
@@ -522,7 +521,7 @@ def test_bf16_parity_gate_and_precision_signatures():
     assert sigs and all("bf16" in repr(s) for s in sigs)
     # an f32 runner over the same model tags differently — the two
     # serve graphs occupy disjoint compile-cache keys by construction
-    f32 = ServeRunner(model, params, cfg, max_batch=1, deterministic=True)
+    f32 = ServeRunner(model, params, cfg, max_batch=1)
     f32.warmup()
     f32_sigs = f32.compile_cache.snapshot()["signatures"]
     assert all("f32" in repr(s) for s in f32_sigs)
@@ -541,8 +540,7 @@ def test_int8_parity_gate_and_broken_scale_fold_refused():
     from mx_rcnn_tpu.serve.runner import PrecisionParityError, ServeRunner
 
     model, params, cfg = _tiny_box_model()
-    runner = ServeRunner(model, params, cfg, max_batch=1,
-                         deterministic=True, precision="int8")
+    runner = ServeRunner(model, params, cfg, max_batch=1, precision="int8")
     runner.warmup()
     report = runner.parity[f"{runner.default_model}:int8"]
     assert report["checked"] and report["ok"]
@@ -557,8 +555,7 @@ def test_int8_parity_gate_and_broken_scale_fold_refused():
         runner.default_model
     )
     # a corrupted scale fold (one leaf's scales x64) fails the gate
-    broken = ServeRunner(model, params, cfg, max_batch=1,
-                         deterministic=True, precision="int8")
+    broken = ServeRunner(model, params, cfg, max_batch=1, precision="int8")
     slot = broken._slot(broken.default_model)
     hit = [False]
 
