@@ -1,0 +1,274 @@
+"""Configuration tree.
+
+TPU-native rebuild of the reference's global easydict config
+(``rcnn/config.py :: config, default, generate_config``).  Field names and
+defaults deliberately match the reference for auditability, but the tree is
+immutable-by-convention dataclasses instead of mutable module globals: a
+``Config`` is built once per run by :func:`generate_config` and passed
+explicitly.  Static, hashable pieces (shape buckets, anchor spec, fixed roi
+counts) feed jit as compile-time constants.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from dataclasses import dataclass, field
+from typing import Any, Dict, Optional, Tuple
+
+
+@dataclass(frozen=True)
+class TrainConfig:
+    """Training hyper-parameters (reference: ``config.TRAIN.*``)."""
+
+    # whether the graph contains the RPN (end2end / rpn-only) or runs
+    # fast-rcnn on precomputed proposals
+    HAS_RPN: bool = True
+    END2END: bool = True
+    # images per device-step (per chip under data parallelism)
+    BATCH_IMAGES: int = 1
+    # RCNN stage sampling (reference: rcnn/io/rcnn.py :: sample_rois)
+    BATCH_ROIS: int = 128
+    FG_FRACTION: float = 0.25
+    FG_THRESH: float = 0.5
+    BG_THRESH_HI: float = 0.5
+    BG_THRESH_LO: float = 0.0
+    # bbox regression targets (reference: rcnn/processing/bbox_regression.py)
+    BBOX_REGRESSION_THRESH: float = 0.5
+    BBOX_NORMALIZATION_PRECOMPUTED: bool = True
+    BBOX_MEANS: Tuple[float, float, float, float] = (0.0, 0.0, 0.0, 0.0)
+    BBOX_STDS: Tuple[float, float, float, float] = (0.1, 0.1, 0.2, 0.2)
+    # per-class (K, 4) normalization tables — the reference's
+    # BBOX_NORMALIZATION_PRECOMPUTED path in add_bbox_regression_targets
+    # computes per-class means/stds; when set (by train_rcnn's roidb
+    # precompute) they override the class-agnostic vectors above in both
+    # sample_rois normalization and test-time de-normalization
+    BBOX_MEANS_PER_CLASS: Optional[Tuple[Tuple[float, ...], ...]] = None
+    BBOX_STDS_PER_CLASS: Optional[Tuple[Tuple[float, ...], ...]] = None
+    # RPN anchor target assignment (reference: rcnn/io/rpn.py :: assign_anchor)
+    RPN_BATCH_SIZE: int = 256
+    RPN_FG_FRACTION: float = 0.5
+    RPN_POSITIVE_OVERLAP: float = 0.7
+    RPN_NEGATIVE_OVERLAP: float = 0.3
+    RPN_CLOBBER_POSITIVES: bool = False
+    RPN_BBOX_WEIGHTS: Tuple[float, float, float, float] = (1.0, 1.0, 1.0, 1.0)
+    # UNIMPLEMENTED placeholder: only the reference default (-1 = uniform
+    # example weighting) is supported; non-default values raise in
+    # generate_config rather than silently diverging
+    RPN_POSITIVE_WEIGHT: float = -1.0
+    # RPN proposal generation, train graph (reference: rcnn/symbol/proposal.py)
+    RPN_NMS_THRESH: float = 0.7
+    RPN_PRE_NMS_TOP_N: int = 12000
+    RPN_POST_NMS_TOP_N: int = 2000
+    RPN_MIN_SIZE: int = 16
+    # augmentation
+    FLIP: bool = True
+    SHUFFLE: bool = True
+    # optimization (reference: train_end2end.py :: train_net)
+    LEARNING_RATE: float = 0.001
+    MOMENTUM: float = 0.9
+    WD: float = 0.0005
+    CLIP_GRADIENT: float = 5.0
+    LR_STEP_EPOCHS: Tuple[int, ...] = (7,)
+    LR_FACTOR: float = 0.1
+    # mask head (Mask R-CNN extension; not in reference)
+    MASK_SIZE: int = 28
+    # gt bitmap resolution in the gt-box frame (data/masks.py): each
+    # gt's polygons rasterize once to (M, M); in-graph targets resample
+    # under the roi grid.  64 ≈ 2.3× the 28-cell target grid — enough
+    # that bilinear resampling, not the bitmap, bounds target fidelity.
+    MASK_GT_SIZE: int = 64
+
+
+@dataclass(frozen=True)
+class TestConfig:
+    """Inference hyper-parameters (reference: ``config.TEST.*``)."""
+
+    HAS_RPN: bool = True
+    BATCH_IMAGES: int = 1
+    # proposal generation, test graph
+    RPN_NMS_THRESH: float = 0.7
+    RPN_PRE_NMS_TOP_N: int = 6000
+    RPN_POST_NMS_TOP_N: int = 300
+    RPN_MIN_SIZE: int = 16
+    # final detection filtering (reference: rcnn/core/tester.py :: pred_eval)
+    NMS: float = 0.3
+    SCORE_THRESH: float = 1e-3
+    MAX_PER_IMAGE: int = 100
+    # fixed per-image detection budget after per-class NMS (TPU fixed shape)
+    DET_PER_CLASS: int = 100
+    # device-side eval postprocess (ops/postprocess.py): per-class
+    # decode+NMS runs in the forward jit and only keep lists come back
+    # to the host; for mask models the jit also gathers each survivor's S×S
+    # mask-logit grid for its predicted class (det_masks), so only
+    # selected grids cross — sigmoid/paste/RLE stay host-side.  False
+    # restores the reference-style host loop
+    DEVICE_POSTPROCESS: bool = True
+    # streaming mask serving (ISSUE 20): additionally paste each
+    # survivor's grid into a fixed (max_det, Hc, Wc) binary canvas
+    # inside the jit (Hc, Wc = padded bucket extent → one shape per
+    # rung, zero-recompile ladder intact) so the host keeps only RLE.
+    # Requires DEVICE_POSTPROCESS and a mask network; off by default —
+    # the detection-only eval path never pays for canvases
+    MASK_CANVAS: bool = False
+    # ship eval images as uint8 and normalize on device — 4× less H2D
+    # traffic for a ≤0.5-LSB quantization of the resized pixels
+    UINT8_TRANSFER: bool = True
+    # proposal dumping for alternate training / recall eval
+    # (reference: config.TEST.PROPOSAL_* — a larger budget than detection's
+    # 300 so the Fast-RCNN stage sees the full 2000-proposal pool)
+    PROPOSAL_NMS: float = 0.7
+    PROPOSAL_PRE_NMS_TOP_N: int = 20000
+    PROPOSAL_POST_NMS_TOP_N: int = 2000
+
+
+@dataclass(frozen=True)
+class NetworkConfig:
+    """Per-backbone settings (reference: ``default`` network registry)."""
+
+    name: str = "resnet"
+    depth: int = 101  # resnet depth: 50 / 101 (ignored for vgg)
+    PIXEL_MEANS: Tuple[float, float, float] = (123.68, 116.779, 103.939)  # RGB
+    PIXEL_STDS: Tuple[float, float, float] = (1.0, 1.0, 1.0)
+    # UNIMPLEMENTED placeholder: bucket padding (SHAPE_BUCKETS) subsumes
+    # the reference's pad-to-stride; non-zero values raise in
+    # generate_config
+    IMAGE_STRIDE: int = 0
+    RPN_FEAT_STRIDE: int = 16
+    RCNN_FEAT_STRIDE: int = 16
+    ANCHOR_SCALES: Tuple[int, ...] = (8, 16, 32)
+    ANCHOR_RATIOS: Tuple[float, ...] = (0.5, 1.0, 2.0)
+    NUM_ANCHORS: int = 9
+    # ROI feature extraction: 'roi_align' (TPU-native default) or 'roi_pool'
+    # compat mode matching MXNet ROIPooling max-pool semantics
+    ROI_MODE: str = "roi_align"
+    POOLED_SIZE: Tuple[int, int] = (14, 14)
+    ROI_SAMPLE_RATIO: int = 2
+    # layers frozen during training (reference: FIXED_PARAMS; conv1 + BN stats)
+    FIXED_PARAMS: Tuple[str, ...] = ("conv0", "stage1", "bn")
+    FIXED_PARAMS_SHARED: Tuple[str, ...] = ("conv0", "stage1", "stage2", "stage3", "bn")
+    # FPN (extension; reference has no FPN)
+    USE_FPN: bool = False
+    FPN_FEAT_STRIDES: Tuple[int, ...] = (4, 8, 16, 32, 64)
+    FPN_ANCHOR_SCALES: Tuple[int, ...] = (8,)
+    FPN_CHANNELS: int = 256
+    # Mask head
+    USE_MASK: bool = False
+    # compute dtype for conv/matmul ("bfloat16" rides the MXU; params stay f32)
+    COMPUTE_DTYPE: str = "float32"
+    # fold frozen-BN affines into conv kernels at apply time (algebraically
+    # exact rewrite, identical param tree — models/layers.fused_conv_bn; the
+    # fold multiplies the f32 weight instead of the activation).  DEFAULT
+    # OFF: the fold's fp-reassociation measurably rerouted random-init
+    # training on the f32 integration gate (C4 gate 0.90@300 unfused vs
+    # 0.43@500 folded, same seed) — a bad default for training fidelity.
+    # It is worth +2-3% on the bf16 flagship bench (where conv rounding
+    # dwarfs the fold delta), so bench.py's perf config enables it
+    # explicitly alongside bf16.
+    FOLD_BN: bool = False
+
+
+@dataclass(frozen=True)
+class DatasetConfig:
+    """Per-dataset settings (reference: ``default`` dataset registry)."""
+
+    name: str = "PascalVOC"
+    NUM_CLASSES: int = 21  # including background
+    # short-side target / long-side cap (reference: config.SCALES, MAX_SIZE)
+    SCALES: Tuple[Tuple[int, int], ...] = ((600, 1000),)
+    root_path: str = "data"
+    dataset_path: str = "data/VOCdevkit"
+    image_set: str = "2007_trainval"
+    test_image_set: str = "2007_test"
+    # max gt boxes per image after padding (TPU fixed shape)
+    MAX_GT_BOXES: int = 100
+
+
+@dataclass(frozen=True)
+class Config:
+    network: NetworkConfig = field(default_factory=NetworkConfig)
+    dataset: DatasetConfig = field(default_factory=DatasetConfig)
+    TRAIN: TrainConfig = field(default_factory=TrainConfig)
+    TEST: TestConfig = field(default_factory=TestConfig)
+    # Padded (H, W) shape buckets replacing MutableModule re-binding
+    # (reference: rcnn/core/module.py).  XLA compiles once per bucket.
+    # Canvases are MXU-friendly multiples of 16·{38,64} rather than the
+    # raw 600×1000 resize bound: the extra border is padding masked via
+    # im_info everywhere, and W/16 = 64 tiles the conv grid exactly
+    # (measured +3% train throughput over 600×1000 canvases).
+    SHAPE_BUCKETS: Tuple[Tuple[int, int], ...] = ((608, 1024), (1024, 608))
+
+    def replace(self, **kw: Any) -> "Config":
+        return dataclasses.replace(self, **kw)
+
+
+# --- registries (reference: rcnn/config.py :: default + generate_config) ---
+
+NETWORKS: Dict[str, NetworkConfig] = {
+    "vgg": NetworkConfig(
+        name="vgg",
+        depth=16,
+        FIXED_PARAMS=("conv1", "conv2"),
+        FIXED_PARAMS_SHARED=("conv1", "conv2", "conv3", "conv4", "conv5"),
+        POOLED_SIZE=(7, 7),
+        ROI_MODE="roi_pool",
+    ),
+    "resnet": NetworkConfig(name="resnet", depth=101),
+    "resnet50": NetworkConfig(name="resnet", depth=50),
+    "resnet152": NetworkConfig(name="resnet", depth=152),
+    "resnet_fpn": NetworkConfig(
+        name="resnet",
+        depth=50,
+        USE_FPN=True,
+        ANCHOR_SCALES=(8,),
+        NUM_ANCHORS=3,
+        POOLED_SIZE=(14, 14),
+    ),
+    "mask_resnet_fpn": NetworkConfig(
+        name="resnet",
+        depth=101,
+        USE_FPN=True,
+        USE_MASK=True,
+        ANCHOR_SCALES=(8,),
+        NUM_ANCHORS=3,
+        POOLED_SIZE=(14, 14),
+    ),
+}
+
+DATASETS: Dict[str, DatasetConfig] = {
+    "PascalVOC": DatasetConfig(),
+    "PascalVOC0712": DatasetConfig(
+        name="PascalVOC",
+        image_set="2007_trainval+2012_trainval",
+        test_image_set="2007_test",
+    ),
+    "coco": DatasetConfig(
+        name="coco",
+        NUM_CLASSES=81,
+        dataset_path="data/coco",
+        image_set="train2017",
+        test_image_set="val2017",
+    ),
+}
+
+
+def generate_config(network: str, dataset: str, **overrides: Any) -> Config:
+    """Build a run config from registry names.
+
+    Reference: ``rcnn/config.py :: generate_config(network, dataset)`` —
+    but returns a fresh immutable tree instead of mutating globals.
+    """
+    net = NETWORKS[network]
+    ds = DATASETS[dataset]
+    train = TrainConfig()
+    test = TestConfig()
+    if ds.name == "coco":
+        train = dataclasses.replace(train, LR_STEP_EPOCHS=(6,))
+    cfg = Config(network=net, dataset=ds, TRAIN=train, TEST=test)
+    if overrides:
+        cfg = dataclasses.replace(cfg, **overrides)
+    # placeholder-field guards AFTER overrides so they can actually fire
+    if cfg.network.IMAGE_STRIDE != 0:
+        raise NotImplementedError("IMAGE_STRIDE is subsumed by SHAPE_BUCKETS")
+    if cfg.TRAIN.RPN_POSITIVE_WEIGHT != -1.0:
+        raise NotImplementedError("RPN_POSITIVE_WEIGHT != -1 is not supported")
+    return cfg
