@@ -62,7 +62,7 @@ from mx_rcnn_tpu.core.resilience import (
     _supports_lr_scale,
     host_copy,
 )
-from mx_rcnn_tpu.utils import faults
+from mx_rcnn_tpu.utils import faults, tracing
 
 logger = logging.getLogger(__name__)
 
@@ -167,6 +167,7 @@ class DeviceFeed:
         self.staged_hits = 0
         self.feed_starved = 0
         self.feed_starved_after_first = 0
+        self.wait_s = 0.0  # consumer time blocked on the worker
         self._thread = threading.Thread(
             target=self._worker, name=name, daemon=True
         )
@@ -188,8 +189,9 @@ class DeviceFeed:
 
     def _worker(self):
         try:
-            for item in self._source:
-                staged = self._place(item)
+            for n, item in enumerate(self._source):
+                with tracing.span(tracing.FEED_PLACE, batch=n):
+                    staged = self._place(item)
                 if not self._put(("item", staged)):
                     return
             self._put(("stop", None))
@@ -208,13 +210,16 @@ class DeviceFeed:
             staged = True
         except queue.Empty:
             staged = False
-            while True:
-                try:
-                    kind, payload = self._q.get(timeout=0.2)
-                    break
-                except queue.Empty:
-                    if self._closed.is_set():
-                        raise StopIteration from None
+            t0 = time.perf_counter()
+            with tracing.span(tracing.FEED_WAIT):
+                while True:
+                    try:
+                        kind, payload = self._q.get(timeout=0.2)
+                        break
+                    except queue.Empty:
+                        if self._closed.is_set():
+                            raise StopIteration from None
+            self.wait_s += time.perf_counter() - t0
         if kind == "stop":
             self._done = True
             raise StopIteration
@@ -249,6 +254,7 @@ class DeviceFeed:
             "staged_hits": self.staged_hits,
             "feed_starved": self.feed_starved,
             "feed_starved_after_first": self.feed_starved_after_first,
+            "wait_s": round(self.wait_s, 4),
             "occupancy": round(self.staged_hits / fed, 4),
         }
 
@@ -328,7 +334,8 @@ class AsyncAuxSink:
         self.fetched_trees += len(trees)
         stalled = not self._ready(trees)
         t0 = time.perf_counter()
-        out = jax.device_get(list(trees))
+        with tracing.span(tracing.GUARD_FETCH, n=len(trees)):
+            out = jax.device_get(list(trees))
         dt = time.perf_counter() - t0
         if stalled:
             self.fetch_stalls += 1
@@ -408,6 +415,8 @@ class PipelinedLoop:
         self.window_rollbacks = 0
         self.replayed_steps = 0
         self.flushes = 0
+        self.snapshots = 0  # host copies of the state (window snapshots)
+        self.snapshot_s = 0.0
 
     # -- delegated counters / snapshot surface (watchdog dumps, summaries)
     @property
@@ -486,12 +495,22 @@ class PipelinedLoop:
         self.guard._since_snapshot = 0
 
     # -- step execution
-    def _dispatch(self, state, batch, rng, tag: str):
+    def _snapshot(self, state, idx: int):
+        """Owning host copy of ``state``: the window's rollback point."""
+        t0 = time.perf_counter()
+        with tracing.span(tracing.GUARD_SNAPSHOT, step=idx):
+            snap = host_copy(state)
+        self.snapshots += 1
+        self.snapshot_s += time.perf_counter() - t0
+        return snap
+
+    def _dispatch(self, state, batch, rng, idx: int, tag: str):
         wd = self.guard.watchdog
         if wd is not None:
-            wd.arm(tag=tag)
+            wd.arm(tag=str(idx) if tag == "step" else f"{tag}@{idx}")
         try:
-            return self._step_fn(state, batch, rng)
+            with tracing.span(tracing.STEP_DISPATCH, step=idx, tag=tag):
+                return self._step_fn(state, batch, rng)
         finally:
             if wd is not None:
                 wd.disarm()
@@ -501,7 +520,8 @@ class PipelinedLoop:
     ) -> Tuple[Any, List[Tuple[int, Dict[str, Any]]], bool]:
         if self.aux_interval <= 1:
             idx = self.guard.step_index
-            state, aux, ok = self.guard.step(state, batch, rng)
+            with tracing.span(tracing.STEP_DISPATCH, step=idx, tag="sync"):
+                state, aux, ok = self.guard.step(state, batch, rng)
             return state, ([(idx, aux)] if ok else []), ok
         idx = self._idx
         self._idx += 1
@@ -509,9 +529,9 @@ class PipelinedLoop:
         if self._win_snapshot is None:
             # BEFORE the first dispatch of a window, as an owning copy:
             # the step may donate the buffers a device_get view aliases
-            self._win_snapshot = host_copy(state)
+            self._win_snapshot = self._snapshot(state, idx)
         faults.stall(idx)  # test injection, no-op in production
-        state, aux = self._dispatch(state, batch, rng, tag=str(idx))
+        state, aux = self._dispatch(state, batch, rng, idx, tag="step")
         self._entries.append(_Entry(idx, batch, rng, aux))
         self.sink.defer()
         if len(self._entries) >= self.aux_interval:
@@ -568,8 +588,8 @@ class PipelinedLoop:
             # restored by the rollback, so the in-graph rng fold
             # reproduces the identical draws — no progress is lost
             for e in entries[:bad_at]:
-                state, _ = self._dispatch(state, e.batch, e.rng,
-                                          tag=f"replay@{e.idx}")
+                state, _ = self._dispatch(state, e.batch, e.rng, e.idx,
+                                          tag="replay")
                 self.replayed_steps += 1
             # synchronous guarded retry at the SAME step coordinate so
             # fault injection / logging line up with the stream position
@@ -584,12 +604,12 @@ class PipelinedLoop:
             # the suffix ran on the poisoned lineage — re-dispatch fresh
             redo, entries = entries[bad_at + 1:], []
             for e in redo:
-                state, aux = self._dispatch(state, e.batch, e.rng,
-                                            tag=f"redo@{e.idx}")
+                state, aux = self._dispatch(state, e.batch, e.rng, e.idx,
+                                            tag="redo")
                 self.replayed_steps += 1
                 entries.append(_Entry(e.idx, e.batch, e.rng, aux))
         # window verified end-to-end: retain its snapshot for the next one
-        self._win_snapshot = host_copy(state)
+        self._win_snapshot = self._snapshot(state, self._idx)
         return state, ready, ok
 
     def stats(self) -> Dict[str, Any]:
@@ -597,6 +617,8 @@ class PipelinedLoop:
             "aux_interval": self.aux_interval,
             "steps": self._idx if self.aux_interval > 1 else self.guard.step_index,
             "flushes": self.flushes,
+            "snapshots": self.snapshots,
+            "snapshot_ms": round(self.snapshot_s * 1e3, 3),
             "window_rollbacks": self.window_rollbacks,
             "replayed_steps": self.replayed_steps,
             "retried_steps": self.guard.retried_steps,

@@ -215,13 +215,16 @@ def make_train_step(
             aux = jax.lax.pmean(
                 {k: v.astype(jnp.float32) for k, v in aux.items()}, pmean_axis
             )
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        if lr_scale is not None:
-            s = jnp.asarray(lr_scale, jnp.float32)
-            updates = jax.tree_util.tree_map(
-                lambda u: u * s.astype(u.dtype), updates
-            )
-        params = optax.apply_updates(state.params, updates)
+        # clip, weight decay, momentum, apply: one stage of the device
+        # trace (utils/tracing.py :: TRAIN_SCOPES); metadata only
+        with jax.named_scope("update"):
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            if lr_scale is not None:
+                s = jnp.asarray(lr_scale, jnp.float32)
+                updates = jax.tree_util.tree_map(
+                    lambda u: u * s.astype(u.dtype), updates
+                )
+            params = optax.apply_updates(state.params, updates)
         new_state = TrainState(state.step + 1, params, opt_state)
         return new_state, aux
 

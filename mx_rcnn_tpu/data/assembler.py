@@ -38,6 +38,8 @@ from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, Iterable, Iterator, Optional
 
+from mx_rcnn_tpu.utils import tracing
+
 __all__ = [
     "AssemblyPool",
     "CompletionPool",
@@ -91,7 +93,11 @@ class _OrderedResults:
         fut = self._q.popleft()
         t0 = time.perf_counter()
         ready = fut.done()
-        out = fut.result()  # re-raises the worker exception in order
+        if ready:
+            out = fut.result()  # re-raises the worker exception in order
+        else:
+            with tracing.span(tracing.LOADER_WAIT):
+                out = fut.result()
         self._pool._account_get(ready, time.perf_counter() - t0,
                                 len(self._q))
         return out
@@ -141,7 +147,8 @@ class _InlineResults:
         item = next(self._items)
         self._pool.submitted += 1
         t0 = time.perf_counter()
-        out = self._fn(item)
+        with tracing.span(tracing.LOADER_WAIT):
+            out = self._fn(item)
         self._pool.completed += 1
         self._pool._account_get(False, time.perf_counter() - t0, 0)
         return out
@@ -320,7 +327,10 @@ class CompletionPool:
                 raise
             return
         t0 = time.perf_counter()
-        self._sem.acquire()
+        if not self._sem.acquire(blocking=False):
+            with tracing.span(tracing.SERVE_SLOT_WAIT,
+                              batch=tracing.current_batch()):
+                self._sem.acquire()
         blocked = time.perf_counter() - t0
 
         def run():

@@ -32,7 +32,7 @@ from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.core.resilience import RetryPolicy, make_retry_policy
 from mx_rcnn_tpu.data.assembler import AssemblyPool, default_assembly_workers
 from mx_rcnn_tpu.data.image import load_image, pick_bucket, prepare_image
-from mx_rcnn_tpu.utils import faults
+from mx_rcnn_tpu.utils import faults, tracing
 
 logger = logging.getLogger(__name__)
 
@@ -546,14 +546,21 @@ class TrainLoader:
     def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:
         plan = self._epoch_plan(self.epoch)
         self.epoch += 1
-        if self.skip_batches:
-            plan = plan[self.skip_batches:]
+        first = self.skip_batches
+        if first:
+            plan = plan[first:]
             self.skip_batches = 0
         if self.row_slice is not None:
             plan = [(b, idxs[self.row_slice]) for b, idxs in plan]
         pc = self.proposal_count
+        # the plan index rides along as the assembly span's ``batch`` id
+        plan = [(first + i, b, idxs) for i, (b, idxs) in enumerate(plan)]
 
-        def build(bucket, idxs):
+        def build(index, bucket, idxs):
+            with tracing.span(tracing.LOADER_ASSEMBLE, batch=index):
+                return assemble(bucket, idxs)
+
+        def assemble(bucket, idxs):
             images = [self._load_guarded(i) for i in idxs]
             good = [(i, im) for i, im in zip(idxs, images) if im is not None]
             if not good:
@@ -600,8 +607,8 @@ class TrainLoader:
             )
         source = (
             batch
-            for bucket, idxs in plan
-            if (batch := build(bucket, idxs)) is not None
+            for entry in plan
+            if (batch := build(*entry)) is not None
         )
         # a real PrefetchIterator (not a generator) so consumers that
         # stop early — or the DeviceFeed stage stacked on top — can

@@ -150,34 +150,42 @@ class FasterRCNN(nn.Module):
         else:
             keys = jax.random.split(key, (b, 2))
 
+        # the named scopes below are the stage names a device trace is
+        # read by (utils/tracing.py :: TRAIN_SCOPES); flax opens
+        # ``backbone``, ``rpn`` and ``rcnn`` itself.  Metadata only.
         # --- RPN anchor targets (reference: rcnn/io/rpn.py :: assign_anchor)
-        atgt = jax.vmap(
-            lambda gtb, gtv, info, k: assign_anchor(anchors, gtb[:, :4], gtv, info, k, cfg)
-        )(gt_boxes, gt_valid, im_info, keys[:, 0])
+        with jax.named_scope("anchor_targets"):
+            atgt = jax.vmap(
+                lambda gtb, gtv, info, k: assign_anchor(anchors, gtb[:, :4], gtv, info, k, cfg)
+            )(gt_boxes, gt_valid, im_info, keys[:, 0])
 
         # --- proposals (stop-gradient: reference proposal op has no backward)
-        fg_scores = jax.nn.softmax(rpn_logits, axis=-1)[..., 1]
-        props = jax.vmap(
-            lambda s, d, info: propose(
-                s,
-                d,
-                anchors,
-                info,
-                t.RPN_PRE_NMS_TOP_N,
-                t.RPN_POST_NMS_TOP_N,
-                t.RPN_NMS_THRESH,
-                t.RPN_MIN_SIZE,
-            )
-        )(jax.lax.stop_gradient(fg_scores), jax.lax.stop_gradient(rpn_deltas), im_info)
+        with jax.named_scope("proposal"):
+            fg_scores = jax.nn.softmax(rpn_logits, axis=-1)[..., 1]
+            props = jax.vmap(
+                lambda s, d, info: propose(
+                    s,
+                    d,
+                    anchors,
+                    info,
+                    t.RPN_PRE_NMS_TOP_N,
+                    t.RPN_POST_NMS_TOP_N,
+                    t.RPN_NMS_THRESH,
+                    t.RPN_MIN_SIZE,
+                )
+            )(jax.lax.stop_gradient(fg_scores), jax.lax.stop_gradient(rpn_deltas), im_info)
 
         # --- sample rois + RCNN targets (reference: proposal_target CustomOp)
-        samples = jax.vmap(
-            lambda r, rv, gtb, gtv, k: sample_rois(r, rv, gtb, gtv, k, cfg)
-        )(props.rois, props.valid, gt_boxes, gt_valid, keys[:, 1])
+        with jax.named_scope("roi_sample"):
+            samples = jax.vmap(
+                lambda r, rv, gtb, gtv, k: sample_rois(r, rv, gtb, gtv, k, cfg)
+            )(props.rois, props.valid, gt_boxes, gt_valid, keys[:, 1])
 
-        # --- second stage
-        trunk = self._roi_features(feat, samples.rois)     # (B*R, D)
-        cls_logits, bbox_pred_out = self.rcnn(trunk)       # (B*R, K), (B*R, 4K)
+        # --- second stage (the ROIAlign kernels stay innermost-scoped by
+        # flax's ``FasterRCNN._roi_features``: the benchmark finds them so)
+        with jax.named_scope("roi_head"):
+            trunk = self._roi_features(feat, samples.rois)     # (B*R, D)
+            cls_logits, bbox_pred_out = self.rcnn(trunk)       # (B*R, K), (B*R, 4K)
 
         labels = samples.labels.reshape(-1)
         bbox_targets = samples.bbox_targets.reshape(bbox_pred_out.shape)
@@ -186,21 +194,22 @@ class FasterRCNN(nn.Module):
         # --- losses, reference normalization semantics (SURVEY §4.5)
         rpn_norm = float(t.RPN_BATCH_SIZE * b)
         rcnn_norm = float(t.BATCH_ROIS * b)
-        rpn_cls_loss = softmax_cross_entropy(
-            rpn_logits.reshape(-1, 2), atgt.labels.reshape(-1), -1, rpn_norm
-        )
-        rpn_bbox_loss = weighted_smooth_l1(
-            rpn_deltas.reshape(-1, 4),
-            atgt.bbox_targets.reshape(-1, 4),
-            atgt.bbox_weights.reshape(-1, 4),
-            sigma=3.0,
-            norm=rpn_norm,
-        )
-        rcnn_cls_loss = softmax_cross_entropy(cls_logits, labels, -1, rcnn_norm)
-        rcnn_bbox_loss = weighted_smooth_l1(
-            bbox_pred_out, bbox_targets, bbox_weights, sigma=1.0, norm=rcnn_norm
-        )
-        total = rpn_cls_loss + rpn_bbox_loss + rcnn_cls_loss + rcnn_bbox_loss
+        with jax.named_scope("losses"):
+            rpn_cls_loss = softmax_cross_entropy(
+                rpn_logits.reshape(-1, 2), atgt.labels.reshape(-1), -1, rpn_norm
+            )
+            rpn_bbox_loss = weighted_smooth_l1(
+                rpn_deltas.reshape(-1, 4),
+                atgt.bbox_targets.reshape(-1, 4),
+                atgt.bbox_weights.reshape(-1, 4),
+                sigma=3.0,
+                norm=rpn_norm,
+            )
+            rcnn_cls_loss = softmax_cross_entropy(cls_logits, labels, -1, rcnn_norm)
+            rcnn_bbox_loss = weighted_smooth_l1(
+                bbox_pred_out, bbox_targets, bbox_weights, sigma=1.0, norm=rcnn_norm
+            )
+            total = rpn_cls_loss + rpn_bbox_loss + rcnn_cls_loss + rcnn_bbox_loss
 
         aux = {
             # the reference's six metrics (rcnn/core/metric.py), same names
@@ -241,43 +250,45 @@ class FasterRCNN(nn.Module):
         rpn_logits, rpn_deltas = self.rpn(feat)
         anchors = self._anchors(feat.shape[1], feat.shape[2])
 
-        fg_scores = jax.nn.softmax(rpn_logits, axis=-1)[..., 1]
-        # kill anchors sitting on bucket padding: their scores come from
-        # zero-padded features, so keeping them would make the pre-NMS
-        # top-k set (and thus detections) depend on which bucket the
-        # image padded into.  Inference-only — train keeps the full pool
-        # (its tuned gate trajectories assume it).
-        grid_ok = jax.vmap(
-            lambda info: anchor_grid_mask(
-                ((feat.shape[1], feat.shape[2]),),
-                (cfg.network.RPN_FEAT_STRIDE,),
-                cfg.network.NUM_ANCHORS,
-                info,
-            )
-        )(im_info)
-        fg_scores = jnp.where(grid_ok, fg_scores, _NEG_INF)
-        props = jax.vmap(
-            lambda s, d, info: propose(
-                s,
-                d,
-                anchors,
-                info,
-                te.RPN_PRE_NMS_TOP_N,
-                te.RPN_POST_NMS_TOP_N,
-                te.RPN_NMS_THRESH,
-                te.RPN_MIN_SIZE,
-            )
-        )(fg_scores, rpn_deltas, im_info)
+        with jax.named_scope("proposal"):
+            fg_scores = jax.nn.softmax(rpn_logits, axis=-1)[..., 1]
+            # kill anchors sitting on bucket padding: their scores come from
+            # zero-padded features, so keeping them would make the pre-NMS
+            # top-k set (and thus detections) depend on which bucket the
+            # image padded into.  Inference-only — train keeps the full pool
+            # (its tuned gate trajectories assume it).
+            grid_ok = jax.vmap(
+                lambda info: anchor_grid_mask(
+                    ((feat.shape[1], feat.shape[2]),),
+                    (cfg.network.RPN_FEAT_STRIDE,),
+                    cfg.network.NUM_ANCHORS,
+                    info,
+                )
+            )(im_info)
+            fg_scores = jnp.where(grid_ok, fg_scores, _NEG_INF)
+            props = jax.vmap(
+                lambda s, d, info: propose(
+                    s,
+                    d,
+                    anchors,
+                    info,
+                    te.RPN_PRE_NMS_TOP_N,
+                    te.RPN_POST_NMS_TOP_N,
+                    te.RPN_NMS_THRESH,
+                    te.RPN_MIN_SIZE,
+                )
+            )(fg_scores, rpn_deltas, im_info)
 
         # one ladder-wide shape into roi_align so the second stage is the
         # SAME program for every bucket (see layers.pad_feat_to_ladder)
         feat = pad_feat_to_ladder(
             feat, cfg.network.RCNN_FEAT_STRIDE, cfg.SHAPE_BUCKETS
         )
-        trunk = self._roi_features(
-            feat, props.rois, fwd_only=True, valid_hw=im_info[:, :2]
-        )
-        cls_logits, bbox_deltas = self.rcnn(trunk)
+        with jax.named_scope("roi_head"):
+            trunk = self._roi_features(
+                feat, props.rois, fwd_only=True, valid_hw=im_info[:, :2]
+            )
+            cls_logits, bbox_deltas = self.rcnn(trunk)
         b, r = images.shape[0], te.RPN_POST_NMS_TOP_N
         k = cfg.dataset.NUM_CLASSES
 

@@ -290,13 +290,14 @@ class FPNFasterRCNN(nn.Module):
         else:
             keys = jax.random.split(key, (b, 2))
 
-        atgt = jax.vmap(
-            lambda gtb, gtv, info, k: assign_anchor(
-                anchors, gtb[:, :4], gtv, info, k, cfg
-            )
-        )(gt_boxes, gt_valid, im_info, keys[:, 0])
+        # stage scopes as in FasterRCNN.train_forward (metadata only)
+        with jax.named_scope("anchor_targets"):
+            atgt = jax.vmap(
+                lambda gtb, gtv, info, k: assign_anchor(
+                    anchors, gtb[:, :4], gtv, info, k, cfg
+                )
+            )(gt_boxes, gt_valid, im_info, keys[:, 0])
 
-        fg_scores = jax.nn.softmax(rpn_logits, axis=-1)[..., 1]
         if proposals is not None:
             # frozen-proposal mode (ROIIter role / churn ablation): the
             # RCNN+mask branches train on an EXTERNAL fixed proposal set
@@ -311,44 +312,50 @@ class FPNFasterRCNN(nn.Module):
         else:
             n_levels = len(bounds) - 1
             pre_per_level = max(t.RPN_PRE_NMS_TOP_N // n_levels, 256)
-            prop_boxes, prop_scores, prop_valid = jax.vmap(
-                lambda s, d, info: self._propose_multilevel(
-                    s, d, anchors, bounds, info, pre_per_level,
-                    t.RPN_POST_NMS_TOP_N, t.RPN_NMS_THRESH, t.RPN_MIN_SIZE,
+            with jax.named_scope("proposal"):
+                fg_scores = jax.nn.softmax(rpn_logits, axis=-1)[..., 1]
+                prop_boxes, prop_scores, prop_valid = jax.vmap(
+                    lambda s, d, info: self._propose_multilevel(
+                        s, d, anchors, bounds, info, pre_per_level,
+                        t.RPN_POST_NMS_TOP_N, t.RPN_NMS_THRESH,
+                        t.RPN_MIN_SIZE,
+                    )
+                )(
+                    jax.lax.stop_gradient(fg_scores),
+                    jax.lax.stop_gradient(rpn_deltas),
+                    im_info,
                 )
-            )(
-                jax.lax.stop_gradient(fg_scores),
-                jax.lax.stop_gradient(rpn_deltas),
-                im_info,
-            )
 
-        samples = jax.vmap(
-            lambda r, rv, gtb, gtv, k: sample_rois(r, rv, gtb, gtv, k, cfg)
-        )(prop_boxes, prop_valid, gt_boxes, gt_valid, keys[:, 1])
+        with jax.named_scope("roi_sample"):
+            samples = jax.vmap(
+                lambda r, rv, gtb, gtv, k: sample_rois(r, rv, gtb, gtv, k, cfg)
+            )(prop_boxes, prop_valid, gt_boxes, gt_valid, keys[:, 1])
 
-        trunk = self._roi_features(pyramid, samples.rois)
-        cls_logits, bbox_pred_out = self.rcnn(trunk)
+        with jax.named_scope("roi_head"):
+            trunk = self._roi_features(pyramid, samples.rois)
+            cls_logits, bbox_pred_out = self.rcnn(trunk)
         labels = samples.labels.reshape(-1)
         bbox_targets = samples.bbox_targets.reshape(bbox_pred_out.shape)
         bbox_weights = samples.bbox_weights.reshape(bbox_pred_out.shape)
 
         rpn_norm = float(t.RPN_BATCH_SIZE * b)
         rcnn_norm = float(t.BATCH_ROIS * b)
-        rpn_cls_loss = softmax_cross_entropy(
-            rpn_logits.reshape(-1, 2), atgt.labels.reshape(-1), -1, rpn_norm
-        )
-        rpn_bbox_loss = weighted_smooth_l1(
-            rpn_deltas.reshape(-1, 4),
-            atgt.bbox_targets.reshape(-1, 4),
-            atgt.bbox_weights.reshape(-1, 4),
-            sigma=3.0,
-            norm=rpn_norm,
-        )
-        rcnn_cls_loss = softmax_cross_entropy(cls_logits, labels, -1, rcnn_norm)
-        rcnn_bbox_loss = weighted_smooth_l1(
-            bbox_pred_out, bbox_targets, bbox_weights, sigma=1.0, norm=rcnn_norm
-        )
-        total = rpn_cls_loss + rpn_bbox_loss + rcnn_cls_loss + rcnn_bbox_loss
+        with jax.named_scope("losses"):
+            rpn_cls_loss = softmax_cross_entropy(
+                rpn_logits.reshape(-1, 2), atgt.labels.reshape(-1), -1, rpn_norm
+            )
+            rpn_bbox_loss = weighted_smooth_l1(
+                rpn_deltas.reshape(-1, 4),
+                atgt.bbox_targets.reshape(-1, 4),
+                atgt.bbox_weights.reshape(-1, 4),
+                sigma=3.0,
+                norm=rpn_norm,
+            )
+            rcnn_cls_loss = softmax_cross_entropy(cls_logits, labels, -1, rcnn_norm)
+            rcnn_bbox_loss = weighted_smooth_l1(
+                bbox_pred_out, bbox_targets, bbox_weights, sigma=1.0, norm=rcnn_norm
+            )
+            total = rpn_cls_loss + rpn_bbox_loss + rcnn_cls_loss + rcnn_bbox_loss
 
         aux = {
             "RPNAcc": accuracy(rpn_logits.reshape(-1, 2), atgt.labels.reshape(-1)),
@@ -385,27 +392,28 @@ class FPNFasterRCNN(nn.Module):
         pad_mask = make_pad_mask(im_info, (images.shape[1], images.shape[2]))
         pyramid = [pad_mask(p) for p in self._pyramid(images, pad_mask)]
         rpn_logits, rpn_deltas, anchors, bounds = self._rpn_over_levels(pyramid)
-        fg_scores = jax.nn.softmax(rpn_logits, axis=-1)[..., 1]
-        # padding-invariance (see FasterRCNN.test_forward): drop anchors
-        # whose grid cell lies in the bucket padding, per level
-        shapes = tuple((p.shape[1], p.shape[2]) for p in pyramid)
-        a_per_cell = len(cfg.network.ANCHOR_RATIOS) * len(
-            cfg.network.FPN_ANCHOR_SCALES
-        )
-        grid_ok = jax.vmap(
-            lambda info: anchor_grid_mask(
-                shapes, cfg.network.FPN_FEAT_STRIDES, a_per_cell, info
+        with jax.named_scope("proposal"):
+            fg_scores = jax.nn.softmax(rpn_logits, axis=-1)[..., 1]
+            # padding-invariance (see FasterRCNN.test_forward): drop anchors
+            # whose grid cell lies in the bucket padding, per level
+            shapes = tuple((p.shape[1], p.shape[2]) for p in pyramid)
+            a_per_cell = len(cfg.network.ANCHOR_RATIOS) * len(
+                cfg.network.FPN_ANCHOR_SCALES
             )
-        )(im_info)
-        fg_scores = jnp.where(grid_ok, fg_scores, _NEG_INF)
-        n_levels = len(bounds) - 1
-        pre_per_level = max(te.RPN_PRE_NMS_TOP_N // n_levels, 256)
-        rois, roi_scores, roi_valid = jax.vmap(
-            lambda s, d, info: self._propose_multilevel(
-                s, d, anchors, bounds, info, pre_per_level,
-                te.RPN_POST_NMS_TOP_N, te.RPN_NMS_THRESH, te.RPN_MIN_SIZE,
-            )
-        )(fg_scores, rpn_deltas, im_info)
+            grid_ok = jax.vmap(
+                lambda info: anchor_grid_mask(
+                    shapes, cfg.network.FPN_FEAT_STRIDES, a_per_cell, info
+                )
+            )(im_info)
+            fg_scores = jnp.where(grid_ok, fg_scores, _NEG_INF)
+            n_levels = len(bounds) - 1
+            pre_per_level = max(te.RPN_PRE_NMS_TOP_N // n_levels, 256)
+            rois, roi_scores, roi_valid = jax.vmap(
+                lambda s, d, info: self._propose_multilevel(
+                    s, d, anchors, bounds, info, pre_per_level,
+                    te.RPN_POST_NMS_TOP_N, te.RPN_NMS_THRESH, te.RPN_MIN_SIZE,
+                )
+            )(fg_scores, rpn_deltas, im_info)
 
         # one ladder-wide shape per level into roi_align so the second
         # stage is the SAME program for every bucket (see
@@ -416,10 +424,11 @@ class FPNFasterRCNN(nn.Module):
             pad_feat_to_ladder(p, s, cfg.SHAPE_BUCKETS)
             for p, s in zip(pyramid[:4], cfg.network.FPN_FEAT_STRIDES[:4])
         ] + pyramid[4:]
-        trunk = self._roi_features(
-            pyramid, rois, fwd_only=True, valid_hw=im_info[:, :2]
-        )
-        cls_logits, bbox_deltas = self.rcnn(trunk)
+        with jax.named_scope("roi_head"):
+            trunk = self._roi_features(
+                pyramid, rois, fwd_only=True, valid_hw=im_info[:, :2]
+            )
+            cls_logits, bbox_deltas = self.rcnn(trunk)
         r = te.RPN_POST_NMS_TOP_N
         means, stds = bbox_denorm_vectors(cfg, k)
         bbox_deltas = bbox_deltas * stds[None, :] + means[None, :]

@@ -215,6 +215,7 @@ def nms_mask_sorted_pallas(
         input_output_aliases={1: 0},
         scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
+        name="pallas_nms_mask",  # the kernel's name in a device trace
     )(coords, keep0)
     return keep[0, :n] > 0.5
 

@@ -283,6 +283,7 @@ def _fwd_impl(feat, rois, pooled, scale, s, interpret, rblk=None):
             (b, r, pooled[0], pooled[1], c), feat.dtype, rois_p, feat
         ),
         interpret=interpret,
+        name="pallas_roi_features_stream_fwd",
     )(rois_p.astype(jnp.float32).transpose(0, 2, 1), feat)
     return out[:, :r_true]
 
@@ -325,6 +326,7 @@ def _bwd_impl(feat_shape, feat_dtype, rois, g, pooled, scale, s, interpret,
         ),
         out_shape=out_struct((b, hf, wf, c), jnp.float32, rois_p, g),
         interpret=interpret,
+        name="pallas_roi_features_stream_bwd",
     )(rois_p.astype(jnp.float32).transpose(0, 2, 1), g)
     return out.astype(feat_dtype)
 

@@ -86,41 +86,45 @@ def make_test_postprocess(
 
     def one_image(rois, valid, scores, deltas, info, ohw):
         r, k = scores.shape
-        boxes = bbox_pred(rois, deltas)                      # (R, 4K)
-        boxes = clip_boxes(boxes, (info[0], info[1]))
-        boxes = clip_boxes(boxes / info[2], (ohw[0], ohw[1]))
-        # foreground classes on the leading axis for the shared
-        # batched per-class NMS helper
-        boxes_k = boxes.reshape(r, k, 4).transpose(1, 0, 2)[1:]   # (K-1, R, 4)
-        scores_k = scores.T[1:]                                   # (K-1, R)
-        valid_k = valid[None, :] & (scores_k > thresh)
-        return batched_class_nms(
-            boxes_k, scores_k, te.NMS, max_out, valid_k, with_idx=True
-        )
+        with jax.named_scope("decode"):
+            boxes = bbox_pred(rois, deltas)                      # (R, 4K)
+            boxes = clip_boxes(boxes, (info[0], info[1]))
+            boxes = clip_boxes(boxes / info[2], (ohw[0], ohw[1]))
+            # foreground classes on the leading axis for the shared
+            # batched per-class NMS helper
+            boxes_k = boxes.reshape(r, k, 4).transpose(1, 0, 2)[1:]   # (K-1, R, 4)
+            scores_k = scores.T[1:]                                   # (K-1, R)
+            valid_k = valid[None, :] & (scores_k > thresh)
+        with jax.named_scope("class_nms"):
+            return batched_class_nms(
+                boxes_k, scores_k, te.NMS, max_out, valid_k, with_idx=True
+            )
 
     def one_image_masks(ob, os_, ov, oi, mask_logits):
         # (K-1, max_out) det grid → flat cross-class top-max_det by
         # score; ties break toward the lower flat index (top_k), which
         # only diverges from the host cap on exact float score ties.
         r = mask_logits.shape[0]
-        flat_scores = jnp.where(ov, os_, _NEG_INF).reshape(-1)
-        top_s, top_flat = jax.lax.top_k(flat_scores, max_det)
-        mvalid = top_s > _NEG_INF / 2
-        # survivor's source roi (per-class nms idx may exceed R on
-        # padding slots — clamp before the gather) and class channel
-        roi_idx = jnp.clip(oi.reshape(-1)[top_flat], 0, r - 1)
-        roi_idx = jnp.where(mvalid, roi_idx, 0)
-        cls = jnp.where(mvalid, top_flat // ov.shape[1] + 1, 1)
-        grids = jax.vmap(lambda ri, c: mask_logits[ri, :, :, c])(
-            roi_idx, cls
-        )
-        # large-negative logits on padding rows: padding-count invariant
-        # AND safe if one ever leaks to paste (sigmoid ≈ 0, empty mask,
-        # no exp overflow on host)
-        grids = jnp.where(
-            mvalid[:, None, None], grids, jnp.float32(-80.0)
-        ).astype(jnp.float32)
-        midx = jnp.where(mvalid, top_flat, -1).astype(jnp.int32)
+        with jax.named_scope("cap"):
+            flat_scores = jnp.where(ov, os_, _NEG_INF).reshape(-1)
+            top_s, top_flat = jax.lax.top_k(flat_scores, max_det)
+            mvalid = top_s > _NEG_INF / 2
+        with jax.named_scope("mask_select"):
+            # survivor's source roi (per-class nms idx may exceed R on
+            # padding slots — clamp before the gather) and class channel
+            roi_idx = jnp.clip(oi.reshape(-1)[top_flat], 0, r - 1)
+            roi_idx = jnp.where(mvalid, roi_idx, 0)
+            cls = jnp.where(mvalid, top_flat // ov.shape[1] + 1, 1)
+            grids = jax.vmap(lambda ri, c: mask_logits[ri, :, :, c])(
+                roi_idx, cls
+            )
+            # large-negative logits on padding rows: padding-count invariant
+            # AND safe if one ever leaks to paste (sigmoid ≈ 0, empty mask,
+            # no exp overflow on host)
+            grids = jnp.where(
+                mvalid[:, None, None], grids, jnp.float32(-80.0)
+            ).astype(jnp.float32)
+            midx = jnp.where(mvalid, top_flat, -1).astype(jnp.int32)
         return grids, midx, mvalid
 
     def one_image_paste(ob, oi_flat, grids, mvalid, info, canvas_hw):
@@ -190,6 +194,12 @@ def make_test_postprocess(
         return jax.vmap(paste_one)(q, x1i, y1i, x2i, y2i, bw, bh, mvalid)
 
     def batched(out: Dict, im_info, orig_hw, canvas_hw=None):
+        # stage scopes of the serve graph's tail, as a device trace is
+        # read by them (utils/tracing.py :: SERVE_SCOPES): metadata only
+        with jax.named_scope("postprocess"):
+            return _batched(out, im_info, orig_hw, canvas_hw)
+
+    def _batched(out: Dict, im_info, orig_hw, canvas_hw):
         ob, os_, ov, oi = jax.vmap(one_image)(
             out["rois"],
             out["roi_valid"].astype(bool),
@@ -207,11 +217,12 @@ def make_test_postprocess(
             res["det_mask_idx"] = midx
             res["det_mask_valid"] = mvalid
             if paste and canvas_hw is not None:
-                res["det_canvas"] = jax.vmap(
-                    lambda b, i, g, m, info: one_image_paste(
-                        b, i, g, m, info, tuple(canvas_hw)
-                    )
-                )(ob, midx, grids, mvalid, im_info)
+                with jax.named_scope("mask_paste"):
+                    res["det_canvas"] = jax.vmap(
+                        lambda b, i, g, m, info: one_image_paste(
+                            b, i, g, m, info, tuple(canvas_hw)
+                        )
+                    )(ob, midx, grids, mvalid, im_info)
         return res
 
     # the Predictor passes the traced image extent as canvas_hw only to

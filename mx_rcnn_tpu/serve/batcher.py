@@ -103,6 +103,7 @@ class Request:
     deadline: Optional[float] = None     # absolute monotonic, or None
     future: Future = field(default_factory=Future)
     picked_t: float = 0.0                # set by next_batch (queue-wait metric)
+    seq: int = 0                         # process-wide number (engine-set; trace id)
     model: Optional[str] = None          # registry model id (None = default)
     lane: str = DEFAULT_LANE             # SLO class: "interactive" | "bulk"
     cache_key: Optional[Tuple] = None    # response-cache key (engine-set)

@@ -39,6 +39,7 @@ fraction scales the effective queue capacity below the current backlog.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from concurrent.futures import Future, InvalidStateError
@@ -69,6 +70,7 @@ from mx_rcnn_tpu.serve.quarantine import (
 )
 from mx_rcnn_tpu.serve.runner import ServeRunner
 from mx_rcnn_tpu.serve.streams import StreamTable
+from mx_rcnn_tpu.utils import tracing
 
 # DeadlineExceeded historically lived here; it moved to serve.batcher so
 # the expired-request sweep can raise it without a circular import, and
@@ -79,6 +81,10 @@ __all__ = [
     "DeadlineExceeded", "EngineStopped", "ServingEngine",
     "InvalidRequest", "PoisonRequest", "RetriesExhausted",
 ]
+
+
+#: process-wide request numbers, drawn at submit (the spans' ``req`` id)
+_REQUEST_SEQ = itertools.count(1)
 
 
 class EngineStopped(RuntimeError):
@@ -405,6 +411,27 @@ class ServingEngine:
         frame: Optional[int] = None,
         masks: bool = False,
     ) -> Future:
+        """Enqueue one image; see :meth:`_submit` for the contract.  The
+        request's process-wide number is drawn here, so the caller-thread
+        span and every later ``rcnn.serve.pickup`` name the same ``req``."""
+        seq = next(_REQUEST_SEQ)
+        with tracing.span(tracing.SERVE_PREPARE, req=seq):
+            return self._submit(
+                seq, im, deadline_s, model, lane, tenant, stream, frame, masks
+            )
+
+    def _submit(
+        self,
+        seq: int,
+        im: np.ndarray,
+        deadline_s: Optional[float],
+        model: Optional[str],
+        lane: Optional[str],
+        tenant: Optional[str],
+        stream: Optional[str],
+        frame: Optional[int],
+        masks: bool,
+    ) -> Future:
         """Enqueue one image; returns a Future resolving to the
         per-class detections list.  ``model`` selects a registry family
         (None = the default model — the tenancy request schema);
@@ -618,6 +645,7 @@ class ServingEngine:
                 req = self.runner.make_request(
                     im, deadline=deadline, model=serve_model
                 )
+            req.seq = seq
             req.lane = lane
             req.tenant = tenant
             req.cache_key = cache_key
@@ -743,8 +771,10 @@ class ServingEngine:
         return self.streams.settle(req.stream, req.frame, fire)
 
     def _assemble_loop(self) -> None:
+        batch_numbers = itertools.count(1)
         while True:
-            batch_reqs = self.batcher.next_batch()
+            with tracing.span(tracing.SERVE_BATCH_WAIT):
+                batch_reqs = self.batcher.next_batch()
             if batch_reqs is None:
                 return
             if self._aborting:
@@ -773,16 +803,32 @@ class ServingEngine:
             self.metrics.record_queue_depth(self.batcher.pending())
             if not live:
                 continue
+            number = next(batch_numbers)
+            tracing.set_batch(number)
+            if tracing.enabled():
+                # the same subtraction queue_wait records, written onto
+                # the trace beside the ids that join the batch's stages
+                waits = [
+                    round((r.picked_t - r.enqueue_t) * 1e3, 3) for r in live
+                ]
+                with tracing.span(
+                    tracing.SERVE_PICKUP, batch=number, n=len(live),
+                    reqs=[r.seq for r in live], wait_ms=max(waits),
+                    wait_ms_each=waits,
+                ):
+                    pass
             batch = self.runner.assemble(live)
             # pool submit blocks at depth=in_flight: at most in_flight
             # batches on the device (the old explicit semaphore)
-            self._pool.submit(self._complete, live, batch)
+            self._pool.submit(self._complete, live, batch, number)
 
     def _complete(
-        self, reqs: List[Request], batch: Dict[str, np.ndarray]
+        self, reqs: List[Request], batch: Dict[str, np.ndarray],
+        number: int = 0,
     ) -> None:
         # runs on a completion-pool worker; the pool's depth slot is
         # released when this returns, unblocking the assembler
+        tracing.set_batch(number)
         t0 = time.monotonic()
         model = reqs[0].model
         lane = reqs[0].lane
@@ -818,6 +864,17 @@ class ServingEngine:
         self.metrics.service.record(done - t0)
         self.metrics.record_batch(len(reqs), self.runner.max_batch)
         self.metrics.record_lane_batch(lane, len(reqs), self.runner.max_batch)
+        with tracing.span(
+            tracing.SERVE_POSTPROCESS, batch=number, n=len(reqs)
+        ):
+            self._deliver(reqs, batch, out, mkw, arm_ver, served_version)
+
+    def _deliver(
+        self, reqs: List[Request], batch: Dict[str, np.ndarray], out,
+        mkw: Dict, arm_ver: Optional[int], served_version: Optional[int],
+    ) -> None:
+        """Per-request postprocess and resolution of a fetched batch."""
+        model = reqs[0].model
         for k, r in enumerate(reqs):
             # deadline re-check at completion: a request that expired
             # while its batch waited behind a slow/hedged predict must
@@ -1046,6 +1103,7 @@ class ServingEngine:
             self._fail_one(req, e)
             return
         req2.future = req.future
+        req2.seq = req.seq
         req2.lane = req.lane
         req2.tenant = req.tenant
         req2.enqueue_t = req.enqueue_t  # e2e spans both passes

@@ -43,6 +43,7 @@ from mx_rcnn_tpu.native.hostops import nms_host
 from mx_rcnn_tpu.analysis.lockcheck import make_lock
 from mx_rcnn_tpu.serve.batcher import Request
 from mx_rcnn_tpu.serve.buckets import BucketLadder, CompileCache
+from mx_rcnn_tpu.utils import tracing
 
 ClsDets = List[Optional[np.ndarray]]  # [None, (n1, 5), ..., (nK-1, 5)]
 
@@ -684,6 +685,12 @@ class ServeRunner:
         """(model, bucket)-homogeneous requests → device batch padded to
         ``max_batch`` (pad slots replicate slot 0 so every bucket keeps a
         single jit signature and pad work is never a fresh codepath)."""
+        with tracing.span(tracing.SERVE_ASSEMBLE,
+                          batch=tracing.current_batch(),
+                          bucket=requests[0].bucket if requests else None):
+            return self._assemble(requests)
+
+    def _assemble(self, requests: List[Request]) -> Dict[str, np.ndarray]:
         n = len(requests)
         if not 0 < n <= self.max_batch:
             raise ValueError(f"batch of {n} vs max_batch={self.max_batch}")
@@ -754,14 +761,16 @@ class ServeRunner:
         one.  Adds no jit signatures beyond :meth:`run`'s: same bucket
         pad, same ``max_batch``, same compiled program."""
         mid = self.default_model if model is None else model
-        slot = self._slot(mid)
-        self._sync(slot)
-        sig = self._signature(batch, mid)
-        self.compile_cache.record(sig)
-        if self.layout_feed:
-            batch = self.stage(batch, mid)
-        bucket = tuple(batch["images"].shape[1:3])
-        outputs = slot.predictor.predict_async(batch)
+        with tracing.span(tracing.SERVE_DISPATCH,
+                          batch=tracing.current_batch()):
+            slot = self._slot(mid)
+            self._sync(slot)
+            sig = self._signature(batch, mid)
+            self.compile_cache.record(sig)
+            if self.layout_feed:
+                batch = self.stage(batch, mid)
+            bucket = tuple(batch["images"].shape[1:3])
+            outputs = slot.predictor.predict_async(batch)
         self.served_buckets.setdefault(mid, set()).add(bucket)
         self.split_dispatches += 1
         return ServeHandle(
@@ -776,7 +785,8 @@ class ServeRunner:
         (:meth:`detections_for` on the returned tree), unchanged from the
         blocking path."""
         t0 = time.monotonic()
-        out = host_copy(handle.outputs)
+        with tracing.span(tracing.SERVE_FETCH, batch=tracing.current_batch()):
+            out = host_copy(handle.outputs)
         self.fetch_stall_s += time.monotonic() - t0
         self.split_completes += 1
         nbytes = sum(

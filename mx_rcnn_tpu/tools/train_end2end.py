@@ -107,7 +107,10 @@ def parse_args(argv=None):
                         "interval (structured twin of the Speedometer log)")
     p.add_argument("--profile", default=None, metavar="DIR",
                    help="capture a jax.profiler trace of steps 10-20 into "
-                        "DIR (view with tensorboard/xprof)")
+                        "DIR (view with tensorboard/xprof): device ops under "
+                        "their stage scopes and the program's own rcnn.* "
+                        "host spans (utils/tracing.py) on one clock; "
+                        "benchmark/tools/idle_by_span.py DIR tabulates it")
     # resilience (core/resilience.py): divergence recovery + hang watchdog
     p.add_argument("--step_timeout", type=float, default=0.0, metavar="SECS",
                    help="wall-clock watchdog per train step: a step that "
@@ -177,8 +180,11 @@ def train_net(args, report=None):
     ``chip_smoke.py``): ``steps`` dispatched by the loop and
     ``steps_applied`` to the optimizer state, the guard's
     ``skipped_batches`` / ``retried_steps`` / ``rollbacks``, the last
-    verified ``losses`` as ``(step, loss)`` pairs, and on an elastic run
-    ``elastic`` / ``degraded``."""
+    verified ``losses`` as ``(step, loss)`` pairs, the host side's own
+    counters — ``feed`` (``DeviceFeed.stats()`` summed over the epochs'
+    feeds), ``pipeline`` (``PipelinedLoop.stats()``) and ``loader`` (the
+    assembly pool's stats, summed; empty on the serial loader) — and on
+    an elastic run ``elastic`` / ``degraded``."""
     import collections
 
     from mx_rcnn_tpu.utils.platform import cli_bootstrap
@@ -434,6 +440,18 @@ def train_net(args, report=None):
     preempted = False
     preempt_guard = PreemptionGuard()
 
+    feed_totals: collections.Counter = collections.Counter()
+    loader_totals: collections.Counter = collections.Counter()
+
+    def add_stats(totals, stats):
+        # sums over the epochs' feeds; ``depth``/``workers`` are settings
+        # and ``occupancy`` a ratio: the last epoch's stand
+        for k, v in stats.items():
+            if k in ("depth", "workers", "occupancy", "queue_depth_max"):
+                totals[k] = v
+            else:
+                totals[k] += v
+
     def deliver(ready):
         for idx, aux in ready:
             tracker.update({k: float(v) for k, v in aux.items()})
@@ -453,8 +471,9 @@ def train_net(args, report=None):
             if use_elastic:
                 epoch_pos["start_step"] = eloop.pipe.next_index
                 epoch_pos["off"] = batch_in_epoch
+            batches = iter(loader)
             feed = DeviceFeed(
-                iter(loader), place_fn=batch_place, depth=args.feed_depth
+                batches, place_fn=batch_place, depth=args.feed_depth
             )
             try:
                 for batch in feed:
@@ -491,6 +510,9 @@ def train_net(args, report=None):
                         break
             finally:
                 feed.close()
+                add_stats(feed_totals, feed.stats())
+                if hasattr(batches, "stats"):  # the assembly pool's stream
+                    add_stats(loader_totals, batches.stats())
             state = flush_pipeline(state)
             if preempted:
                 break
@@ -528,6 +550,17 @@ def train_net(args, report=None):
             logger.info(
                 "profiler trace (short run) written to %s", args.profile
             )
+        pipe_stats = pipeline.stats()
+        logger.info(
+            "host side: feed starved %d of %d gets after the first (waited "
+            "%.2fs), assembly pool starved %d of %d (waited %.2fs), %d state "
+            "snapshot(s) %.0f ms, %d fetch stall(s) %.0f ms",
+            feed_totals["feed_starved_after_first"], feed_totals["fed"],
+            feed_totals["wait_s"], loader_totals["starved_after_first"],
+            loader_totals["yielded"], loader_totals["wait_s"],
+            pipe_stats["snapshots"], pipe_stats["snapshot_ms"],
+            pipe_stats["fetch_stalls"], pipe_stats["fetch_stall_ms"],
+        )
         if report is not None:
             report.update(
                 steps=total_steps,
@@ -535,6 +568,9 @@ def train_net(args, report=None):
                 retried_steps=pipeline.retried_steps,
                 rollbacks=pipeline.rollbacks,
                 losses=list(losses),
+                feed=dict(feed_totals),
+                pipeline=pipe_stats,
+                loader=dict(loader_totals),
             )
         if use_elastic:
             if eloop.monitor.shrinks:
