@@ -153,7 +153,8 @@ def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
     input, on the device that will run them.  The tier-1 tests check the
     kernels' arithmetic in interpret mode; what Mosaic compiled from them
     only a chip can check.  ROIAlign (resident and streaming, forward and
-    backward) against the gather reference ``ops.roi_align.roi_align``;
+    backward; the resident forward with ``valid_hw`` as serving calls it)
+    against the gather reference ``ops.roi_align.roi_align``;
     NMS against the numpy oracle ``ops.nms.nms_numpy``, on boxes chosen
     so that no pair sits within 1e-4 of the IoU threshold."""
     import jax
@@ -182,11 +183,16 @@ def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
         return float(np.abs(got - ref).max() / np.abs(ref).max())
 
     cases = [
-        # (tag, kernel, feature map, rois/image, pooled, stride)
-        ("resident", roi_align_pallas, (2, 38, 64, 256), 64, (7, 7), 16),
-        ("stream", roi_align_stream, (1, 152, 256, 128), 128, (14, 14), 4),
+        # (tag, kernel, feature map, rois/image, pooled, stride, valid_hw)
+        # valid_hw: the serve graph's call, forward only, samples clamped
+        # to each image's own extent — one image fills a corner of the
+        # 608x1024 canvas, so rois lie across its edge and past it
+        ("resident", roi_align_pallas, (2, 38, 64, 256), 64, (7, 7), 16,
+         [[400.0, 700.0], [608.0, 1024.0]]),
+        ("stream", roi_align_stream, (1, 152, 256, 128), 128, (14, 14), 4,
+         None),
     ]
-    for tag, kernel, shape, n_rois, pooled, stride in cases:
+    for tag, kernel, shape, n_rois, pooled, stride, valid_hw in cases:
         b, h, w, c = shape
         scale = 1.0 / stride
         feat = jnp.asarray(rng.randn(*shape).astype(np.float32))
@@ -208,6 +214,18 @@ def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
         ):
             errs[f"{tag}_{dtype}_fwd"] = rel(out_grad[0], ref_out)
             errs[f"{tag}_{dtype}_bwd"] = rel(out_grad[1], ref_grad)
+        if valid_hw is None:
+            continue
+        valid_hw = jnp.asarray(valid_hw)
+        ref_out = np.asarray(jax.jit(jax.vmap(
+            lambda f1, r1, v1: roi_align(f1, r1, pooled, scale, 2,
+                                         valid_hw=v1)
+        ))(feat, rois, valid_hw))
+        clamped = jax.jit(lambda f: kernel(
+            f, rois, pooled, scale, 2, interpret, valid_hw=valid_hw))
+        for dtype, f in (("f32", feat), ("bf16", feat.astype(jnp.bfloat16))):
+            errs[f"{tag}_valid_hw_{dtype}_fwd"] = rel(
+                np.asarray(clamped(f), np.float32), ref_out)
 
     # NMS: a dense field of boxes, score-sorted as the proposal path hands
     # them over

@@ -90,16 +90,18 @@ class FasterRCNN(nn.Module):
     ) -> jnp.ndarray:
         """(B, Hf, Wf, C) × (B, R, 4) → (B*R, D) head trunk features."""
         net = self.cfg.network
-        pooled = extract_roi_features_batched(
-            feat,
-            rois,
-            net.ROI_MODE,
-            net.POOLED_SIZE,
-            1.0 / net.RCNN_FEAT_STRIDE,
-            net.ROI_SAMPLE_RATIO,
-            fwd_only=fwd_only,
-            valid_hw=valid_hw,
-        )
+        # closes before top_head: the scope's device time is the pooling's
+        with jax.named_scope("roi_align"):
+            pooled = extract_roi_features_batched(
+                feat,
+                rois,
+                net.ROI_MODE,
+                net.POOLED_SIZE,
+                1.0 / net.RCNN_FEAT_STRIDE,
+                net.ROI_SAMPLE_RATIO,
+                fwd_only=fwd_only,
+                valid_hw=valid_hw,
+            )
         b, r = pooled.shape[0], pooled.shape[1]
         return self.top_head(pooled.reshape((b * r,) + pooled.shape[2:]))
 

@@ -239,11 +239,12 @@ class FPNFasterRCNN(nn.Module):
         levels = roi_levels(rois)                        # (B, R) in [2, 5]
         pooled = None
         for li, stride in enumerate(net.FPN_FEAT_STRIDES[:4]):  # P2..P5
-            feats = extract_roi_features_batched(
-                pyramid[li], rois, "roi_align", net.POOLED_SIZE,
-                1.0 / stride, net.ROI_SAMPLE_RATIO, fwd_only=fwd_only,
-                valid_hw=valid_hw,
-            )                                            # (B, R, ph, pw, C)
+            with jax.named_scope("roi_align"):
+                feats = extract_roi_features_batched(
+                    pyramid[li], rois, "roi_align", net.POOLED_SIZE,
+                    1.0 / stride, net.ROI_SAMPLE_RATIO, fwd_only=fwd_only,
+                    valid_hw=valid_hw,
+                )                                        # (B, R, ph, pw, C)
             mask = (levels == li + 2)[..., None, None, None]
             contrib = jnp.where(mask, feats, 0.0)
             pooled = contrib if pooled is None else pooled + contrib
@@ -453,11 +454,12 @@ class FPNFasterRCNN(nn.Module):
         levels = roi_levels(rois)
         pooled = None
         for li, stride in enumerate(net.FPN_FEAT_STRIDES[:4]):
-            feats = extract_roi_features_batched(
-                pyramid[li], rois, "roi_align", (14, 14),
-                1.0 / stride, net.ROI_SAMPLE_RATIO, fwd_only=fwd_only,
-                valid_hw=valid_hw,
-            )
+            with jax.named_scope("roi_align"):
+                feats = extract_roi_features_batched(
+                    pyramid[li], rois, "roi_align", (14, 14),
+                    1.0 / stride, net.ROI_SAMPLE_RATIO, fwd_only=fwd_only,
+                    valid_hw=valid_hw,
+                )
             mask = (levels == li + 2)[..., None, None, None]
             contrib = jnp.where(mask, feats, 0.0)
             pooled = contrib if pooled is None else pooled + contrib

@@ -21,8 +21,14 @@ feature block stays resident in VMEM across the entire roi sweep, so HBM
 traffic is feat×(C/CBLK reads) + out, independent of R.
 
 Exactness: same edge semantics as ``ops.roi_align.roi_align`` (clip to
-[0, size-1], hi=lo+1 capped, roi w/h floored at 1) — validated against it
-in interpret mode by ``tests/test_pallas_roi_align.py``.
+[0, lim-1], hi=lo+1 capped at lim-1, roi w/h floored at 1) — validated
+against it in interpret mode by ``tests/test_pallas_roi_align.py``.
+``lim`` is the canvas extent, a Python constant, when ``valid_hw`` is
+None (training: one scalar-prefetch operand, the rois); with ``valid_hw``
+it is ``ops.roi_align._feat_limits``' per-image valid extent, handed to
+the same kernel body as a second scalar-prefetch operand (serving and
+eval: pooled features independent of the shape bucket, SERVING.md
+"Padding invariance").
 """
 
 from __future__ import annotations
@@ -35,57 +41,69 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from mx_rcnn_tpu.ops.pallas import out_struct
+from mx_rcnn_tpu.ops.roi_align import _feat_limits
 
 
-def _interp_matrix(lo_f, whi, size: int, nbins: int, s: int):
+def _interp_matrix(lo_f, whi, size: int, last, nbins: int, s: int):
     """Mean-of-samples one-hot interpolation matrix (nbins, size).
 
     ``lo_f``/``whi`` are (nbins*s,) f32 vectors of floor indices and
     hi-weights for each sample point; folds the 1/s sample average in.
+    ``last``: the last cell a sample may read (see :func:`_sample_coords`).
     """
     n = nbins * s
     # int iota cast to f32: Mosaic's tpu.iota only emits integer vectors
     cell = jax.lax.broadcasted_iota(jnp.int32, (n, size), 1).astype(jnp.float32)
     lo = lo_f.reshape(n, 1)
-    hi = jnp.minimum(lo + 1.0, float(size - 1))
+    hi = jnp.minimum(lo + 1.0, last)
     w1 = whi.reshape(n, 1)
     m = jnp.where(cell == lo, 1.0 - w1, 0.0) + jnp.where(cell == hi, w1, 0.0)
     # average the s sample rows of each bin
     return m.reshape(nbins, s, size).sum(axis=1) * (1.0 / s)
 
 
-def _sample_coords(c1, c2, size: int, nbins: int, s: int):
+def _sample_coords(c1, c2, last, nbins: int, s: int):
     """Sample-point floors/weights along one axis for one roi.
 
-    c1/c2: scaled roi edges (scalars).  Returns (lo_f (nbins*s,), whi)."""
+    c1/c2: scaled roi edges (scalars); ``last`` = limit − 1, the last
+    cell a sample may read: a Python float (the canvas) or an SMEM scalar
+    (the image's valid extent).  Returns (lo_f (nbins*s,), whi)."""
     length = jnp.maximum(c2 - c1, 1.0)
     bin_sz = length / nbins
     i = jax.lax.broadcasted_iota(jnp.int32, (nbins * s, 1), 0).astype(jnp.float32)
     g = c1 + (i + 0.5) / s * bin_sz                                  # (n, 1)
-    g = jnp.clip(g, 0.0, float(size - 1))
+    g = jnp.clip(g, 0.0, last)
     lo_f = jnp.floor(g)
     return lo_f, g - lo_f
 
 
-def _matrices_for_roi(rois_ref, b, r, hf: int, wf: int, pooled, s: int, scale: float):
+def _matrices_for_roi(rois_ref, lims_ref, b, r, hf: int, wf: int, pooled,
+                      s: int, scale: float):
     """``rois_ref`` is scalar-prefetched SMEM in (B, 4, R) layout — the
     coordinate dim must NOT be minor: SMEM pads the minor dim to 128
     lanes, so (B, R, 4) would blow up 32× and overflow the 1 MB SMEM at
-    eval roi counts (B=8, R=300 → 1.2 MB)."""
+    eval roi counts (B=8, R=300 → 1.2 MB).  ``lims_ref``: None (clamp to
+    the canvas) or the (2, B) per-image (rows, cols) limits, B minor for
+    the same reason."""
     ph, pw = pooled
+    if lims_ref is None:
+        last_y, last_x = float(hf - 1), float(wf - 1)
+    else:
+        last_y, last_x = lims_ref[0, b] - 1.0, lims_ref[1, b] - 1.0
     x1 = rois_ref[b, 0, r] * scale
     y1 = rois_ref[b, 1, r] * scale
     x2 = rois_ref[b, 2, r] * scale
     y2 = rois_ref[b, 3, r] * scale
-    ylo, ywhi = _sample_coords(y1, y2, hf, ph, s)
-    xlo, xwhi = _sample_coords(x1, x2, wf, pw, s)
-    my = _interp_matrix(ylo, ywhi, hf, ph, s)                        # (PH, H)
-    mx = _interp_matrix(xlo, xwhi, wf, pw, s)                        # (PW, W)
+    ylo, ywhi = _sample_coords(y1, y2, last_y, ph, s)
+    xlo, xwhi = _sample_coords(x1, x2, last_x, pw, s)
+    my = _interp_matrix(ylo, ywhi, hf, last_y, ph, s)                # (PH, H)
+    mx = _interp_matrix(xlo, xwhi, wf, last_x, pw, s)                # (PW, W)
     return my, mx
 
 
-def _fwd_kernel(rois_ref, feat_ref, out_ref, *, pooled, s, scale, rblk):
-    """Blocked forward: RBLK rois per grid step.
+def _fwd_kernel(rois_ref, *refs, pooled, s, scale, rblk):
+    """Blocked forward: RBLK rois per grid step.  ``refs`` is
+    ``[lims_ref,] feat_ref, out_ref``.
 
     The W-contraction (the majority of the flops — W ≥ H in every
     landscape bucket) runs once on a STACKED (RBLK·PW, W) interpolation
@@ -97,13 +115,15 @@ def _fwd_kernel(rois_ref, feat_ref, out_ref, *, pooled, s, scale, rblk):
     form emits (PH, PW, CB) directly — no in-kernel transpose.  Blocking
     the per-roi side would need a block-diagonal My whose 7/8 zero flops
     exactly cancel the utilization win."""
+    *lims, feat_ref, out_ref = refs  # the limits only with ``valid_hw``
+    lims_ref = lims[0] if lims else None
     b, rb = pl.program_id(0), pl.program_id(2)
     hf, wf = feat_ref.shape[1], feat_ref.shape[2]
     _, pw = pooled  # only PW shapes the stacked contraction below
     mys, mxs = [], []
     for k in range(rblk):
         my, mx = _matrices_for_roi(
-            rois_ref, b, rb * rblk + k, hf, wf, pooled, s, scale
+            rois_ref, lims_ref, b, rb * rblk + k, hf, wf, pooled, s, scale
         )
         mys.append(my)
         mxs.append(mx)
@@ -144,8 +164,9 @@ def _fwd_kernel(rois_ref, feat_ref, out_ref, *, pooled, s, scale, rblk):
         out_ref[0, k] = out_k.astype(out_ref.dtype)
 
 
-def _bwd_kernel(rois_ref, g_ref, dfeat_ref, *, pooled, s, scale, rblk):
-    """Blocked backward: RBLK rois per grid step.
+def _bwd_kernel(rois_ref, *refs, pooled, s, scale, rblk):
+    """Blocked backward: RBLK rois per grid step.  ``refs`` is
+    ``[lims_ref,] g_ref, dfeat_ref``.
 
     dfeat is accumulated across the roi-block sweep in f32 (the
     out_shape is forced f32 regardless of feat dtype — sequential bf16
@@ -168,6 +189,8 @@ def _bwd_kernel(rois_ref, g_ref, dfeat_ref, *, pooled, s, scale, rblk):
     6-pass HIGHEST buys nothing the rest of that backward has — while
     f32 cotangents (COMPUTE_DTYPE=float32 runs) keep HIGHEST so
     gradients round at ~1e-5, not bf16-mantissa ~1e-3."""
+    *lims, g_ref, dfeat_ref = refs
+    lims_ref = lims[0] if lims else None
     b, rb = pl.program_id(0), pl.program_id(2)
     wf, hf = dfeat_ref.shape[1], dfeat_ref.shape[2]
     ph, pw = pooled
@@ -179,7 +202,7 @@ def _bwd_kernel(rois_ref, g_ref, dfeat_ref, *, pooled, s, scale, rblk):
     ts, mxs = [], []
     for k in range(rblk):
         my, mx = _matrices_for_roi(
-            rois_ref, b, rb * rblk + k, hf, wf, pooled, s, scale
+            rois_ref, lims_ref, b, rb * rblk + k, hf, wf, pooled, s, scale
         )
         g = g_ref[0, k].astype(jnp.float32)                          # (PH, PW, CB)
         # t_k: (H, PW, CB) = Myᵀ_k contract PH
@@ -310,12 +333,23 @@ def _pad_rois(rois, rblk):
     return rois_t, rp
 
 
-def _roi_align_fwd_impl(feat, rois, pooled, scale, s, interpret):
+def _scalar_prefetch(rois, valid_hw, feat_hw, scale):
+    """→ (the kernels' scalar-prefetch operands, Rp): the padded rois
+    and, with ``valid_hw`` (B, 2), the (2, B) f32 per-image limits of
+    ``ops.roi_align._feat_limits`` — the gather path's own."""
+    rois_t, rp = _pad_rois(rois, _RBLK)
+    if valid_hw is None:
+        return (rois_t,), rp
+    lims = _feat_limits(feat_hw, (valid_hw[:, 0], valid_hw[:, 1]), scale)
+    return (rois_t, jnp.stack([lim for lim, _ in lims])), rp
+
+
+def _roi_align_fwd_impl(feat, rois, valid_hw, pooled, scale, s, interpret):
     b, hf, wf, c = feat.shape
     r = rois.shape[1]
     esize = feat.dtype.itemsize
     cblk = _cblk_fit(lambda blk: _fwd_bytes(hf, wf, blk, esize, pooled), c)
-    rois_t, rp = _pad_rois(rois, _RBLK)
+    prefetch, rp = _scalar_prefetch(rois, valid_hw, (hf, wf), scale)
     grid = (b, c // cblk, rp // _RBLK)
     kernel = partial(_fwd_kernel, pooled=pooled, s=s, scale=scale, rblk=_RBLK)
     out = pl.pallas_call(
@@ -327,36 +361,37 @@ def _roi_align_fwd_impl(feat, rois, pooled, scale, s, interpret):
             ("parallel", "parallel", "parallel"),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=[
                 pl.BlockSpec(
                     (1, hf, wf, cblk),
-                    lambda bb, cb, rr, rois_ref: (bb, 0, 0, cb),
+                    lambda bb, cb, rr, *prefetch_refs: (bb, 0, 0, cb),
                 ),
             ],
             out_specs=pl.BlockSpec(
                 (1, _RBLK, pooled[0], pooled[1], cblk),
-                lambda bb, cb, rr, rois_ref: (bb, rr, 0, 0, cb),
+                lambda bb, cb, rr, *prefetch_refs: (bb, rr, 0, 0, cb),
             ),
         ),
         out_shape=out_struct(
-            (b, rp, pooled[0], pooled[1], c), feat.dtype, rois_t, feat
+            (b, rp, pooled[0], pooled[1], c), feat.dtype, prefetch[0], feat
         ),
         interpret=interpret,
         # a device trace names the kernel by this; ``_roi_features`` stays
         # in it because the benchmark's roi_align_roofline finds it so
         name="pallas_roi_features_fwd",
-    )(rois_t, feat)
+    )(*prefetch, feat)
     return out[:, :r] if rp != r else out
 
 
-def _roi_align_bwd_impl(feat_shape, feat_dtype, rois, g, pooled, scale, s, interpret):
+def _roi_align_bwd_impl(feat_shape, feat_dtype, rois, valid_hw, g, pooled,
+                        scale, s, interpret):
     b, hf, wf, c = feat_shape
     r = rois.shape[1]
     gsize = g.dtype.itemsize
     cblk = _cblk_fit(lambda blk: _bwd_bytes(hf, wf, blk, gsize, pooled), c)
-    rois_t, rp = _pad_rois(rois, _RBLK)
+    prefetch, rp = _scalar_prefetch(rois, valid_hw, (hf, wf), scale)
     if rp != r:
         g = jnp.pad(g, ((0, 0), (0, rp - r)) + ((0, 0),) * (g.ndim - 2))
     grid = (b, c // cblk, rp // _RBLK)
@@ -370,24 +405,24 @@ def _roi_align_bwd_impl(feat_shape, feat_dtype, rois, g, pooled, scale, s, inter
             ("parallel", "parallel", "arbitrary"),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=[
                 pl.BlockSpec(
                     (1, _RBLK, pooled[0], pooled[1], cblk),
-                    lambda bb, cb, rr, rois_ref: (bb, rr, 0, 0, cb),
+                    lambda bb, cb, rr, *prefetch_refs: (bb, rr, 0, 0, cb),
                 ),
             ],
             out_specs=pl.BlockSpec(
                 (1, wf, hf, cblk),
-                lambda bb, cb, rr, rois_ref: (bb, 0, 0, cb),
+                lambda bb, cb, rr, *prefetch_refs: (bb, 0, 0, cb),
             ),
         ),
         # (B, W, H, C): the kernel accumulates transposed (see docstring)
-        out_shape=out_struct((b, wf, hf, c), jnp.float32, rois_t, g),
+        out_shape=out_struct((b, wf, hf, c), jnp.float32, prefetch[0], g),
         interpret=interpret,
         name="pallas_roi_features_bwd",
-    )(rois_t, g)
+    )(*prefetch, g)
     return out.swapaxes(1, 2).astype(feat_dtype)
 
 
@@ -399,31 +434,44 @@ def roi_align_pallas(
     spatial_scale: float = 1.0 / 16.0,
     sample_ratio: int = 2,
     interpret: bool = False,
+    valid_hw=None,
 ) -> jnp.ndarray:
     """(B, H, W, C) feature + (B, R, 4) image-coord rois → (B, R, ph, pw, C).
 
     Batched twin of ``ops.roi_align.roi_align`` backed by the Pallas MXU
     kernel; differentiable in ``feat`` (rois get zero cotangent, matching
     the stop-gradient proposal semantics of the reference's Proposal op).
+
+    ``valid_hw`` (B, 2) = true pre-padding image sizes: samples clamp to
+    each image's valid feature extent instead of the canvas, as the gather
+    path does under the same argument.  The backward carries the same
+    limits (it is the transpose of the forward it belongs to) and gives
+    ``valid_hw`` a zero cotangent; today every caller that passes
+    ``valid_hw`` is forward-only.
     """
     return _roi_align_fwd_impl(
-        feat, rois, pooled, spatial_scale, sample_ratio, interpret
+        feat, rois, valid_hw, pooled, spatial_scale, sample_ratio, interpret
     )
 
 
-def _vjp_fwd(feat, rois, pooled, spatial_scale, sample_ratio, interpret):
-    out = _roi_align_fwd_impl(feat, rois, pooled, spatial_scale, sample_ratio, interpret)
+def _vjp_fwd(feat, rois, pooled, spatial_scale, sample_ratio, interpret,
+             valid_hw=None):
+    out = _roi_align_fwd_impl(
+        feat, rois, valid_hw, pooled, spatial_scale, sample_ratio, interpret
+    )
     # feat rides along only for its shape/dtype; it is already live as a
     # backbone activation so this costs nothing extra
-    return out, (feat, rois)
+    return out, (feat, rois, valid_hw)
 
 
 def _vjp_bwd(pooled, spatial_scale, sample_ratio, interpret, res, g):
-    feat, rois = res
+    feat, rois, valid_hw = res
     dfeat = _roi_align_bwd_impl(
-        feat.shape, feat.dtype, rois, g, pooled, spatial_scale, sample_ratio, interpret
+        feat.shape, feat.dtype, rois, valid_hw, g, pooled, spatial_scale,
+        sample_ratio, interpret,
     )
-    return dfeat, jnp.zeros_like(rois)
+    dvalid = None if valid_hw is None else jnp.zeros_like(valid_hw)
+    return dfeat, jnp.zeros_like(rois), dvalid
 
 
 roi_align_pallas.defvjp(_vjp_fwd, _vjp_bwd)

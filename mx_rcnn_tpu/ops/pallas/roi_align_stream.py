@@ -70,8 +70,8 @@ def _row_matrices(rois_ref, b, r, hf: int, wf: int, offset, hblk: int,
     x2 = rois_ref[b, 2, r] * scale
     y2 = rois_ref[b, 3, r] * scale
     valid = x2 >= x1  # inverted boxes are _pad_rois fillers
-    ylo, ywhi = _sample_coords(y1, y2, hf, ph, s)
-    xlo, xwhi = _sample_coords(x1, x2, wf, pw, s)
+    ylo, ywhi = _sample_coords(y1, y2, float(hf - 1), ph, s)
+    xlo, xwhi = _sample_coords(x1, x2, float(wf - 1), pw, s)
     # cap: when lo is the last row/col, send the hi-weight to lo as well
     # (resident kernel achieves this because lo==hi makes both one-hot
     # terms hit the same cell; here lo+1 would fall outside)
@@ -83,7 +83,7 @@ def _row_matrices(rois_ref, b, r, hf: int, wf: int, offset, hblk: int,
     my = _interp_matrix_rows(ylo, ywhi, offset, hblk, ph, s)     # (PH, hblk)
     from mx_rcnn_tpu.ops.pallas.roi_align import _interp_matrix
 
-    mx = _interp_matrix(xlo, xwhi, wf, pw, s)                    # (PW, W)
+    mx = _interp_matrix(xlo, xwhi, wf, float(wf - 1), pw, s)    # (PW, W)
     # conservative GLOBAL row extent of the roi's sample support, for
     # the caller's block-skip predicate.  Sample points live in
     # [clip(y1), clip(y1 + max(y2-y1, 1))] (the min-length clamp in

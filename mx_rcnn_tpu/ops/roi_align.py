@@ -31,8 +31,13 @@ def _feat_limits(feat_hw, valid_hw, spatial_scale):
     rows/cols that carry image content, ``ceil(h·scale)``.  Rows past that
     are functions of the zero padding only, and (crucially) the clamp at
     ``size − 1`` then lands at the same coordinate for every canvas the
-    image fits in, so the gather is bit-identical across shape buckets
-    (the serving padding-invariance guarantee; see SERVING.md)."""
+    image fits in, so the pooled features do not depend on the shape
+    bucket (the serving padding-invariance guarantee; see SERVING.md).
+    One rule for both implementations: the gather below and the resident
+    Pallas kernel (``ops/pallas/roi_align.py``, which hands the float
+    limits to its kernel body as scalars) clamp samples to ``lim − 1``
+    and cap ``hi`` there.  ``valid_hw``: one image's (2,), or a pair of
+    (B,) vectors for a batch."""
     if valid_hw is None:
         return [(float(s), s) for s in feat_hw]
     lims = []
@@ -230,10 +235,16 @@ def extract_roi_features_batched(
     ``valid_hw`` (B, 2) = true pre-padding image sizes (``im_info[:, :2]``):
     sample coordinates clamp to the valid feature extent instead of the
     canvas, making the pooled features independent of the shape bucket
-    (the serving padding-invariance contract).  The Pallas kernels clamp
-    to the canvas, so a non-None ``valid_hw`` takes the jnp gather path
-    on every backend — inference-only callers pay a modest TPU perf cost
-    for exactness under bucketing.
+    (the serving padding-invariance contract).  The resident Pallas
+    kernel takes the same limits (``_feat_limits``) as a second
+    scalar-prefetch operand, so on a TPU a map that fits VMEM is pooled
+    by the kernel with or without ``valid_hw``.  The streaming kernel
+    clamps to the canvas only: an over-VMEM map with ``valid_hw`` takes
+    the jnp gather (every caller that passes ``valid_hw`` is forward-only,
+    where the gather is the over-VMEM choice anyway).  That gather is not
+    cheap on a TPU: a ``lax.map`` of ten 32-roi chunks was 85% of the
+    serve cell's device time before the kernel took ``valid_hw``
+    (PERF.md, PR 26).
     """
     from mx_rcnn_tpu.utils.platform import use_pallas
 
@@ -244,7 +255,7 @@ def extract_roi_features_batched(
     # outputs in scratch (ops/pallas/roi_align_stream.py)
     from mx_rcnn_tpu.ops.pallas.roi_align import fits_vmem
 
-    if mode == "roi_align" and valid_hw is None and use_pallas():
+    if mode == "roi_align" and use_pallas():
         if fits_vmem(
             feat.shape[1], feat.shape[2], feat.shape[3], pooled,
             feat.dtype.itemsize,
@@ -252,9 +263,10 @@ def extract_roi_features_batched(
             from mx_rcnn_tpu.ops.pallas.roi_align import roi_align_pallas
 
             return roi_align_pallas(
-                feat, rois, pooled, spatial_scale, sample_ratio
+                feat, rois, pooled, spatial_scale, sample_ratio,
+                valid_hw=valid_hw,
             )
-        if not fwd_only:
+        if not fwd_only and valid_hw is None:
             from mx_rcnn_tpu.ops.pallas.roi_align_stream import (
                 roi_align_stream,
             )

@@ -49,10 +49,12 @@ SERVE_SPANS = (
 )
 
 # device side: jax.named_scope components (metadata only).  ``backbone``,
-# ``rpn`` and ``rcnn`` are the scopes flax opens for those submodules.
+# ``rpn`` and ``rcnn`` are the scopes flax opens for those submodules;
+# ``roi_align`` is the pooling alone, inside ``roi_head`` and closed
+# before the head's trunk (flax's ``<Model>._roi_features`` lies between).
 TRAIN_SCOPES = (
     "backbone", "rpn", "anchor_targets", "proposal", "roi_sample",
-    "roi_head", "losses", "update",
+    "roi_head", "roi_align", "losses", "update",
 )
 SERVE_SCOPES = (
     "postprocess/decode", "postprocess/class_nms", "postprocess/cap",

@@ -101,6 +101,26 @@ def test_roi_align_fwd_bwd_compiles(one_chip, kernel, feat, n_rois, pooled,
     assert text.count("tpu_custom_call") >= 2  # fwd + bwd kernels
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_roi_align_serve_valid_hw_compiles(one_chip, dtype):
+    """The serve graph's second stage as ``test_forward`` hands it over:
+    the C4 map padded to the ladder's extent, 300 rois an image, forward
+    only, with the per-image valid extents as the kernel's second
+    scalar-prefetch operand."""
+    feat, pooled = (8, 64, 64, 1024), (14, 14)
+    assert fits_vmem(*feat[1:], pooled, jnp.dtype(dtype).itemsize)
+    text = _compiled_text(
+        lambda f, rois, valid_hw: roi_align_pallas(
+            f, rois, pooled, 1 / 16, 2, valid_hw=valid_hw
+        ),
+        one_chip, (feat, dtype), ((8, 300, 4), jnp.float32),
+        ((8, 2), jnp.float32),
+    )
+    assert "pallas_roi_features_fwd" in text
+    assert text.count("tpu_custom_call") == 1
+
+
 @pytest.mark.parametrize("n,max_keep", [(12000, 2000), (6000, 300)],
                          ids=["train-12000-2000", "test-6000-300"])
 def test_sorted_nms_compiles(one_chip, n, max_keep):

@@ -72,7 +72,7 @@ def test_kernels_phase_in_interpret_mode():
         f"{kernel}_{dtype}_{pass_}"
         for kernel in ("resident", "stream")
         for dtype in ("f32", "bf16") for pass_ in ("fwd", "bwd")
-    }
+    } | {f"resident_valid_hw_{dtype}_fwd" for dtype in ("f32", "bf16")}
     assert max(v for k, v in errs.items() if "f32" in k) < 1e-5
 
 

@@ -214,3 +214,211 @@ class TestStreamingRoiAlign:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref), rtol=1e-4, atol=1e-4
         )
+
+
+# canvas of the valid_hw cases: 12 x 16 cells at stride 16 = a 192 x 256
+# bucket; (100, 150) is an image with 7 x 10 cells of content in it
+_H, _W, _C = 12, 16, 128
+_CANVAS_HW = (_H * 16.0, _W * 16.0)
+
+# (valid_hw of each image, rois of every image or None for random ones)
+_VALID_HW_CASES = {
+    "both-axes-smaller": ([(100.0, 150.0)] * 2, None),
+    "rows-only-smaller": ([(100.0, _CANVAS_HW[1])], None),
+    "cols-only-smaller": ([(_CANVAS_HW[0], 150.0)], None),
+    "equal-to-canvas": ([_CANVAS_HW] * 2, None),
+    # x1 < 150 < x2 and y1 < 100 < y2, one axis and both
+    "rois-crossing-the-valid-edge": ([(100.0, 150.0)], [
+        [120, 20, 200, 80], [20, 60, 120, 140], [100, 70, 250, 190],
+        [0, 0, 255, 191],
+    ]),
+    "rois-wholly-in-the-padding": ([(100.0, 150.0)], [
+        [160, 110, 250, 190], [152, 10, 200, 90], [10, 104, 140, 180],
+        [151, 101, 151.5, 101.5],
+    ]),
+    "images-of-different-extents": (
+        [(100.0, 150.0), _CANVAS_HW, (192.0, 100.0), (33.0, 17.0)], None),
+}
+
+
+def _valid_hw_case(rng, name):
+    valid, rois = _VALID_HW_CASES[name]
+    b = len(valid)
+    feat = rng.randn(b, _H, _W, _C).astype(np.float32)
+    if rois is None:
+        rois = np.stack([random_rois(rng, 6, *_CANVAS_HW) for _ in range(b)])
+    else:
+        rois = np.tile(np.asarray(rois, np.float32)[None], (b, 1, 1))
+    return jnp.asarray(feat), jnp.asarray(rois), jnp.asarray(valid, jnp.float32)
+
+
+class TestPallasRoiAlignValidHw:
+    """The resident kernel's per-image valid-extent clamp (serving's
+    padding invariance) against the gather path's, image by image."""
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                           (jnp.bfloat16, 0.05)],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("case", list(_VALID_HW_CASES))
+    def test_fwd_matches_gather(self, rng, case, dtype, tol):
+        feat, rois, valid_hw = _valid_hw_case(rng, case)
+        got = roi_align_pallas(
+            feat.astype(dtype), rois, (7, 7), 1.0 / 16, 2, True,
+            valid_hw=valid_hw,
+        )
+        assert got.dtype == dtype
+        for i in range(feat.shape[0]):
+            ref = roi_align(feat[i], rois[i], (7, 7), 1.0 / 16, 2,
+                            valid_hw=valid_hw[i])
+            np.testing.assert_allclose(
+                np.asarray(got[i], np.float32), np.asarray(ref),
+                rtol=tol, atol=tol,
+            )
+        if case == "equal-to-canvas":
+            # the limit is the canvas: the same arithmetic as without it
+            canvas = roi_align_pallas(
+                feat.astype(dtype), rois, (7, 7), 1.0 / 16, 2, True
+            )
+            assert np.array_equal(np.asarray(got, np.float32),
+                                  np.asarray(canvas, np.float32))
+        if case == "rois-wholly-in-the-padding":
+            # every sample clamps to the last valid row / column
+            assert np.isfinite(np.asarray(got, np.float32)).all()
+
+    def test_pooled_features_do_not_depend_on_the_canvas(self, rng):
+        """The same valid content zero-padded into two canvases (two shape
+        buckets) pools to bitwise-equal features: the clamp depends on the
+        image alone, and cells past it carry weight zero."""
+        content = rng.randn(7, 10, _C).astype(np.float32)
+        rois = jnp.asarray(random_rois(rng, 9, 100.0, 150.0))[None]
+        valid_hw = jnp.asarray([[100.0, 150.0]], jnp.float32)
+        outs = []
+        for h, w in ((_H, _W), (10, 20)):
+            feat = np.zeros((1, h, w, _C), np.float32)
+            feat[0, :7, :10] = content
+            outs.append(np.asarray(roi_align_pallas(
+                jnp.asarray(feat), rois, (7, 7), 1.0 / 16, 2, True,
+                valid_hw=valid_hw,
+            )))
+        assert np.array_equal(outs[0], outs[1])
+        assert np.abs(outs[0]).max() > 0.1
+
+    def test_bwd_carries_the_limits(self, rng):
+        """No training path passes ``valid_hw``; the backward is still the
+        transpose of the forward it belongs to."""
+        feat, rois, valid_hw = _valid_hw_case(
+            rng, "images-of-different-extents")
+        cot = jnp.asarray(
+            rng.randn(*rois.shape[:2], 7, 7, _C).astype(np.float32))
+        ref_grad = jax.grad(lambda f: sum(
+            (roi_align(f[i], rois[i], (7, 7), 1.0 / 16, 2,
+                       valid_hw=valid_hw[i]) * cot[i]).sum()
+            for i in range(f.shape[0])
+        ))(feat)
+        got_grad, dvalid = jax.grad(
+            lambda f, v: (roi_align_pallas(
+                f, rois, (7, 7), 1.0 / 16, 2, True, valid_hw=v
+            ) * cot).sum(), argnums=(0, 1),
+        )(feat, valid_hw)
+        np.testing.assert_allclose(
+            np.asarray(got_grad), np.asarray(ref_grad), rtol=1e-4, atol=1e-4
+        )
+        assert not np.asarray(dvalid).any()
+
+    @pytest.mark.parametrize("with_valid_hw", [False, True],
+                             ids=["canvas", "valid_hw"])
+    def test_scalar_prefetch_operands(self, rng, with_valid_hw):
+        """``valid_hw=None`` is the program training always ran: one
+        scalar-prefetch operand (the rois), and the clamp a constant.  The
+        limits ride in as a second one only when the caller gives them."""
+        feat, rois, valid_hw = _valid_hw_case(rng, "both-axes-smaller")
+        jaxpr = jax.make_jaxpr(lambda f, r, v: roi_align_pallas(
+            f, r, (7, 7), 1.0 / 16, 2, True,
+            valid_hw=v if with_valid_hw else None,
+        ))(feat, rois, valid_hw)
+        calls = _pallas_calls(jaxpr.jaxpr)
+        assert len(calls) == 1
+        mapping = calls[0].params["grid_mapping"]
+        assert mapping.num_index_operands == (2 if with_valid_hw else 1)
+        assert len(calls[0].invars) == mapping.num_index_operands + 1
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_pallas_calls(sub))
+    return found
+
+
+class TestRoiAlignDispatchValidHw:
+    """``extract_roi_features_batched`` on a TPU (``use_pallas`` steered:
+    the backend here is the CPU): where a map with ``valid_hw`` goes."""
+
+    @pytest.fixture
+    def reached(self, monkeypatch):
+        from mx_rcnn_tpu.ops import roi_align as ops_mod
+        from mx_rcnn_tpu.ops.pallas import roi_align as resident_mod
+        from mx_rcnn_tpu.ops.pallas import roi_align_stream as stream_mod
+        from mx_rcnn_tpu.utils import platform
+
+        monkeypatch.setattr(platform, "use_pallas", lambda: True)
+        calls = []
+
+        def record(name, real, **forced):
+            def wrapped(*args, **kwargs):
+                calls.append((name, kwargs.get("valid_hw")))
+                return real(*args, **{**kwargs, **forced})
+            return wrapped
+
+        monkeypatch.setattr(resident_mod, "roi_align_pallas", record(
+            "resident", resident_mod.roi_align_pallas, interpret=True))
+        monkeypatch.setattr(stream_mod, "roi_align_stream", record(
+            "stream", stream_mod.roi_align_stream))
+        monkeypatch.setattr(ops_mod, "roi_align", record(
+            "gather", ops_mod.roi_align))
+        return calls
+
+    @staticmethod
+    def _trace(feat_shape, pooled, fwd_only, with_valid_hw=True):
+        from mx_rcnn_tpu.ops.roi_align import extract_roi_features_batched
+
+        b = feat_shape[0]
+        return jax.eval_shape(
+            lambda f, r, v: extract_roi_features_batched(
+                f, r, "roi_align", pooled, 1.0 / 16, 2, fwd_only=fwd_only,
+                valid_hw=v if with_valid_hw else None,
+            ),
+            jax.ShapeDtypeStruct(feat_shape, jnp.float32),
+            jax.ShapeDtypeStruct((b, 300, 4), jnp.float32),
+            jax.ShapeDtypeStruct((b, 2), jnp.float32),
+        )
+
+    def test_fitting_map_with_valid_hw_takes_the_resident_kernel(
+            self, reached):
+        """The serve graph's own shape: the C4 map at the ladder's extent."""
+        out = self._trace((8, 64, 64, 1024), (14, 14), fwd_only=True)
+        assert out.shape == (8, 300, 14, 14, 1024)
+        assert [name for name, _ in reached] == ["resident"]
+        assert reached[0][1] is not None
+
+    def test_over_vmem_fwd_only_map_keeps_the_gather(self, reached):
+        """FPN P2 at flagship resolution: forward-only graphs gather there,
+        with ``valid_hw`` as without it."""
+        self._trace((2, 152, 256, 256), (7, 7), fwd_only=True)
+        assert [name for name, _ in reached] == ["gather"]
+        assert reached[0][1] is not None
+
+    def test_over_vmem_map_with_valid_hw_never_streams(self, reached):
+        """The streaming kernel clamps to the canvas: ``valid_hw`` keeps an
+        over-VMEM map off it even in a differentiated graph, and without
+        ``valid_hw`` that graph streams as before."""
+        self._trace((2, 152, 256, 256), (7, 7), fwd_only=False)
+        assert [name for name, _ in reached] == ["gather"]
+        del reached[:]
+        self._trace((2, 152, 256, 256), (7, 7), fwd_only=False,
+                    with_valid_hw=False)
+        assert [name for name, _ in reached] == ["stream"]

@@ -333,6 +333,20 @@ def test_roi_align_kernels_stay_where_the_benchmark_finds_them(
         "pallas_nms_mask"]
 
 
+def test_roi_align_scope_holds_the_pooling_and_not_the_trunk(
+        lowered_train_step):
+    """``roi_align_device_share.serve`` reads the device time under this
+    one component: it lies inside ``roi_head``, holds both kernels, and
+    closes before ``top_head``, which ``_roi_features`` also runs."""
+    names = _scoped_names(lowered_train_step)
+    under = [n for n in names if "/roi_align/" in n + "/"]
+    assert under and all("/roi_head/" in n for n in under), under
+    assert {"pallas_roi_features_fwd", "pallas_roi_features_bwd"} <= {
+        c for n in under for c in n.split("/")}
+    assert not [n for n in under if "top_head" in n]
+    assert [n for n in names if "top_head" in n and "/roi_head/" in n]
+
+
 @pytest.mark.parametrize("scope", tracing.SERVE_SCOPES)
 def test_serve_postprocess_names_the_scope(scope):
     from mx_rcnn_tpu.ops.postprocess import make_test_postprocess
@@ -357,7 +371,7 @@ def test_serve_graph_names_its_stages(tiny_runner):
         np.zeros((48, 56, 3), np.float32))])
     text = pred._fn.lower(pred.params, batch).as_text(debug_info=True)
     names = _scoped_names(text)
-    for scope in ("backbone", "rpn", "proposal", "roi_head",
+    for scope in ("backbone", "rpn", "proposal", "roi_head", "roi_align",
                   "postprocess/decode", "postprocess/class_nms"):
         assert any(f"/{scope}/" in n + "/" for n in names), scope
 
