@@ -2,12 +2,12 @@
 """Chip smoke: the flagship trainer and the serving engine, through their
 normal entry points, once, on the TPU.
 
-    python chip_smoke.py               # one chip: kernels, train (bf16 b8, f32 b1), serve
+    python chip_smoke.py               # one chip: kernels, train (C4 bf16 b8, f32 b1; pyramid bf16 b8, f32 b1), serve
     python chip_smoke.py --multichip   # four chips: DP train + DP-vs-single check
 
-Full width (ResNet-101 C4 on the (608, 1024) bucket, the default serve
-ladder), random weights from a seed, synthetic data from a seed; depth of
-the RUN is cut (a handful of steps, 16 requests), not the model.  Every
+Full width (ResNet-101 C4 and the ResNet-50 pyramid on the (608, 1024)
+bucket, the default serve ladder), random weights from a seed, synthetic
+data from a seed; depth of the RUN is cut (a handful of steps, 16 requests), not the model.  Every
 phase checks its own output by the repo's means — the kernels against
 their jnp/numpy references on a small input, the trainer's guard counters,
 the engine's snapshot, the compile-cache audit — and raises on the first
@@ -41,6 +41,27 @@ TRAIN_BF16_ARGV = _TRAIN_COMMON + [
 #: the repo's DEFAULT configuration (README quickstart without
 #: --compute_dtype; what PARITY.md's gate evidence is for)
 TRAIN_F32_ARGV = _TRAIN_COMMON + ["--batch_images", "1", "--max_steps", "2"]
+#: the pyramid (Faster R-CNN ResNet-50-FPN, COCO's 81 classes) as the cell
+#: fpn_train_b8 runs it.  --lr as the benchmark's mix: the default spikes
+#: the loss of a random network under frozen BN (PERF.md, PR 21)
+_TRAIN_FPN_COMMON = [
+    "--network", "resnet_fpn", "--dataset", "coco", "--synthetic", "64",
+    "--epochs", "1", "--frequent", "1", "--lr", "1e-05",
+]
+TRAIN_FPN_BF16_ARGV = _TRAIN_FPN_COMMON + [
+    "--batch_images", "8", "--compute_dtype", "bfloat16", "--max_steps", "6",
+]
+#: the family's DEFAULT per-chip batch, the shape whose per-level top-k
+#: used to abort the chip's compiler (ROADMAP R1)
+TRAIN_FPN_F32_ARGV = _TRAIN_FPN_COMMON + [
+    "--batch_images", "1", "--max_steps", "2",
+]
+#: Pallas kernels the compiled pyramid step must hold: P2 and P3 stream,
+#: P4 and P5 stay resident (fits_vmem at bf16, 14x14), one proposal NMS
+FPN_STEP_KERNELS = (
+    "pallas_roi_features_stream_fwd", "pallas_roi_features_stream_bwd",
+    "pallas_roi_features_fwd", "pallas_roi_features_bwd", "pallas_nms_mask",
+)
 #: tools/serve.py without --small: flagship, default ladder, f32
 SERVE_ARGV = [
     "--network", "resnet", "--max_batch", "4", "--requests", "16",
@@ -148,6 +169,15 @@ def _random_rois(rng, b, r, h_img, w_img):
     return rois
 
 
+#: the maps the pyramid's train step hands the streaming pair: P2 and P3
+#: of a (608, 1024) image at 256 channels, 8 images (tag, map, stride);
+#: 128 rois an image, 14x14
+STREAM_TRAIN_MAPS = (
+    ("stream_p2", (8, 152, 256, 256), 4),
+    ("stream_p3", (8, 76, 128, 256), 8),
+)
+
+
 def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
     """The Pallas kernels against the repo's own references, on a small
     input, on the device that will run them.  The tier-1 tests check the
@@ -156,7 +186,10 @@ def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
     backward; the resident forward with ``valid_hw`` as serving calls it)
     against the gather reference ``ops.roi_align.roi_align``;
     NMS against the numpy oracle ``ops.nms.nms_numpy``, on boxes chosen
-    so that no pair sits within 1e-4 of the IoU threshold."""
+    so that no pair sits within 1e-4 of the IoU threshold.
+    On the chip (not under the interpreter, where they would take many
+    minutes) also the streaming pair at P2's and P3's shapes in the
+    pyramid's train step (``STREAM_TRAIN_MAPS``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -192,12 +225,21 @@ def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
         ("stream", roi_align_stream, (1, 152, 256, 128), 128, (14, 14), 4,
          None),
     ]
+    if not interpret:
+        cases += [(tag, roi_align_stream, shape, 128, (14, 14), stride, None)
+                  for tag, shape, stride in STREAM_TRAIN_MAPS]
+    # the train-shape cases draw from a stream of their own, so the NMS
+    # probe below keeps the boxes it always had (checked not borderline)
+    train_rng = np.random.RandomState(1)
+    train_tags = {tag for tag, _shape, _stride in STREAM_TRAIN_MAPS}
     for tag, kernel, shape, n_rois, pooled, stride, valid_hw in cases:
         b, h, w, c = shape
         scale = 1.0 / stride
-        feat = jnp.asarray(rng.randn(*shape).astype(np.float32))
-        rois = jnp.asarray(_random_rois(rng, b, n_rois, h * stride, w * stride))
-        cot = jnp.asarray(rng.randn(b, n_rois, *pooled, c).astype(np.float32))
+        draw = train_rng if tag in train_tags else rng
+        feat = jnp.asarray(draw.randn(*shape).astype(np.float32))
+        rois = jnp.asarray(
+            _random_rois(draw, b, n_rois, h * stride, w * stride))
+        cot = jnp.asarray(draw.randn(b, n_rois, *pooled, c).astype(np.float32))
 
         def reference(f, r):
             return jax.vmap(
@@ -259,24 +301,73 @@ def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
 
 
 # --------------------------------------------------------------------- train
-def train_phase(argv, name: str = "train"):
+def _compiled_step_text(cli, found: dict):
+    """Stand between ``train_net`` and the step it builds, as the
+    benchmark's driver does, and keep the text of the program the chip's
+    compiler made of it: the step is compiled once more from the shapes
+    of its first call, which the persistent cache answers.  → the saved
+    ``make_train_step`` to put back."""
+    import jax
+
+    make = cli.make_train_step
+
+    def spying(*a, **kw):
+        step = make(*a, **kw)
+
+        def first_then_plain(state, batch, rng, **kws):
+            if found:
+                return step(state, batch, rng, **kws)
+            shapes = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=getattr(x, "sharding", None)),
+                (state, batch, rng))
+            out = step(state, batch, rng, **kws)  # donates its state
+            found["text"] = step.lower(*shapes, **kws).compile().as_text()
+            return out
+
+        return first_then_plain
+
+    cli.make_train_step = spying
+    return make
+
+
+def train_phase(argv, name: str = "train", kernels=()):
     """``train_net`` on ``argv`` exactly as ``train_end2end.main`` calls
     it; fails unless every planned step was applied with a finite loss
     and the NaN guard never had to act (it would otherwise turn a broken
-    step into a clean exit).  → (final state, report)."""
+    step into a clean exit).  ``kernels``: names the compiled step must
+    hold; with them the loss must also stay flat or fall (no loss over
+    twice the first: the default LR's spike on these weights was 2000x).
+    → (final state, report)."""
     from mx_rcnn_tpu.tools import train_end2end as cli
 
     args = cli.parse_args(argv)
     report: dict = {}
+    compiled: dict = {}
+    make = _compiled_step_text(cli, compiled) if kernels else None
     t0 = time.monotonic()
-    state = cli.train_net(args, report=report)
+    try:
+        state = cli.train_net(args, report=report)
+    finally:
+        if make is not None:
+            cli.make_train_step = make
     wall = time.monotonic() - t0
+    if kernels and "text" not in compiled:
+        raise RuntimeError(
+            f"{name}: train_net did not build its step through "
+            f"make_train_step (more than one device?)")
+    held = {k: compiled["text"].count(f"%{k}") for k in kernels}
     losses = [loss for _step, loss in report["losses"]]
     say(phase=name, wall_s=round(wall, 1), steps=report["steps"],
         steps_applied=report["steps_applied"], losses=losses,
         skipped_batches=report["skipped_batches"],
         retried_steps=report["retried_steps"],
-        rollbacks=report["rollbacks"])
+        rollbacks=report["rollbacks"],
+        roi_levels=report.get("roi_levels"), kernels=held)
+    if not all(held.values()):
+        raise RuntimeError(f"{name}: compiled step holds {held}")
+    if kernels and not max(losses) <= 2 * losses[0]:
+        raise RuntimeError(f"{name}: loss neither flat nor falling: {losses}")
     if report["steps"] != args.max_steps:
         raise RuntimeError(
             f"{name}: planned {args.max_steps} steps, loop ran "
@@ -538,8 +629,8 @@ def main(argv=None) -> int:
         devices=len(devices), compile_cache=compile_cache_dir())
     check_native()
 
-    def phase(fn, arg, name):
-        out = fn(arg, name=name)
+    def phase(fn, arg, name, **kw):
+        out = fn(arg, name=name, **kw)
         say(phase=name, compile_s=clock.lap())
         return out
 
@@ -567,6 +658,10 @@ def main(argv=None) -> int:
                   "train_bf16_b8")
             phase(train_phase, TRAIN_F32_ARGV + prefix("f32"),
                   "train_f32_b1")
+            phase(train_phase, TRAIN_FPN_BF16_ARGV + prefix("fpn_bf16"),
+                  "train_fpn_bf16_b8", kernels=FPN_STEP_KERNELS)
+            phase(train_phase, TRAIN_FPN_F32_ARGV + prefix("fpn_f32"),
+                  "train_fpn_f32_b1")
             phase(serve_phase, SERVE_ARGV, "serve")
 
     stats = devices[0].memory_stats() or {}

@@ -117,6 +117,19 @@ class FPNTopHead(nn.Module):
         return nn.relu(x)
 
 
+def per_image(fn, *args):
+    """``jax.vmap(fn)(*args)`` over the leading (image) axis — except at
+    batch 1, where ``fn`` runs on the one image without the batch axis.
+    Same values either way; the shapes differ, and that is the point:
+    the TPU compiler aborts in its TopK emitter on a ``[1, N]`` operand
+    at the finest level's N = 152·256·3 = 116736 (ROADMAP R1), while the
+    rank-1 ``[N]`` and every batch ≥ 2 compile."""
+    if args[0].shape[0] != 1:
+        return jax.vmap(fn)(*args)
+    out = fn(*(a[0] for a in args))
+    return jax.tree_util.tree_map(lambda x: x[None], out)
+
+
 def roi_levels(rois: jnp.ndarray, k0: int = 4, canonical: float = 224.0,
                lo: int = 2, hi: int = 5) -> jnp.ndarray:
     """(…, 4) boxes → FPN level index in [lo, hi] (Lin et al. eq. 1)."""
@@ -124,6 +137,46 @@ def roi_levels(rois: jnp.ndarray, k0: int = 4, canonical: float = 224.0,
     h = jnp.maximum(rois[..., 3] - rois[..., 1] + 1.0, 1.0)
     k = jnp.floor(k0 + jnp.log2(jnp.sqrt(w * h) / canonical))
     return jnp.clip(k, lo, hi).astype(jnp.int32)
+
+
+def roi_level_counts(rois: jnp.ndarray) -> Dict[str, jnp.ndarray]:
+    """``num_rois_p2`` .. ``num_rois_p5``: how many of ``rois`` each level
+    pools (:func:`roi_levels`), summed over every leading axis — how the
+    second stage's work splits over the pyramid, a counter of the step's
+    ``aux`` that ``train_net`` sums into ``report["roi_levels"]``."""
+    levels = roi_levels(rois)
+    return {f"num_rois_p{lv}": (levels == lv).sum() for lv in range(2, 6)}
+
+
+def pool_levels(pyramid, rois: jnp.ndarray, pooled_size, strides,
+                sample_ratio: int, fwd_only: bool = False, valid_hw=None):
+    """Masked multi-level ROIAlign: P2.. maps × (B, R, 4) rois →
+    (B, R, ph, pw, C), each roi's features from the level eq. 1 gives it.
+
+    Static shapes: every level's call takes all R rois and the results are
+    blended by a one-hot level mask.  A roi of ANOTHER level is handed to
+    the call as a zero box: its result is masked away whatever it is, and
+    the kernels' work grows with a roi's extent on the map (the streaming
+    pair skips the row blocks a roi does not touch) — a 400-pixel roi
+    pooled on the stride-4 map for nothing cost more than every roi that
+    belongs there, and made the step's time follow the proposals' sizes
+    (9% between seeds on the chip; PERF.md §6, PR 28).  Exact: the rows
+    that count are computed as before."""
+    levels = roi_levels(rois)                            # (B, R) in [2, 5]
+    pooled = None
+    for li, stride in enumerate(strides):
+        own = levels == li + 2
+        # roi_align/p2 .. roi_align/p5: the streaming levels and the
+        # resident ones told apart in a device trace (metadata only)
+        with jax.named_scope("roi_align"), jax.named_scope(f"p{li + 2}"):
+            feats = extract_roi_features_batched(
+                pyramid[li], jnp.where(own[..., None], rois, 0.0),
+                "roi_align", pooled_size, 1.0 / stride, sample_ratio,
+                fwd_only=fwd_only, valid_hw=valid_hw,
+            )                                            # (B, R, ph, pw, C)
+        contrib = jnp.where(own[..., None, None, None], feats, 0.0)
+        pooled = contrib if pooled is None else pooled + contrib
+    return pooled
 
 
 class FPNFasterRCNN(nn.Module):
@@ -236,18 +289,10 @@ class FPNFasterRCNN(nn.Module):
     ) -> jnp.ndarray:
         """Masked multi-level ROIAlign: (B, R, 4) → (B*R, D)."""
         net = self.cfg.network
-        levels = roi_levels(rois)                        # (B, R) in [2, 5]
-        pooled = None
-        for li, stride in enumerate(net.FPN_FEAT_STRIDES[:4]):  # P2..P5
-            with jax.named_scope("roi_align"):
-                feats = extract_roi_features_batched(
-                    pyramid[li], rois, "roi_align", net.POOLED_SIZE,
-                    1.0 / stride, net.ROI_SAMPLE_RATIO, fwd_only=fwd_only,
-                    valid_hw=valid_hw,
-                )                                        # (B, R, ph, pw, C)
-            mask = (levels == li + 2)[..., None, None, None]
-            contrib = jnp.where(mask, feats, 0.0)
-            pooled = contrib if pooled is None else pooled + contrib
+        pooled = pool_levels(
+            pyramid, rois, net.POOLED_SIZE, net.FPN_FEAT_STRIDES[:4],
+            net.ROI_SAMPLE_RATIO, fwd_only, valid_hw,
+        )
         b, r = pooled.shape[0], pooled.shape[1]
         return self.top_head(pooled.reshape((b * r,) + pooled.shape[2:]))
 
@@ -315,13 +360,12 @@ class FPNFasterRCNN(nn.Module):
             pre_per_level = max(t.RPN_PRE_NMS_TOP_N // n_levels, 256)
             with jax.named_scope("proposal"):
                 fg_scores = jax.nn.softmax(rpn_logits, axis=-1)[..., 1]
-                prop_boxes, prop_scores, prop_valid = jax.vmap(
+                prop_boxes, prop_scores, prop_valid = per_image(
                     lambda s, d, info: self._propose_multilevel(
                         s, d, anchors, bounds, info, pre_per_level,
                         t.RPN_POST_NMS_TOP_N, t.RPN_NMS_THRESH,
                         t.RPN_MIN_SIZE,
-                    )
-                )(
+                    ),
                     jax.lax.stop_gradient(fg_scores),
                     jax.lax.stop_gradient(rpn_deltas),
                     im_info,
@@ -369,6 +413,7 @@ class FPNFasterRCNN(nn.Module):
             "num_valid_props": prop_valid.sum(),
             "num_fg_anchors": (atgt.labels == 1).sum(),
         }
+        aux.update(roi_level_counts(samples.rois))
 
         if cfg.network.USE_MASK:
             mask_loss, mask_aux = self._mask_loss(
@@ -409,12 +454,13 @@ class FPNFasterRCNN(nn.Module):
             fg_scores = jnp.where(grid_ok, fg_scores, _NEG_INF)
             n_levels = len(bounds) - 1
             pre_per_level = max(te.RPN_PRE_NMS_TOP_N // n_levels, 256)
-            rois, roi_scores, roi_valid = jax.vmap(
+            rois, roi_scores, roi_valid = per_image(
                 lambda s, d, info: self._propose_multilevel(
                     s, d, anchors, bounds, info, pre_per_level,
                     te.RPN_POST_NMS_TOP_N, te.RPN_NMS_THRESH, te.RPN_MIN_SIZE,
-                )
-            )(fg_scores, rpn_deltas, im_info)
+                ),
+                fg_scores, rpn_deltas, im_info,
+            )
 
         # one ladder-wide shape per level into roi_align so the second
         # stage is the SAME program for every bucket (see
@@ -451,18 +497,10 @@ class FPNFasterRCNN(nn.Module):
                      valid_hw=None):
         """(B, R, 4) → (B*R, 14, 14, C) mask-branch roi features."""
         net = self.cfg.network
-        levels = roi_levels(rois)
-        pooled = None
-        for li, stride in enumerate(net.FPN_FEAT_STRIDES[:4]):
-            with jax.named_scope("roi_align"):
-                feats = extract_roi_features_batched(
-                    pyramid[li], rois, "roi_align", (14, 14),
-                    1.0 / stride, net.ROI_SAMPLE_RATIO, fwd_only=fwd_only,
-                    valid_hw=valid_hw,
-                )
-            mask = (levels == li + 2)[..., None, None, None]
-            contrib = jnp.where(mask, feats, 0.0)
-            pooled = contrib if pooled is None else pooled + contrib
+        pooled = pool_levels(
+            pyramid, rois, (14, 14), net.FPN_FEAT_STRIDES[:4],
+            net.ROI_SAMPLE_RATIO, fwd_only, valid_hw,
+        )
         b, r = pooled.shape[0], pooled.shape[1]
         return pooled.reshape((b * r,) + pooled.shape[2:])
 

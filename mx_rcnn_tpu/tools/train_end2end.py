@@ -183,7 +183,9 @@ def train_net(args, report=None):
     verified ``losses`` as ``(step, loss)`` pairs, the host side's own
     counters — ``feed`` (``DeviceFeed.stats()`` summed over the epochs'
     feeds), ``pipeline`` (``PipelinedLoop.stats()``) and ``loader`` (the
-    assembly pool's stats, summed; empty on the serial loader) — and on
+    assembly pool's stats, summed; empty on the serial loader) —
+    ``roi_levels`` (a pyramid's sampled rois by pooling level,
+    ``num_rois_p2`` .. summed over the fetched steps; empty otherwise) and on
     an elastic run ``elastic`` / ``degraded``."""
     import collections
 
@@ -452,10 +454,17 @@ def train_net(args, report=None):
             else:
                 totals[k] += v
 
+    # a pyramid's sampled rois by the level that pools them
+    # (``num_rois_p2`` .. in the step's aux), summed over the fetched steps
+    roi_level_totals: collections.Counter = collections.Counter()
+
     def deliver(ready):
         for idx, aux in ready:
-            tracker.update({k: float(v) for k, v in aux.items()})
-            losses.append((idx, float(aux["loss"])))
+            values = {k: float(v) for k, v in aux.items()}
+            tracker.update(values)
+            losses.append((idx, values["loss"]))
+            roi_level_totals.update(
+                {k: v for k, v in values.items() if k.startswith("num_rois_p")})
 
     def flush_pipeline(state):
         # force the deferred aux checks before any checkpoint/summary:
@@ -561,6 +570,12 @@ def train_net(args, report=None):
             pipe_stats["snapshots"], pipe_stats["snapshot_ms"],
             pipe_stats["fetch_stalls"], pipe_stats["fetch_stall_ms"],
         )
+        if roi_level_totals:
+            logger.info(
+                "host side: sampled rois by pyramid level: %s",
+                ", ".join(f"{k[len('num_rois_'):]} {v:.0f}"
+                          for k, v in sorted(roi_level_totals.items())),
+            )
         if report is not None:
             report.update(
                 steps=total_steps,
@@ -571,6 +586,7 @@ def train_net(args, report=None):
                 feed=dict(feed_totals),
                 pipeline=pipe_stats,
                 loader=dict(loader_totals),
+                roi_levels=dict(roi_level_totals),
             )
         if use_elastic:
             if eloop.monitor.shrinks:

@@ -52,10 +52,13 @@ SERVE_SPANS = (
 # ``rpn`` and ``rcnn`` are the scopes flax opens for those submodules;
 # ``roi_align`` is the pooling alone, inside ``roi_head`` and closed
 # before the head's trunk (flax's ``<Model>._roi_features`` lies between).
+# A pyramid also has flax's ``neck``, and under ``roi_align`` one more
+# component a level, ``roi_align/p2`` .. ``roi_align/p5`` (models/fpn.py).
 TRAIN_SCOPES = (
     "backbone", "rpn", "anchor_targets", "proposal", "roi_sample",
     "roi_head", "roi_align", "losses", "update",
 )
+FPN_SCOPES = ("neck",) + tuple(f"roi_align/p{lv}" for lv in range(2, 6))
 SERVE_SCOPES = (
     "postprocess/decode", "postprocess/class_nms", "postprocess/cap",
     "postprocess/mask_select", "postprocess/mask_paste",

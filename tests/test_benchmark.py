@@ -1,0 +1,16 @@
+"""The benchmark's cases for the pyramid configuration
+(``benchmark/graphs/fpn.py`` against hand counts, the cell
+``fpn_train_b8`` as ``spec.load_cell`` assembles it), collected here so
+the tier-1 run holds them: the cases live in
+``benchmark/tests/test_fpn_graph.py`` (fast, no JAX)."""
+
+import os
+import sys
+
+_BENCH = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+for _p in (os.path.join(_BENCH, "tests"), _BENCH):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+from test_fpn_graph import *  # noqa: E402,F401,F403 — the cases themselves

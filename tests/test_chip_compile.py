@@ -74,6 +74,12 @@ _ROI_ALIGN_CASES = [
                  id="stream-p2-7"),
     pytest.param(roi_align_stream, (2, 152, 256, 256), 512, (14, 14), 1 / 4,
                  id="stream-p2-14"),
+    # the pyramid's train step (cell fpn_train_b8): 8 images, 128 sampled
+    # rois, 14x14 - P2 and P3 are over the resident budget at both dtypes
+    pytest.param(roi_align_stream, (8, 152, 256, 256), 128, (14, 14), 1 / 4,
+                 id="stream-p2-train"),
+    pytest.param(roi_align_stream, (8, 76, 128, 256), 128, (14, 14), 1 / 8,
+                 id="stream-p3-train"),
 ]
 
 
@@ -119,6 +125,22 @@ def test_roi_align_serve_valid_hw_compiles(one_chip, dtype):
     )
     assert "pallas_roi_features_fwd" in text
     assert text.count("tpu_custom_call") == 1
+
+
+def test_pyramid_top_k_compiles_at_batch_one(one_chip):
+    """The finest level's 152x256x3 anchor scores at per-chip batch 1: as
+    ``[1, 116736]`` the chip's compiler aborts the PROCESS in its TopK
+    emitter (ROADMAP R1; not tried here for that reason), so
+    ``models/fpn.py::per_image`` hands it the one image without the batch
+    axis.  Batch 2 goes through ``vmap`` as before."""
+    from mx_rcnn_tpu.models.fpn import per_image
+
+    for batch in (1, 2):
+        text = _compiled_text(
+            lambda s: per_image(lambda row: jax.lax.top_k(row, 2400), s),
+            one_chip, ((batch, 116736), jnp.float32),
+        )
+        assert f"f32[{batch},2400]" in text  # it compiled, whole
 
 
 @pytest.mark.parametrize("n,max_keep", [(12000, 2000), (6000, 300)],

@@ -76,6 +76,86 @@ def test_kernels_phase_in_interpret_mode():
     assert max(v for k, v in errs.items() if "f32" in k) < 1e-5
 
 
+def test_pyramid_phases_ask_for_the_cell_s_configuration():
+    """The two pyramid phases' argv through the CLI's own parser: the
+    network and dataset of ``frcnn_r50_fpn_coco``, batch 8 in bf16 as the
+    cell ``fpn_train_b8`` and the family's default batch 1 in f32."""
+    from mx_rcnn_tpu.tools import train_end2end as cli
+
+    for argv, batch, dtype, steps in (
+            (chip_smoke.TRAIN_FPN_BF16_ARGV, 8, "bfloat16", 6),
+            (chip_smoke.TRAIN_FPN_F32_ARGV, 1, "float32", 2)):
+        args = cli.parse_args(argv + ["--prefix", "/nowhere"])
+        cfg = cli.config_from_args(args)
+        assert cfg.network.USE_FPN and not cfg.network.USE_MASK
+        assert cfg.network.depth == 50 and cfg.dataset.NUM_CLASSES == 81
+        assert cfg.TRAIN.BATCH_IMAGES == batch
+        assert cfg.network.COMPUTE_DTYPE == dtype
+        assert args.max_steps == steps and args.lr == 1e-05
+    with open(os.path.join(REPO_ROOT, "benchmark", "configs",
+                           "frcnn_r50_fpn_coco.json")) as f:
+        cell_argv = json.load(f)["train_argv"]
+    assert chip_smoke.TRAIN_FPN_BF16_ARGV[:4] == cell_argv
+
+
+def test_streaming_train_shapes_are_the_pyramid_s_p2_and_p3():
+    from mx_rcnn_tpu.ops.pallas.roi_align import fits_vmem
+
+    cases = chip_smoke.STREAM_TRAIN_MAPS
+    assert [c[0] for c in cases] == ["stream_p2", "stream_p3"]
+    for _tag, (b, h, w, c), stride in cases:
+        assert (b, c) == (8, 256)
+        assert (h * stride, w * stride) == (608, 1024)
+        for esize in (2, 4):  # over the resident budget: these stream
+            assert not fits_vmem(h, w, c, (14, 14), esize)
+
+
+def test_the_compiled_step_s_text_is_kept_once():
+    """``train_phase(kernels=...)`` stands between ``train_net`` and the
+    step it builds: the first call's shapes are compiled once more and
+    the text kept; later calls go straight through."""
+    import jax
+    import jax.numpy as jnp
+
+    def make_train_step(scale):
+        def step(state, batch, rng):
+            return state * scale + batch["x"].sum(), {"loss": rng.sum()}
+
+        return jax.jit(step)
+
+    cli = SimpleNamespace(make_train_step=make_train_step)
+    found: dict = {}
+    saved = chip_smoke._compiled_step_text(cli, found)
+    assert saved is make_train_step and cli.make_train_step is not saved
+    step = cli.make_train_step(2.0)
+    args = (jnp.ones((3,)), {"x": jnp.ones((2, 2))}, jnp.zeros((2,)))
+    out, _aux = step(*args)
+    assert out.tolist() == [6.0, 6.0, 6.0]
+    text = found["text"]
+    assert "f32[2,2]" in text and "HloModule" in text
+    step(*args)
+    assert found["text"] is text
+
+
+def test_tiny_pyramid_train_phase_counts_rois_by_level(tmp_path, monkeypatch):
+    """The pyramid through ``train_phase`` at the tiny size, one image a
+    virtual device - the per-chip batch 1 that ``per_image`` serves - and
+    the per-level counters in ``report``: every sampled roi on one level."""
+    from mx_rcnn_tpu.tools import train_end2end as cli
+
+    monkeypatch.setattr(cli, "generate_config", _tiny_generate_config)
+    _state, report = chip_smoke.train_phase([
+        "--network", "resnet_fpn", "--dataset", "PascalVOC",
+        "--synthetic", "16", "--epochs", "1", "--frequent", "1",
+        "--batch_images", "1", "--lr", "1e-05", "--max_steps", "2",
+        "--prefix", str(tmp_path / "ckpt"),
+    ])
+    levels = report["roi_levels"]
+    assert set(levels) == {f"num_rois_p{lv}" for lv in (2, 3, 4, 5)}
+    # aux is averaged over the devices: 16 rois an image, 2 steps
+    assert sum(levels.values()) == pytest.approx(2 * 16)
+
+
 def test_serve_phase_small_config():
     report = chip_smoke.serve_phase([
         "--small", "--max_batch", "2", "--requests", "8",
