@@ -178,7 +178,21 @@ STREAM_TRAIN_MAPS = (
 )
 
 
-def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
+def _level_rois(rng, b, r, h_img, w_img, stride):
+    """(B, R, 4) boxes of the level that pools them at ``stride``: sides up
+    to 28 cells (eq. 1 hands P2 the rois under 112 pixels, P3 those under
+    224), anywhere in the image."""
+    import numpy as np
+
+    side = rng.rand(b, r, 2) * 28 * stride
+    x1 = rng.rand(b, r) * (w_img - side[..., 0])
+    y1 = rng.rand(b, r) * (h_img - side[..., 1])
+    return np.stack([x1, y1, x1 + side[..., 0], y1 + side[..., 1]],
+                    axis=-1).astype(np.float32)
+
+
+def kernels_phase(interpret: bool = False, name: str = "kernels",
+                  train_maps=None) -> dict:
     """The Pallas kernels against the repo's own references, on a small
     input, on the device that will run them.  The tier-1 tests check the
     kernels' arithmetic in interpret mode; what Mosaic compiled from them
@@ -189,7 +203,11 @@ def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
     so that no pair sits within 1e-4 of the IoU threshold.
     On the chip (not under the interpreter, where they would take many
     minutes) also the streaming pair at P2's and P3's shapes in the
-    pyramid's train step (``STREAM_TRAIN_MAPS``)."""
+    pyramid's train step (``STREAM_TRAIN_MAPS``; ``train_maps`` lets the
+    CPU rehearsal put tiny ones in their place), each twice: every roi
+    pooled, and as ``pool_levels`` calls it, with a span of the level's
+    own rois in a sorted list (a third to a half of them, the rest zero
+    boxes) against the gather on the own rois alone."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -203,14 +221,20 @@ def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
     rng = np.random.RandomState(0)
     errs = {}
 
-    def fwd_bwd(fn, feat, rois, cot):
-        """→ (output, d(sum(output·cot))/d(feat)) as f32 numpy."""
-        def loss(f):
-            out = fn(f, rois)
-            return (out.astype(jnp.float32) * cot).sum(), out
+    def fwd_bwd(fn):
+        """→ jitted (feat, rois, cot) → (output, d(sum(output·cot))/d(feat))
+        as f32 numpy; one compile a dtype however often it is called."""
+        def loss(f, r, c):
+            out = fn(f, r)
+            return (out.astype(jnp.float32) * c).sum(), out
 
-        (_, out), grad = jax.jit(jax.value_and_grad(loss, has_aux=True))(feat)
-        return np.asarray(out, np.float32), np.asarray(grad, np.float32)
+        run = jax.jit(jax.value_and_grad(loss, has_aux=True))
+
+        def call(feat, rois, cot):
+            (_, out), grad = run(feat, rois, cot)
+            return np.asarray(out, np.float32), np.asarray(grad, np.float32)
+
+        return call
 
     def rel(got, ref):
         return float(np.abs(got - ref).max() / np.abs(ref).max())
@@ -225,13 +249,14 @@ def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
         ("stream", roi_align_stream, (1, 152, 256, 128), 128, (14, 14), 4,
          None),
     ]
-    if not interpret:
-        cases += [(tag, roi_align_stream, shape, 128, (14, 14), stride, None)
-                  for tag, shape, stride in STREAM_TRAIN_MAPS]
+    if train_maps is None:
+        train_maps = () if interpret else STREAM_TRAIN_MAPS
+    cases += [(tag, roi_align_stream, shape, 128, (14, 14), stride, None)
+              for tag, shape, stride in train_maps]
     # the train-shape cases draw from a stream of their own, so the NMS
     # probe below keeps the boxes it always had (checked not borderline)
     train_rng = np.random.RandomState(1)
-    train_tags = {tag for tag, _shape, _stride in STREAM_TRAIN_MAPS}
+    train_tags = {tag for tag, _shape, _stride in train_maps}
     for tag, kernel, shape, n_rois, pooled, stride, valid_hw in cases:
         b, h, w, c = shape
         scale = 1.0 / stride
@@ -241,21 +266,42 @@ def kernels_phase(interpret: bool = False, name: str = "kernels") -> dict:
             _random_rois(draw, b, n_rois, h * stride, w * stride))
         cot = jnp.asarray(draw.randn(b, n_rois, *pooled, c).astype(np.float32))
 
-        def reference(f, r):
+        def gather(f, r):
             return jax.vmap(
                 lambda f1, r1: roi_align(f1, r1, pooled, scale, 2)
             )(f, r)
 
-        def pallas(f, r):
+        def every_roi(f, r):
             return kernel(f, r, pooled, scale, 2, interpret)
 
-        ref_out, ref_grad = fwd_bwd(reference, feat, rois, cot)
-        for dtype, out_grad in (
-            ("f32", fwd_bwd(pallas, feat, rois, cot)),
-            ("bf16", fwd_bwd(pallas, feat.astype(jnp.bfloat16), rois, cot)),
-        ):
-            errs[f"{tag}_{dtype}_fwd"] = rel(out_grad[0], ref_out)
-            errs[f"{tag}_{dtype}_bwd"] = rel(out_grad[1], ref_grad)
+        reference, pallas = fwd_bwd(gather), fwd_bwd(every_roi)
+        ref_out, ref_grad = reference(feat, rois, cot)
+        for dtype, f in (("f32", feat), ("bf16", feat.astype(jnp.bfloat16))):
+            out, grad = pallas(f, rois, cot)
+            errs[f"{tag}_{dtype}_fwd"] = rel(out, ref_out)
+            errs[f"{tag}_{dtype}_bwd"] = rel(grad, ref_grad)
+        if tag in train_tags:
+            # the step's own call: the list sorted by level, this level's
+            # rois in [start, start + count), every other roi a zero box
+            count = draw.randint(n_rois // 3, n_rois // 2 + 1, size=b)
+            start = (draw.rand(b) * (n_rois - count + 1)).astype(np.int64)
+            span = jnp.asarray(np.stack([start, count], axis=1), jnp.int32)
+            at = np.arange(n_rois)[None]
+            own = (at >= start[:, None]) & (at < (start + count)[:, None])
+            rois = jnp.asarray(np.where(own[..., None], _level_rois(
+                draw, b, n_rois, h * stride, w * stride, stride), 0.0))
+            own = own[..., None, None, None]
+            # the gather pools every roi: only the own rois' cotangent is
+            # non-zero, and only their rows are compared
+            cot = jnp.where(own, cot, 0.0)
+            ref_out, ref_grad = reference(feat, rois, cot)
+            ref_out = np.where(own, ref_out, 0.0)
+            spanned = fwd_bwd(lambda f, r: jnp.where(own, kernel(
+                f, r, pooled, scale, 2, interpret, span), 0.0))
+            for dtype, f in (("f32", feat), ("bf16", feat.astype(jnp.bfloat16))):
+                out, grad = spanned(f, rois, cot)
+                errs[f"{tag}_span_{dtype}_fwd"] = rel(out, ref_out)
+                errs[f"{tag}_span_{dtype}_bwd"] = rel(grad, ref_grad)
         if valid_hw is None:
             continue
         valid_hw = jnp.asarray(valid_hw)

@@ -148,21 +148,90 @@ def roi_level_counts(rois: jnp.ndarray) -> Dict[str, jnp.ndarray]:
     return {f"num_rois_p{lv}": (levels == lv).sum() for lv in range(2, 6)}
 
 
+def _level_spans(levels: jnp.ndarray) -> jnp.ndarray:
+    """(B, R) levels → (B, 4, 2) int32: ``[start, count]`` of each level's
+    rois in the image's list once it is sorted by level."""
+    count = (levels[..., None] == jnp.arange(2, 6)).sum(axis=1)
+    return jnp.stack([jnp.cumsum(count, axis=1) - count, count], axis=-1)
+
+
+def roi_stream_steps(pyramid, rois: jnp.ndarray,
+                     pooled_size) -> Dict[str, jnp.ndarray]:
+    """``roi_steps_live_p<l>`` and ``roi_steps_p<l>`` for every level the
+    streaming ROIAlign pair pools in a differentiated graph: of the
+    (roi block, image) pairs its grid walks (the second, a constant), how
+    many hold a roi of the level (the first; on the others the kernels do
+    nothing and fetch nothing).  Counters of the step's ``aux`` beside
+    :func:`roi_level_counts`; empty where no level streams."""
+    from mx_rcnn_tpu.ops.pallas.roi_align_stream import live_roi_blocks
+    from mx_rcnn_tpu.ops.roi_align import roi_align_kernel
+
+    spans = _level_spans(roi_levels(rois))
+    out = {}
+    for li, feat in enumerate(pyramid[:4]):
+        if roi_align_kernel(feat, tuple(pooled_size)) != "stream":
+            continue
+        live = live_roi_blocks(
+            spans[:, li], rois.shape[1], tuple(pooled_size), feat.shape[-1]
+        )
+        out[f"roi_steps_live_p{li + 2}"] = live.sum()
+        out[f"roi_steps_p{li + 2}"] = jnp.asarray(live.size, jnp.int32)
+    return out
+
+
+@jax.custom_vjp
+def _reorder(x, order, inverse):
+    """``x[b, order[b]]`` along axis 1, for a permutation ``order`` whose
+    inverse the caller has: the cotangent comes back by a gather through
+    ``inverse`` where autodiff would emit a scatter-add."""
+    return jnp.take_along_axis(
+        x, order.reshape(order.shape + (1,) * (x.ndim - 2)), axis=1
+    )
+
+
+def _reorder_fwd(x, order, inverse):
+    return _reorder(x, order, inverse), (order, inverse)
+
+
+def _reorder_bwd(res, g):
+    order, inverse = res
+    zero = np.zeros(order.shape, jax.dtypes.float0)
+    return _reorder(g, inverse, order), zero, zero
+
+
+_reorder.defvjp(_reorder_fwd, _reorder_bwd)
+
+
 def pool_levels(pyramid, rois: jnp.ndarray, pooled_size, strides,
                 sample_ratio: int, fwd_only: bool = False, valid_hw=None):
     """Masked multi-level ROIAlign: P2.. maps × (B, R, 4) rois →
     (B, R, ph, pw, C), each roi's features from the level eq. 1 gives it.
 
     Static shapes: every level's call takes all R rois and the results are
-    blended by a one-hot level mask.  A roi of ANOTHER level is handed to
-    the call as a zero box: its result is masked away whatever it is, and
-    the kernels' work grows with a roi's extent on the map (the streaming
-    pair skips the row blocks a roi does not touch) — a 400-pixel roi
-    pooled on the stride-4 map for nothing cost more than every roi that
-    belongs there, and made the step's time follow the proposals' sizes
-    (9% between seeds on the chip; PERF.md §6, PR 28).  Exact: the rows
-    that count are computed as before."""
-    levels = roi_levels(rois)                            # (B, R) in [2, 5]
+    selected by the level mask.  Each image's rois are sorted by level
+    ONCE (stable), so level l owns the contiguous span
+    ``[start, start + count)`` of the sorted list; every call gets the
+    sorted rois and its span, and the pooled result goes back into the
+    caller's order once, after the selection (a gather by the inverse
+    order, forward and backward).  The streaming kernels (P2, P3 at
+    flagship resolution in a differentiated graph) visit the span's rois
+    alone and leave the other rows undefined — hence ``jnp.where`` below,
+    never a product.  The resident kernels and the gather take no span:
+    there a roi of ANOTHER level is a zero box, because the kernels' work
+    grows with a roi's extent on the map — a 400-pixel roi pooled on a
+    fine map for nothing cost more than every roi that belongs there, and
+    made the step's time follow the proposals' sizes (9% between seeds on
+    the chip; PERF.md §6, PR 28).  Exact: the rows that count are computed
+    as before."""
+    # sort and way back under roi_align (the scope's metric pays for
+    # them), the selection outside it as it always was
+    with jax.named_scope("roi_align"):
+        levels = roi_levels(rois)                        # (B, R) in [2, 5]
+        order = jnp.argsort(levels, axis=1, stable=True)
+        inverse = jnp.argsort(order, axis=1)
+        rois = _reorder(rois, order, inverse)
+        levels = jnp.take_along_axis(levels, order, axis=1)
+        spans = _level_spans(levels)
     pooled = None
     for li, stride in enumerate(strides):
         own = levels == li + 2
@@ -172,11 +241,12 @@ def pool_levels(pyramid, rois: jnp.ndarray, pooled_size, strides,
             feats = extract_roi_features_batched(
                 pyramid[li], jnp.where(own[..., None], rois, 0.0),
                 "roi_align", pooled_size, 1.0 / stride, sample_ratio,
-                fwd_only=fwd_only, valid_hw=valid_hw,
+                fwd_only=fwd_only, valid_hw=valid_hw, span=spans[:, li],
             )                                            # (B, R, ph, pw, C)
         contrib = jnp.where(own[..., None, None, None], feats, 0.0)
         pooled = contrib if pooled is None else pooled + contrib
-    return pooled
+    with jax.named_scope("roi_align"):
+        return _reorder(pooled, inverse, order)
 
 
 class FPNFasterRCNN(nn.Module):
@@ -414,6 +484,8 @@ class FPNFasterRCNN(nn.Module):
             "num_fg_anchors": (atgt.labels == 1).sum(),
         }
         aux.update(roi_level_counts(samples.rois))
+        aux.update(roi_stream_steps(
+            pyramid, samples.rois, cfg.network.POOLED_SIZE))
 
         if cfg.network.USE_MASK:
             mask_loss, mask_aux = self._mask_loss(

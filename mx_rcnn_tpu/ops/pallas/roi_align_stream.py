@@ -10,12 +10,30 @@ This kernel STREAMS the feature map through VMEM in row blocks instead:
 - forward: grid (B, C-blocks, roi-blocks, H-blocks); a VMEM scratch
   accumulator holds the roi-block's (rblk, PH, PW, cblk) outputs while
   row blocks stream past; each roi adds ``My[:, rows] @ F @ Mxᵀ`` for
-  the rows it intersects (``pl.when`` skips non-intersecting blocks, so
-  compute scales with roi extent, not map height).  HBM feature traffic
+  the rows it intersects.  Whether a roi touches the row block is decided
+  from scalars alone (``_row_extent``), before either matrix is built, so
+  compute scales with roi extent, not map height.  HBM feature traffic
   is (R/rblk)× the map per channel block — independent of R's 512.
 - backward: grid (B, C-blocks, H-blocks, roi-blocks); the (hblk, W,
   cblk) dfeat block stays resident while roi-blocks of cotangents
   stream past, accumulating ``My[:, rows]ᵀ @ g @ Mx``.
+
+With a ``span`` (``[start, count]`` an image, a second scalar-prefetch
+operand; ``models/fpn.py::pool_levels`` passes each pyramid level its
+own rois' range in a list sorted by level) both kernels visit the rois
+``start <= r < start + count`` only: the roi loop of a grid step runs over
+the span's intersection with its roi block (dynamic ``fori_loop``
+bounds), and a grid step whose intersection is empty is DEAD — it
+computes nothing, and the index map of the block it reads (forward: the
+feature row block; backward: the cotangent roi block) stays where the
+neighbouring live step has or wants it, so nothing is fetched either.
+Two things still happen on dead steps: the backward zeroes its output
+block at the first roi block (an image with an empty span returns a zero
+map), and the forward's output block of a dead roi block is written back
+as whatever the buffer held — rows outside the span are UNDEFINED and the
+caller selects them away.  Without a span (``None``) the pair has one
+scalar operand and a static loop over every roi, fillers skipped by their
+inverted boxes.
 
 Same bilinear semantics as the resident kernel (shared interpolation
 helpers; the row-restricted matrices are the same one-hot construction
@@ -30,11 +48,12 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from mx_rcnn_tpu.ops.pallas import out_struct
-from mx_rcnn_tpu.ops.pallas.roi_align import _sample_coords
+from mx_rcnn_tpu.ops.pallas.roi_align import _interp_matrix, _sample_coords
 
 
 def _interp_matrix_rows(lo_f, whi, offset, hblk: int, nbins: int, s: int):
@@ -55,9 +74,33 @@ def _interp_matrix_rows(lo_f, whi, offset, hblk: int, nbins: int, s: int):
     return m.reshape(nbins, s, hblk).sum(axis=1) * (1.0 / s)
 
 
+def _scaled_roi(rois_ref, b, r, scale: float):
+    """(x1, y1, x2, y2) of roi ``r`` of image ``b`` in feature cells."""
+    return tuple(rois_ref[b, k, r] * scale for k in range(4))
+
+
+def _row_extent(rois_ref, b, r, hf: int, scale: float):
+    """(valid, lo_cell, hi_cell) of one roi, from scalars alone: whether
+    it is a real box, and the conservative GLOBAL row extent of its sample
+    support — the kernels' block-skip predicate, decided before any
+    matrix is built.  Sample points live in
+    [clip(y1), clip(y1 + max(y2-y1, 1))] (the min-length clamp in
+    _sample_coords means a degenerate roi still reaches ~y1+1, NOT y2!),
+    and each contributes to rows floor(g) and floor(g)+1; clamping into
+    [0, hf-1] keeps fully-offscreen rois pointing at the edge rows their
+    clipped samples actually hit."""
+    x1, y1, x2, y2 = _scaled_roi(rois_ref, b, r, scale)
+    valid = x2 >= x1  # inverted boxes are _pad_rois fillers
+    lo_cell = jnp.clip(jnp.floor(y1), 0.0, float(hf - 1))
+    hi_cell = jnp.clip(
+        jnp.floor(y1 + jnp.maximum(y2 - y1, 1.0)) + 1.0, 0.0, float(hf - 1)
+    )
+    return valid, lo_cell, hi_cell
+
+
 def _row_matrices(rois_ref, b, r, hf: int, wf: int, offset, hblk: int,
                   pooled, s: int, scale: float):
-    """(My_sub (PH, hblk), Mx (PW, W), y-extent scalars) for one roi.
+    """(My_sub (PH, hblk), Mx (PW, W)) for one roi and one row block.
 
     The hi=lo+1 cap at size-1 is folded into the coords: a sample with
     lo == size-1 gets whi forced to 0 so all its weight lands on lo —
@@ -65,68 +108,95 @@ def _row_matrices(rois_ref, b, r, hf: int, wf: int, offset, hblk: int,
     one-hot terms colliding on the same cell.
     """
     ph, pw = pooled
-    x1 = rois_ref[b, 0, r] * scale
-    y1 = rois_ref[b, 1, r] * scale
-    x2 = rois_ref[b, 2, r] * scale
-    y2 = rois_ref[b, 3, r] * scale
-    valid = x2 >= x1  # inverted boxes are _pad_rois fillers
+    x1, y1, x2, y2 = _scaled_roi(rois_ref, b, r, scale)
     ylo, ywhi = _sample_coords(y1, y2, float(hf - 1), ph, s)
     xlo, xwhi = _sample_coords(x1, x2, float(wf - 1), pw, s)
     # cap: when lo is the last row/col, send the hi-weight to lo as well
     # (resident kernel achieves this because lo==hi makes both one-hot
     # terms hit the same cell; here lo+1 would fall outside)
-    ylo_last = ylo == float(hf - 1)
-    ywhi = jnp.where(ylo_last, 0.0, ywhi)
-    xlo_last = xlo == float(wf - 1)
-    xwhi = jnp.where(xlo_last, 0.0, xwhi)
-
+    ywhi = jnp.where(ylo == float(hf - 1), 0.0, ywhi)
+    xwhi = jnp.where(xlo == float(wf - 1), 0.0, xwhi)
     my = _interp_matrix_rows(ylo, ywhi, offset, hblk, ph, s)     # (PH, hblk)
-    from mx_rcnn_tpu.ops.pallas.roi_align import _interp_matrix
-
     mx = _interp_matrix(xlo, xwhi, wf, float(wf - 1), pw, s)    # (PW, W)
-    # conservative GLOBAL row extent of the roi's sample support, for
-    # the caller's block-skip predicate.  Sample points live in
-    # [clip(y1), clip(y1 + max(y2-y1, 1))] (the min-length clamp in
-    # _sample_coords means a degenerate roi still reaches ~y1+1, NOT
-    # y2!), and each contributes to rows floor(g) and floor(g)+1;
-    # clamping into [0, hf-1] keeps fully-offscreen rois pointing at
-    # the edge rows their clipped samples actually hit.
-    lo_cell = jnp.clip(jnp.floor(y1), 0.0, float(hf - 1))
-    hi_cell = jnp.clip(
-        jnp.floor(y1 + jnp.maximum(y2 - y1, 1.0)) + 1.0, 0.0, float(hf - 1)
-    )
-    return my, mx, valid, lo_cell, hi_cell
+    return my, mx
 
 
-def _fwd_kernel(rois_ref, feat_ref, out_ref, acc_ref, *, pooled, s, scale,
-                hblk, n_hblk, rblk, hf):
+def _own_range(span_ref, b, rb, rblk: int):
+    """[lo, hi) within roi block ``rb`` of image ``b`` that the image's
+    span ``[start, start + count)`` covers; empty (hi <= lo) where the
+    block holds none of the span's rois.  Scalars, so the kernels' loops
+    and the index maps decide by the same rule."""
+    start = span_ref[0, b]
+    stop = start + span_ref[1, b]
+    first = rb * rblk
+    return jnp.maximum(start - first, 0), jnp.minimum(stop - first, rblk)
+
+
+def live_roi_blocks(span, n_rois: int, pooled, channels: int):
+    """(B, 2) spans → (B, n_rblk) bool: the roi blocks of each image's
+    grid that hold a roi of its span — the kernels' own rule
+    (:func:`_own_range`) outside them, for the step's counters: on the
+    other (roi block, image) pairs every grid step is dead."""
+    rblk = _pick_rblk(pooled, _cblk(channels))
+    images = jnp.arange(span.shape[0])[:, None]
+    blocks = jnp.arange(-(-n_rois // rblk))[None]
+    lo, hi = _own_range(span.T, images, blocks, rblk)
+    return hi > lo
+
+
+def _block_range(span_ref, b, rb, rblk: int):
+    """The roi loop's bounds within roi block ``rb``: the whole block
+    (Python ints: a static loop) without a span, the span's part of it
+    (scalars: dynamic bounds, possibly empty) with one."""
+    if span_ref is None:
+        return 0, rblk
+    return _own_range(span_ref, b, rb, rblk)
+
+
+def _roi_loop(rois_ref, b, rb, lo, hi, rblk, hf, scale, offset, hblk, visit):
+    """``visit(i, r)`` for every roi ``r = rb*rblk + i``, ``lo <= i < hi``,
+    that is a real box and whose rows reach the row block at ``offset``."""
+    def body(i, _):
+        r = rb * rblk + i
+        valid, lo_cell, hi_cell = _row_extent(rois_ref, b, r, hf, scale)
+
+        @pl.when(valid & (hi_cell >= offset) & (lo_cell <= offset + (hblk - 1)))
+        def _():
+            visit(i, r)
+
+        return 0
+
+    jax.lax.fori_loop(lo, hi, body, 0)
+
+
+def _fwd_kernel(rois_ref, *refs, pooled, s, scale, hblk, n_hblk, rblk, hf):
+    """``refs``: ``[span_ref,] feat_ref, out_ref, acc_ref``."""
+    *span, feat_ref, out_ref, acc_ref = refs  # the span only where given
+    span_ref = span[0] if span else None
     b = pl.program_id(0)
     rb = pl.program_id(2)
     hb = pl.program_id(3)
     wf = feat_ref.shape[2]
     offset = hb * hblk  # int; promotes against the f32 iota/extents
+    lo, hi = _block_range(span_ref, b, rb, rblk)
 
-    @pl.when(hb == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    feat = feat_ref[0]                                           # (hblk, W, CB)
-    # rows past H in the (padded) last block hold uninitialized memory;
-    # their interpolation weight is zero, but 0·NaN/Inf would still
-    # poison the matmul accumulator — mask them to real zeros
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, (hblk, 1, 1), 0) + offset
-    feat = jnp.where(row_ids < hf, feat, jnp.zeros_like(feat))
-    f32 = feat.dtype != jnp.bfloat16
-
-    def body(i, _):
-        r = rb * rblk + i
-        my, mx, valid, lo_cell, hi_cell = _row_matrices(
-            rois_ref, b, r, hf, wf, offset, hblk, pooled, s, scale
-        )
-
-        # skip fillers and row blocks outside the sample-support extent
-        @pl.when(valid & (hi_cell >= offset) & (lo_cell <= offset + (hblk - 1)))
+    def step():
+        @pl.when(hb == 0)
         def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        feat = feat_ref[0]                                       # (hblk, W, CB)
+        # rows past H in the (padded) last block hold uninitialized memory;
+        # their interpolation weight is zero, but 0·NaN/Inf would still
+        # poison the matmul accumulator — mask them to real zeros
+        row_ids = jax.lax.broadcasted_iota(jnp.int32, (hblk, 1, 1), 0) + offset
+        feat = jnp.where(row_ids < hf, feat, jnp.zeros_like(feat))
+        f32 = feat.dtype != jnp.bfloat16
+
+        def visit(i, r):
+            my, mx = _row_matrices(
+                rois_ref, b, r, hf, wf, offset, hblk, pooled, s, scale
+            )
             if f32:
                 rows = jax.lax.dot_general(
                     my, feat.astype(jnp.float32), (((1,), (0,)), ((), ())),
@@ -153,23 +223,33 @@ def _fwd_kernel(rois_ref, feat_ref, out_ref, acc_ref, *, pooled, s, scale,
             # measured that pattern at 35 ms)
             acc_ref[i] = acc_ref[i] + out
 
-        return 0
+        _roi_loop(rois_ref, b, rb, lo, hi, rblk, hf, scale, offset, hblk,
+                  visit)
 
-    jax.lax.fori_loop(0, rblk, body, 0)
+        @pl.when(hb == n_hblk - 1)
+        def _():
+            out_ref[0] = acc_ref[...].transpose(0, 2, 1, 3).astype(out_ref.dtype)
 
-    @pl.when(hb == n_hblk - 1)
-    def _():
-        out_ref[0] = acc_ref[...].transpose(0, 2, 1, 3).astype(out_ref.dtype)
+    if span_ref is None:
+        step()
+    else:
+        # a roi block outside the span is a dead step: no zeroing, no
+        # flush — its output block keeps whatever the buffer held, rows
+        # the caller must not read (pool_levels selects them away)
+        pl.when(hi > lo)(step)
 
 
-def _bwd_kernel(rois_ref, g_ref, dfeat_ref, *, pooled, s, scale, hblk,
-                rblk, hf):
+def _bwd_kernel(rois_ref, *refs, pooled, s, scale, hblk, rblk, hf):
+    """``refs``: ``[span_ref,] g_ref, dfeat_ref``."""
+    *span, g_ref, dfeat_ref = refs
+    span_ref = span[0] if span else None
     b = pl.program_id(0)
     hb = pl.program_id(2)
     rb = pl.program_id(3)
     wf = dfeat_ref.shape[2]
     offset = hb * hblk
 
+    # on dead steps too: an image whose span is empty returns a zero map
     @pl.when(rb == 0)
     def _():
         dfeat_ref[...] = jnp.zeros_like(dfeat_ref)
@@ -183,29 +263,25 @@ def _bwd_kernel(rois_ref, g_ref, dfeat_ref, *, pooled, s, scale, hblk,
         else jax.lax.Precision.DEFAULT
     )
 
-    def body(i, _):
-        r = rb * rblk + i
-        my, mx, valid, lo_cell, hi_cell = _row_matrices(
+    def visit(i, r):
+        my, mx = _row_matrices(
             rois_ref, b, r, hf, wf, offset, hblk, pooled, s, scale
         )
+        g = g_ref[0, i].astype(jnp.float32)                      # (PH, PW, CB)
+        # t: (W, PH, CB) = Mxᵀ contract PW;  d: (hblk, W, CB)
+        t = jax.lax.dot_general(
+            mx, g, (((0,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
+        )
+        d = jax.lax.dot_general(
+            my, t, (((0,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
+        )                                                        # (hblk, W, CB)
+        dfeat_ref[0] = dfeat_ref[0] + d
 
-        @pl.when(valid & (hi_cell >= offset) & (lo_cell <= offset + (hblk - 1)))
-        def _():
-            g = g_ref[0, i].astype(jnp.float32)                  # (PH, PW, CB)
-            # t: (W, PH, CB) = Mxᵀ contract PW;  d: (hblk, W, CB)
-            t = jax.lax.dot_general(
-                mx, g, (((0,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32, precision=prec,
-            )
-            d = jax.lax.dot_general(
-                my, t, (((0,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32, precision=prec,
-            )                                                    # (hblk, W, CB)
-            dfeat_ref[0] = dfeat_ref[0] + d
-
-        return 0
-
-    jax.lax.fori_loop(0, rblk, body, 0)
+    # with a span a dead step's loop is empty: nothing else to skip
+    lo, hi = _block_range(span_ref, b, rb, rblk)
+    _roi_loop(rois_ref, b, rb, lo, hi, rblk, hf, scale, offset, hblk, visit)
 
 
 # Both kernels' blocks are sized by budget (_pick_hblk: 2 MiB of feature
@@ -216,6 +292,10 @@ def _bwd_kernel(rois_ref, g_ref, dfeat_ref, *, pooled, s, scale, hblk,
 # temporaries; f32 forward is the top of the range — ISSUE 21).  That is
 # over Mosaic's default 16 MiB scoped limit, so the kernels state theirs.
 _VMEM_LIMIT = 32 * 2**20
+
+
+def _cblk(c: int) -> int:
+    return 128 if c % 128 == 0 else c
 
 
 def _pick_hblk(w: int, cblk: int, budget: int = 2 * 2**20) -> int:
@@ -245,12 +325,23 @@ def _pad_rois(rois, rblk):
     return rois, r
 
 
-def _fwd_impl(feat, rois, pooled, scale, s, interpret, rblk=None):
-    b, hf, wf, c = feat.shape
-    cblk = 128 if c % 128 == 0 else c
-    rblk = rblk or _pick_rblk(pooled, cblk)
+def _scalar_prefetch(rois, span, rblk):
+    """→ (the kernels' scalar-prefetch operands, true R): the padded rois
+    as (B, 4, Rp) f32 and, with a ``span`` (B, 2), its (2, B) int32
+    ``[start, count]`` rows."""
     rois_p, r_true = _pad_rois(rois, rblk)
-    r = rois_p.shape[1]
+    rois_t = rois_p.astype(jnp.float32).transpose(0, 2, 1)
+    if span is None:
+        return (rois_t,), r_true
+    return (rois_t, span.astype(jnp.int32).T), r_true
+
+
+def _fwd_impl(feat, rois, span, pooled, scale, s, interpret):
+    b, hf, wf, c = feat.shape
+    cblk = _cblk(c)
+    rblk = _pick_rblk(pooled, cblk)
+    prefetch, r_true = _scalar_prefetch(rois, span, rblk)
+    r = prefetch[0].shape[2]
     hblk = _pick_hblk(wf, cblk)
     n_hblk = -(-hf // hblk)
     grid = (b, c // cblk, r // rblk, n_hblk)
@@ -258,21 +349,26 @@ def _fwd_impl(feat, rois, pooled, scale, s, interpret, rblk=None):
         _fwd_kernel, pooled=pooled, s=s, scale=scale, hblk=hblk,
         n_hblk=n_hblk, rblk=rblk, hf=hf,
     )
+
+    def feat_block(bb, cb, rb, hb, rois_ref, span_ref=None):
+        if span_ref is not None:
+            # a dead step fetches nothing: before the span it waits on the
+            # row block the first live step needs, after it it stays on
+            # the last one fetched
+            lo, hi = _own_range(span_ref, bb, rb, rblk)
+            hb = jnp.where(hi > lo, hb, jnp.where(hi <= 0, n_hblk - 1, 0))
+        return bb, hb, 0, cb
+
     out = pl.pallas_call(
         kernel,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
-            in_specs=[
-                pl.BlockSpec(
-                    (1, hblk, wf, cblk),
-                    lambda bb, cb, rb, hb, rois_ref: (bb, hb, 0, cb),
-                ),
-            ],
+            in_specs=[pl.BlockSpec((1, hblk, wf, cblk), feat_block)],
             out_specs=pl.BlockSpec(
                 (1, rblk, pooled[0], pooled[1], cblk),
-                lambda bb, cb, rb, hb, rois_ref: (bb, rb, 0, 0, cb),
+                lambda bb, cb, rb, hb, *prefetch_refs: (bb, rb, 0, 0, cb),
             ),
             scratch_shapes=[
                 # transposed (PW, PH) layout — see the kernel's flush
@@ -280,21 +376,21 @@ def _fwd_impl(feat, rois, pooled, scale, s, interpret, rblk=None):
             ],
         ),
         out_shape=out_struct(
-            (b, r, pooled[0], pooled[1], c), feat.dtype, rois_p, feat
+            (b, r, pooled[0], pooled[1], c), feat.dtype, *prefetch, feat
         ),
         interpret=interpret,
         name="pallas_roi_features_stream_fwd",
-    )(rois_p.astype(jnp.float32).transpose(0, 2, 1), feat)
+    )(*prefetch, feat)
     return out[:, :r_true]
 
 
-def _bwd_impl(feat_shape, feat_dtype, rois, g, pooled, scale, s, interpret,
-              rblk=None):
+def _bwd_impl(feat_shape, feat_dtype, rois, span, g, pooled, scale, s,
+              interpret):
     b, hf, wf, c = feat_shape
-    cblk = 128 if c % 128 == 0 else c
-    rblk = rblk or _pick_rblk(pooled, cblk)
-    rois_p, r_true = _pad_rois(rois, rblk)
-    r = rois_p.shape[1]
+    cblk = _cblk(c)
+    rblk = _pick_rblk(pooled, cblk)
+    prefetch, _ = _scalar_prefetch(rois, span, rblk)
+    r = prefetch[0].shape[2]
     if r != g.shape[1]:
         g = jnp.concatenate(
             [g, jnp.zeros((b, r - g.shape[1]) + g.shape[2:], g.dtype)], axis=1
@@ -307,27 +403,39 @@ def _bwd_impl(feat_shape, feat_dtype, rois, g, pooled, scale, s, interpret,
         _bwd_kernel, pooled=pooled, s=s, scale=scale, hblk=hblk,
         rblk=rblk, hf=hf,
     )
+
+    def g_block(bb, cb, hb, rb, rois_ref, span_ref=None):
+        if span_ref is not None:
+            # a dead step fetches nothing: the cotangent block stays on the
+            # span's nearest roi block (the first one ahead of the span,
+            # the last one fetched behind it)
+            start = span_ref[0, bb]
+            last = jnp.maximum(start + span_ref[1, bb] - 1, start)
+            rb = jnp.clip(
+                rb,
+                jnp.minimum(jax.lax.div(start, rblk), n_rblk - 1),
+                jax.lax.div(last, rblk),
+            )
+        return bb, rb, 0, 0, cb
+
     out = pl.pallas_call(
         kernel,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=[
-                pl.BlockSpec(
-                    (1, rblk, pooled[0], pooled[1], cblk),
-                    lambda bb, cb, hb, rb, rois_ref: (bb, rb, 0, 0, cb),
-                ),
+                pl.BlockSpec((1, rblk, pooled[0], pooled[1], cblk), g_block)
             ],
             out_specs=pl.BlockSpec(
                 (1, hblk, wf, cblk),
-                lambda bb, cb, hb, rb, rois_ref: (bb, hb, 0, cb),
+                lambda bb, cb, hb, rb, *prefetch_refs: (bb, hb, 0, cb),
             ),
         ),
-        out_shape=out_struct((b, hf, wf, c), jnp.float32, rois_p, g),
+        out_shape=out_struct((b, hf, wf, c), jnp.float32, *prefetch, g),
         interpret=interpret,
         name="pallas_roi_features_stream_bwd",
-    )(rois_p.astype(jnp.float32).transpose(0, 2, 1), g)
+    )(*prefetch, g)
     return out.astype(feat_dtype)
 
 
@@ -339,24 +447,37 @@ def roi_align_stream(
     spatial_scale: float = 0.25,
     sample_ratio: int = 2,
     interpret: bool = False,
+    span=None,
 ) -> jnp.ndarray:
     """(B, H, W, C) × (B, R, 4) → (B, R, ph, pw, C); the streaming twin
-    of ``roi_align_pallas`` for maps over the VMEM budget."""
-    return _fwd_impl(feat, rois, pooled, spatial_scale, sample_ratio, interpret)
+    of ``roi_align_pallas`` for maps over the VMEM budget.
+
+    ``span`` (B, 2) int32 = ``[start, count]`` an image: only the rois
+    ``start <= r < start + count`` are pooled (and only they receive the
+    cotangent's contribution to the map); the rows of every other roi are
+    UNDEFINED and the caller must select them away (``jnp.where``, not a
+    product: they may hold NaN).  ``None`` pools every roi."""
+    return _fwd_impl(
+        feat, rois, span, pooled, spatial_scale, sample_ratio, interpret
+    )
 
 
-def _vjp_fwd(feat, rois, pooled, spatial_scale, sample_ratio, interpret):
-    out = _fwd_impl(feat, rois, pooled, spatial_scale, sample_ratio, interpret)
-    return out, (feat, rois)
+def _vjp_fwd(feat, rois, pooled, spatial_scale, sample_ratio, interpret,
+             span=None):
+    out = _fwd_impl(
+        feat, rois, span, pooled, spatial_scale, sample_ratio, interpret
+    )
+    return out, (feat, rois, span)
 
 
 def _vjp_bwd(pooled, spatial_scale, sample_ratio, interpret, res, g):
-    feat, rois = res
+    feat, rois, span = res
     dfeat = _bwd_impl(
-        feat.shape, feat.dtype, rois, g, pooled, spatial_scale,
+        feat.shape, feat.dtype, rois, span, g, pooled, spatial_scale,
         sample_ratio, interpret,
     )
-    return dfeat, jnp.zeros_like(rois)
+    dspan = None if span is None else np.zeros(span.shape, jax.dtypes.float0)
+    return dfeat, jnp.zeros_like(rois), dspan
 
 
 roi_align_stream.defvjp(_vjp_fwd, _vjp_bwd)

@@ -209,6 +209,31 @@ def extract_roi_features(
     raise ValueError(f"unknown ROI_MODE {mode!r}")
 
 
+def roi_align_kernel(feat, pooled, fwd_only: bool = False, valid_hw=None):
+    """Which Pallas kernel pools this (B, H, W, C) map under ROIAlign:
+    ``"resident"`` (``ops/pallas/roi_align.py`` keeps an (H, W, cblk)
+    feature block in VMEM across the roi sweep), ``"stream"`` (maps over
+    that budget — FPN P2 at flagship resolution is 152×256 — take
+    ``ops/pallas/roi_align_stream.py``, which row-blocks the feature
+    through VMEM and accumulates the roi-block outputs in scratch), or
+    None: the jnp gather (no TPU, or an over-VMEM map that is
+    forward-only or carries ``valid_hw``; see
+    :func:`extract_roi_features_batched`)."""
+    from mx_rcnn_tpu.ops.pallas.roi_align import fits_vmem
+    from mx_rcnn_tpu.utils.platform import use_pallas
+
+    if not use_pallas():
+        return None
+    if fits_vmem(
+        feat.shape[1], feat.shape[2], feat.shape[3], pooled,
+        feat.dtype.itemsize,
+    ):
+        return "resident"
+    if not fwd_only and valid_hw is None:
+        return "stream"
+    return None
+
+
 def extract_roi_features_batched(
     feat: jnp.ndarray,
     rois: jnp.ndarray,
@@ -218,6 +243,7 @@ def extract_roi_features_batched(
     sample_ratio: int = 2,
     fwd_only: bool = False,
     valid_hw=None,
+    span=None,
 ) -> jnp.ndarray:
     """(B, H, W, C) × (B, R, 4) → (B, R, ph, pw, C).
 
@@ -245,35 +271,29 @@ def extract_roi_features_batched(
     cheap on a TPU: a ``lax.map`` of ten 32-roi chunks was 85% of the
     serve cell's device time before the kernel took ``valid_hw``
     (PERF.md, PR 26).
+
+    ``span`` (B, 2) int32 = ``[start, count]`` an image: the caller reads
+    the rois ``start <= r < start + count`` only and selects every other
+    row away (``models/fpn.py::pool_levels``: a level's own rois in a
+    list sorted by level).  The streaming pair then visits those rois
+    alone and leaves the other rows undefined; the resident kernels and
+    the gather pool every roi as without it.
     """
-    from mx_rcnn_tpu.utils.platform import use_pallas
+    kernel = (roi_align_kernel(feat, pooled, fwd_only, valid_hw)
+              if mode == "roi_align" else None)
+    if kernel == "resident":
+        from mx_rcnn_tpu.ops.pallas.roi_align import roi_align_pallas
 
-    # Two Pallas kernels: the resident one keeps an (H, W, cblk) feature
-    # block in VMEM across the roi sweep; maps over the budget (FPN P2 at
-    # flagship resolution is 152×256) take the STREAMING kernel, which
-    # row-blocks the feature through VMEM and accumulates the roi-block
-    # outputs in scratch (ops/pallas/roi_align_stream.py)
-    from mx_rcnn_tpu.ops.pallas.roi_align import fits_vmem
+        return roi_align_pallas(
+            feat, rois, pooled, spatial_scale, sample_ratio,
+            valid_hw=valid_hw,
+        )
+    if kernel == "stream":
+        from mx_rcnn_tpu.ops.pallas.roi_align_stream import roi_align_stream
 
-    if mode == "roi_align" and use_pallas():
-        if fits_vmem(
-            feat.shape[1], feat.shape[2], feat.shape[3], pooled,
-            feat.dtype.itemsize,
-        ):
-            from mx_rcnn_tpu.ops.pallas.roi_align import roi_align_pallas
-
-            return roi_align_pallas(
-                feat, rois, pooled, spatial_scale, sample_ratio,
-                valid_hw=valid_hw,
-            )
-        if not fwd_only and valid_hw is None:
-            from mx_rcnn_tpu.ops.pallas.roi_align_stream import (
-                roi_align_stream,
-            )
-
-            return roi_align_stream(
-                feat, rois, pooled, spatial_scale, sample_ratio
-            )
+        return roi_align_stream(
+            feat, rois, pooled, spatial_scale, sample_ratio, span=span
+        )
     if mode == "roi_pool" and not fwd_only:
         # SEQUENTIAL over the batch: differentiating roi_pool's chunked
         # masked-max under vmap saves every chunk's intermediate as a

@@ -185,8 +185,10 @@ def train_net(args, report=None):
     feeds), ``pipeline`` (``PipelinedLoop.stats()``) and ``loader`` (the
     assembly pool's stats, summed; empty on the serial loader) —
     ``roi_levels`` (a pyramid's sampled rois by pooling level,
-    ``num_rois_p2`` .. summed over the fetched steps; empty otherwise) and on
-    an elastic run ``elastic`` / ``degraded``."""
+    ``num_rois_p2`` .., and where its streaming ROIAlign kernels run their
+    live and walked (roi block, image) steps, ``roi_steps_live_p2`` /
+    ``roi_steps_p2`` ..; summed over the fetched steps; empty otherwise)
+    and on an elastic run ``elastic`` / ``degraded``."""
     import collections
 
     from mx_rcnn_tpu.utils.platform import cli_bootstrap
@@ -454,8 +456,10 @@ def train_net(args, report=None):
             else:
                 totals[k] += v
 
-    # a pyramid's sampled rois by the level that pools them
-    # (``num_rois_p2`` .. in the step's aux), summed over the fetched steps
+    # a pyramid's sampled rois by the level that pools them, and the
+    # streaming kernels' live steps of those they walk (``num_rois_p2`` ..,
+    # ``roi_steps_live_p2`` / ``roi_steps_p2`` .. in the step's aux),
+    # summed over the fetched steps
     roi_level_totals: collections.Counter = collections.Counter()
 
     def deliver(ready):
@@ -464,7 +468,8 @@ def train_net(args, report=None):
             tracker.update(values)
             losses.append((idx, values["loss"]))
             roi_level_totals.update(
-                {k: v for k, v in values.items() if k.startswith("num_rois_p")})
+                {k: v for k, v in values.items()
+                 if k.startswith(("num_rois_p", "roi_steps_"))})
 
     def flush_pipeline(state):
         # force the deferred aux checks before any checkpoint/summary:
@@ -574,8 +579,22 @@ def train_net(args, report=None):
             logger.info(
                 "host side: sampled rois by pyramid level: %s",
                 ", ".join(f"{k[len('num_rois_'):]} {v:.0f}"
-                          for k, v in sorted(roi_level_totals.items())),
+                          for k, v in sorted(roi_level_totals.items())
+                          if k.startswith("num_rois_")),
             )
+            live = {k[len("roi_steps_live_"):]: v
+                    for k, v in roi_level_totals.items()
+                    if k.startswith("roi_steps_live_")}
+            if live:
+                logger.info(
+                    "host side: streaming ROIAlign steps with a roi of their "
+                    "level, of those walked: %s",
+                    ", ".join(
+                        f"{lv} {v:.0f} of "
+                        f"{roi_level_totals[f'roi_steps_{lv}']:.0f} "
+                        f"({v / roi_level_totals[f'roi_steps_{lv}']:.1%})"
+                        for lv, v in sorted(live.items())),
+                )
         if report is not None:
             report.update(
                 steps=total_steps,

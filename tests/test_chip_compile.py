@@ -109,6 +109,34 @@ def test_roi_align_fwd_bwd_compiles(one_chip, kernel, feat, n_rois, pooled,
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
+@pytest.mark.parametrize("feat,scale", [
+    pytest.param((8, 152, 256, 256), 1 / 4, id="stream-p2-train"),
+    pytest.param((8, 76, 128, 256), 1 / 8, id="stream-p3-train"),
+])
+def test_roi_align_stream_with_a_span_compiles(one_chip, feat, scale, dtype):
+    """The streaming pair as ``pool_levels`` calls it in the pyramid's
+    train step: the level's ``[start, count]`` an image as a second
+    scalar-prefetch operand, the roi loop's bounds and the dead steps'
+    block indices computed from it (dynamic ``fori_loop`` bounds and an
+    index map that reads SMEM are what Mosaic could refuse)."""
+    def fwd_bwd(f, rois, span):
+        def loss(x):
+            out = roi_align_stream(x, rois, (14, 14), scale, 2, False, span)
+            return (out.astype(jnp.float32) ** 2).sum()
+
+        return jax.value_and_grad(loss)(f)
+
+    text = _compiled_text(
+        fwd_bwd, one_chip, (feat, dtype), ((feat[0], 128, 4), jnp.float32),
+        ((feat[0], 2), jnp.int32),
+    )
+    assert "pallas_roi_features_stream_fwd" in text
+    assert "pallas_roi_features_stream_bwd" in text
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
 def test_roi_align_serve_valid_hw_compiles(one_chip, dtype):
     """The serve graph's second stage as ``test_forward`` hands it over:
     the C4 map padded to the ladder's extent, 300 rois an image, forward

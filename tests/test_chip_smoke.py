@@ -76,6 +76,18 @@ def test_kernels_phase_in_interpret_mode():
     assert max(v for k, v in errs.items() if "f32" in k) < 1e-5
 
 
+def test_kernels_phase_span_cases_in_interpret_mode():
+    """The train-shape cases' second half (the streaming pair with a span,
+    as ``pool_levels`` calls it) on a map small enough to interpret."""
+    errs = chip_smoke.kernels_phase(
+        interpret=True, train_maps=(("stream_tiny", (2, 24, 32, 128), 4),))
+    span = {k: v for k, v in errs.items() if k.startswith("stream_tiny_span")}
+    assert set(span) == {
+        f"stream_tiny_span_{dtype}_{pass_}"
+        for dtype in ("f32", "bf16") for pass_ in ("fwd", "bwd")}
+    assert max(v for k, v in span.items() if "f32" in k) < 1e-5
+
+
 def test_pyramid_phases_ask_for_the_cell_s_configuration():
     """The two pyramid phases' argv through the CLI's own parser: the
     network and dataset of ``frcnn_r50_fpn_coco``, batch 8 in bf16 as the

@@ -261,3 +261,187 @@ def test_pyramid_step_names_its_scopes(monkeypatch):
         for k in kernels:
             assert re.search(pattern, f"%{k}.1 = bf16[8] custom-call(...), "
                              'custom_call_target="tpu_custom_call"')
+
+
+# ------------------------------------------------ pool_levels, sorted by level
+# A tiny pyramid (B, H, W, C) a level at strides 4..32 under a 96x128
+# image, and 21 rois an image whose sides put some on every level.
+_STRIDES = (4, 8, 16, 32)
+_POOL_SHAPES = ((2, 24, 32, 128), (2, 12, 16, 128), (2, 6, 8, 128),
+                (2, 3, 4, 128))
+_POOL_R = 21
+
+
+def _pool_inputs(seed=5):
+    rng = np.random.RandomState(seed)
+    pyramid = tuple(jnp.asarray(rng.randn(*s).astype(np.float32))
+                    for s in _POOL_SHAPES)
+    side = rng.choice([20.0, 60.0, 150.0, 300.0, 500.0, 1000.0],
+                      size=(2, _POOL_R))
+    x1 = rng.rand(2, _POOL_R) * 100
+    y1 = rng.rand(2, _POOL_R) * 70
+    rois = jnp.asarray(np.stack(
+        [x1, y1, x1 + side - 1, y1 + side * 0.8 - 1], -1).astype(np.float32))
+    cot = jnp.asarray(rng.randn(2, _POOL_R, 7, 7, 128).astype(np.float32))
+    assert set(np.asarray(program_fpn.roi_levels(rois)).ravel()) == {2, 3, 4, 5}
+    return pyramid, rois, cot
+
+
+def _pool_levels_as_pr28_left_it(pyramid, rois, pooled_size, strides,
+                                 sample_ratio):
+    """The pool before the sort: every level's call gets the rois in the
+    sampler's order, another level's as zero boxes, and no span."""
+    levels = program_fpn.roi_levels(rois)
+    pooled = None
+    for li, stride in enumerate(strides):
+        own = levels == li + 2
+        feats = program_fpn.extract_roi_features_batched(
+            pyramid[li], jnp.where(own[..., None], rois, 0.0), "roi_align",
+            pooled_size, 1.0 / stride, sample_ratio)
+        contrib = jnp.where(own[..., None, None, None], feats, 0.0)
+        pooled = contrib if pooled is None else pooled + contrib
+    return pooled
+
+
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
+    """A TPU's choice of kernels, interpreted on the CPU: P2 and P3 count
+    as over the VMEM budget (the streaming pair, in row blocks of 8 rows
+    and roi blocks of 8 rois), P4 and P5 take the resident pair.  → the
+    streaming calls' spans, as traced."""
+    from mx_rcnn_tpu.ops.pallas import roi_align as resident_mod
+    from mx_rcnn_tpu.ops.pallas import roi_align_stream as stream_mod
+    from mx_rcnn_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "use_pallas", lambda: True)
+    monkeypatch.setattr(resident_mod, "fits_vmem",
+                        lambda h, w, c, pooled, esize: h < 12)
+    monkeypatch.setattr(stream_mod, "_pick_hblk", lambda w, cblk, budget=0: 8)
+    monkeypatch.setattr(stream_mod, "_pick_rblk",
+                        lambda pooled, cblk, budget=0: 8)
+    resident, stream = resident_mod.roi_align_pallas, stream_mod.roi_align_stream
+    spans = []
+
+    def streamed(feat, rois, pooled, scale, ratio, span=None):
+        spans.append(span)
+        return stream(feat, rois, pooled, scale, ratio, True, span)
+
+    monkeypatch.setattr(stream_mod, "roi_align_stream", streamed)
+    monkeypatch.setattr(
+        resident_mod, "roi_align_pallas",
+        lambda feat, rois, pooled, scale, ratio, valid_hw=None: resident(
+            feat, rois, pooled, scale, ratio, True, valid_hw))
+    return spans
+
+
+def _pool_and_grad(pool, pyramid, rois, cot):
+    def loss(pyr):
+        out = pool(pyr, rois, (7, 7), _STRIDES, 2)
+        return (out * cot).sum(), out
+
+    (_, out), grads = jax.value_and_grad(loss, has_aux=True)(pyramid)
+    return np.asarray(out), [np.asarray(g) for g in grads]
+
+
+def _assert_same_pool(got, want):
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6, atol=1e-6)
+    for lv, (g, w) in enumerate(zip(got[1], want[1]), 2):
+        assert np.abs(w).max() > 0, lv          # every level pools something
+        np.testing.assert_allclose(
+            g, w, rtol=1e-5, atol=1e-5 * np.abs(w).max(), err_msg=f"P{lv}")
+
+
+def test_pool_levels_sorted_is_the_old_pool_on_the_gather_path():
+    """Same values in the sampler's order, same gradient of every map."""
+    pyramid, rois, cot = _pool_inputs()
+    _assert_same_pool(
+        _pool_and_grad(program_fpn.pool_levels, pyramid, rois, cot),
+        _pool_and_grad(_pool_levels_as_pr28_left_it, pyramid, rois, cot))
+
+
+def test_pool_levels_sorted_is_the_old_pool_with_the_kernels(
+        interpreted_kernels):
+    pyramid, rois, cot = _pool_inputs()
+    new = _pool_and_grad(program_fpn.pool_levels, pyramid, rois, cot)
+    # P2 and P3 streamed with the level's own span, forward (the backward
+    # reuses it); the spans tile each image's 21 rois in level order
+    spans = [np.asarray(s) for s in interpreted_kernels]
+    assert len(spans) == 2 and all(s.shape == (2, 2) for s in spans)
+    assert (spans[0][:, 0] == 0).all()
+    assert (spans[1][:, 0] == spans[0][:, 1]).all()
+    del interpreted_kernels[:]
+    old = _pool_and_grad(_pool_levels_as_pr28_left_it, pyramid, rois, cot)
+    assert interpreted_kernels == [None, None]
+    _assert_same_pool(new, old)
+
+
+def test_pool_levels_never_reads_a_dead_steps_rows(
+        interpreted_kernels, monkeypatch):
+    """On the chip a roi block outside the span is never written: its rows
+    hold what the buffer held.  NaN there, on every row outside the span,
+    reaches neither the pooled rois nor the maps' gradients."""
+    from mx_rcnn_tpu.ops.pallas import roi_align_stream as stream_mod
+
+    pyramid, rois, cot = _pool_inputs()
+    sound = _pool_and_grad(program_fpn.pool_levels, pyramid, rois, cot)
+    streamed = stream_mod.roi_align_stream
+    poisoned = []
+
+    def poison(feat, rois, pooled, scale, ratio, span=None):
+        out = streamed(feat, rois, pooled, scale, ratio, span)
+        r = jnp.arange(out.shape[1])[None]
+        own = (r >= span[:, :1]) & (r < span[:, :1] + span[:, 1:])
+        poisoned.append(int((~own).sum()))
+        return jnp.where(own[..., None, None, None], out, jnp.nan)
+
+    monkeypatch.setattr(stream_mod, "roi_align_stream", poison)
+    got = _pool_and_grad(program_fpn.pool_levels, pyramid, rois, cot)
+    assert len(poisoned) == 2 and min(poisoned) > 0
+    assert np.isfinite(got[0]).all()
+    np.testing.assert_array_equal(got[0], sound[0])
+    for g, w in zip(got[1], sound[1]):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_pool_levels_way_back_is_a_gather(monkeypatch):
+    """Lowered for the TPU (kernels as custom calls, nothing compiled):
+    the pool and its gradient hold no scatter — the sort's way back
+    transposes to a gather by the inverse order."""
+    monkeypatch.setenv("MX_RCNN_TPU_PALLAS", "1")
+    pyramid, rois, cot = _pool_inputs()
+
+    def pooled_and_grads(pyr, rois):
+        return jax.value_and_grad(lambda p: (program_fpn.pool_levels(
+            p, rois, (7, 7), _STRIDES, 2) * cot).sum())(pyr)
+
+    text = jax.jit(pooled_and_grads).trace(pyramid, rois).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 8
+    assert "gather" in text and "scatter" not in text
+
+
+@pytest.mark.parametrize("seed", [5, 6, 7])
+def test_live_step_counter_against_a_count_by_hand(interpreted_kernels, seed):
+    """``roi_steps_live_p<l>``: the (roi block, image) pairs that hold a
+    roi of the level once the image's rois are sorted by level, for the
+    levels that stream (P2, P3 here), against the same count in numpy; of
+    ``roi_steps_p<l>`` = 3 blocks of 8 x 2 images walked."""
+    pyramid, rois, _cot = _pool_inputs(seed)
+    got = {k: int(v) for k, v in program_fpn.roi_stream_steps(
+        pyramid, rois, (7, 7)).items()}
+    levels = np.asarray(program_fpn.roi_levels(rois))
+    want = {}
+    for lv in (2, 3):
+        live = 0
+        for row in levels:
+            at = np.nonzero(np.sort(row, kind="stable") == lv)[0]
+            live += len(set(at // 8))
+        want[f"roi_steps_live_p{lv}"] = live
+        want[f"roi_steps_p{lv}"] = 6
+    assert got == want
+    assert 0 < got["roi_steps_live_p2"] + got["roi_steps_live_p3"] < 12
+
+
+def test_live_step_counter_is_empty_where_nothing_streams():
+    pyramid, rois, _cot = _pool_inputs()
+    assert program_fpn.roi_stream_steps(pyramid, rois, (7, 7)) == {}

@@ -216,6 +216,138 @@ class TestStreamingRoiAlign:
         )
 
 
+# [start, count] of image 0 and image 1 over 21 rois in roi blocks of 8
+# (three blocks, the last one ragged: 5 rois and 3 fillers)
+_SPAN_CASES = {
+    "starts-inside-a-block": ((3, 9), (0, 21)),
+    "ends-on-a-block-edge": ((5, 11), (8, 8)),
+    "covers-nothing": ((10, 0), (0, 0)),
+    "covers-everything": ((0, 21), (0, 21)),
+    "starts-past-block-0": ((17, 4), (9, 3)),
+    "one-roi-and-an-empty-image": ((20, 1), (21, 0)),
+}
+
+
+def _own_mask(span, r):
+    idx = np.arange(r)[None]
+    span = np.asarray(span)
+    return (idx >= span[:, :1]) & (idx < span[:, :1] + span[:, 1:])
+
+
+class TestStreamingRoiAlignSpan:
+    """The streaming pair with a span (``models/fpn.py::pool_levels``
+    hands each level the rois sorted by level and the level's own
+    ``[start, start + count)``): the span's rois are pooled as without
+    it, nothing else is visited, and the map's gradient is that of the
+    span's rois alone.  Row blocks of 8 rows and roi blocks of 8 rois, so
+    every case crosses both kinds of block edge; 21 rois leave the last
+    roi block ragged."""
+
+    B, H, W, C, R = 2, 26, 20, 128, 21
+
+    @pytest.fixture
+    def rng(self):
+        return np.random.RandomState(11)
+
+    @pytest.fixture
+    def mod(self, monkeypatch):
+        from mx_rcnn_tpu.ops.pallas import roi_align_stream as mod
+
+        monkeypatch.setattr(mod, "_pick_hblk", lambda w, cblk, budget=0: 8)
+        monkeypatch.setattr(mod, "_pick_rblk", lambda pooled, cblk, budget=0: 8)
+        return mod
+
+    def _inputs(self, rng):
+        feat = rng.randn(self.B, self.H, self.W, self.C).astype(np.float32)
+        rois = np.stack([random_rois(rng, self.R, self.H * 4, self.W * 4)
+                         for _ in range(self.B)])
+        return jnp.asarray(feat), jnp.asarray(rois)
+
+    @pytest.mark.parametrize("case", list(_SPAN_CASES))
+    def test_fwd_of_the_own_rois_is_bitwise_the_call_without_a_span(
+            self, rng, mod, case):
+        feat, rois = self._inputs(rng)
+        span = jnp.asarray(_SPAN_CASES[case], jnp.int32)
+        full = mod.roi_align_stream(feat, rois, (7, 7), 0.25, 2, True)
+        got = mod.roi_align_stream(feat, rois, (7, 7), 0.25, 2, True, span)
+        assert got.shape == full.shape
+        own = _own_mask(span, self.R)
+        np.testing.assert_array_equal(
+            np.asarray(got)[own], np.asarray(full)[own])
+
+    @pytest.mark.parametrize("case", list(_SPAN_CASES))
+    def test_bwd_is_the_gather_gradient_of_the_own_rois_alone(
+            self, rng, mod, case):
+        """What the caller selects away carries no cotangent (and may hold
+        anything: the selection is a ``where``).  An image whose count is
+        0 gets a zero map back."""
+        feat, rois = self._inputs(rng)
+        span = jnp.asarray(_SPAN_CASES[case], jnp.int32)
+        own = jnp.asarray(_own_mask(span, self.R))[..., None, None, None]
+        cot = jnp.asarray(
+            rng.randn(self.B, self.R, 7, 7, self.C).astype(np.float32))
+
+        def loss(pool):
+            return lambda f: (jnp.where(own, pool(f), 0.0) * cot).sum()
+
+        ref = jax.grad(loss(lambda f: jax.vmap(
+            lambda f1, r1: roi_align(f1, r1, (7, 7), 0.25, 2))(f, rois)))(feat)
+        got = jax.grad(loss(lambda f: mod.roi_align_stream(
+            f, rois, (7, 7), 0.25, 2, True, span)))(feat)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(ref), rtol=1e-4, atol=1e-4)
+        for b, (_start, count) in enumerate(_SPAN_CASES[case]):
+            if count == 0:
+                assert not np.asarray(got[b]).any()
+
+    def test_bf16_with_a_span(self, rng, mod):
+        feat, rois = self._inputs(rng)
+        span = jnp.asarray(_SPAN_CASES["starts-inside-a-block"], jnp.int32)
+        full = mod.roi_align_stream(
+            feat.astype(jnp.bfloat16), rois, (7, 7), 0.25, 2, True)
+        got = mod.roi_align_stream(
+            feat.astype(jnp.bfloat16), rois, (7, 7), 0.25, 2, True, span)
+        assert got.dtype == jnp.bfloat16
+        own = _own_mask(span, self.R)
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32)[own], np.asarray(full, np.float32)[own])
+
+    @pytest.mark.parametrize("with_span", [False, True],
+                             ids=["every-roi", "span"])
+    def test_scalar_prefetch_operands(self, rng, mod, with_span):
+        """``span=None`` is the pair as it always lowered: one
+        scalar-prefetch operand (the rois), forward and backward.  The
+        span rides in as a second one only when the caller gives it."""
+        feat, rois = self._inputs(rng)
+        span = jnp.asarray(_SPAN_CASES["starts-inside-a-block"], jnp.int32)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda f, r, sp: mod.roi_align_stream(
+            f, r, (7, 7), 0.25, 2, True, sp if with_span else None,
+        ).sum()))(feat, rois, span)
+        calls = _pallas_calls(jaxpr.jaxpr)
+        assert [c.params["name"] for c in calls] == [
+            "pallas_roi_features_stream_fwd", "pallas_roi_features_stream_bwd"]
+        for call in calls:
+            mapping = call.params["grid_mapping"]
+            assert mapping.num_index_operands == (2 if with_span else 1)
+            assert len(call.invars) == mapping.num_index_operands + 1
+
+    @pytest.mark.parametrize("span,want", [
+        ((0, 40), [1, 0, 0, 0]), ((39, 2), [1, 1, 0, 0]),
+        ((50, 0), [0, 0, 0, 0]), ((100, 28), [0, 0, 1, 1]),
+        ((128, 0), [0, 0, 0, 0]), ((80, 1), [0, 0, 1, 0]),
+        ((0, 128), [1, 1, 1, 1]), ((40, 40), [0, 1, 0, 0]),
+    ], ids=lambda v: "-".join(map(str, v)))
+    def test_live_roi_blocks_by_hand(self, span, want):
+        """128 rois at 14x14 and 256 channels walk four roi blocks of 40
+        (the train cell's own numbers); which of them a span touches."""
+        from mx_rcnn_tpu.ops.pallas import roi_align_stream as real
+
+        assert real._pick_rblk((14, 14), 128) == 40
+        live = real.live_roi_blocks(
+            jnp.asarray([span], jnp.int32), 128, (14, 14), 256)
+        assert np.asarray(live).astype(int).tolist() == [want]
+
+
 # canvas of the valid_hw cases: 12 x 16 cells at stride 16 = a 192 x 256
 # bucket; (100, 150) is an image with 7 x 10 cells of content in it
 _H, _W, _C = 12, 16, 128
