@@ -7,7 +7,7 @@
 
 PY ?= python
 
-.PHONY: native test test-kernels test-fast lint check resilience bench bench-eval eval-bench serve serve-overlap serve-fault serve-mask streaming serve-scale serve-fleet swap rollout cascade slo poison pipeline elastic chaos integration-gate clean-native
+.PHONY: native test test-kernels test-fast lint check resilience chaos integration-gate clean-native
 
 # compile native/hostops.c + native/rlelib.c into ~/.cache/mx_rcnn_tpu
 native:
@@ -40,8 +40,8 @@ test-fast:
 	$(PY) -m pytest tests/ -m "not slow" -q
 
 # graftlint: project-native static analysis (ANALYSIS.md) — exits
-# nonzero on any unsuppressed finding, stale baseline entry, or
-# unparseable BENCH_*.json artifact.  Pure stdlib-ast: no jax import.
+# nonzero on any unsuppressed finding or stale baseline entry.  Pure
+# stdlib-ast: no jax import.
 lint:
 	$(PY) tools/lint.py
 
@@ -57,175 +57,14 @@ resilience:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_resilience.py \
 	      tests/test_preemption.py -q
 
-# flagship train throughput (real TPU); prints one JSON line
-bench:
-	$(PY) bench.py
-
-# inference throughput (host-bound on weak dev hosts; see the docstring)
-bench-eval:
-	$(PY) -m mx_rcnn_tpu.tools.bench_eval
-
-# eval host data plane bench (ISSUE 5): parallel assembly + prepared
-# cache + completion pool around a stub device at flagship image size;
-# serial vs overlapped img/s, stage counters, bitwise detection check;
-# emits JSON lines + the BENCH_eval_cpu.json artifact
-eval-bench:
-	JAX_PLATFORMS=cpu $(PY) bench.py --eval --out BENCH_eval_cpu.json
-
-# online serving load test (mixed-size synthetic traffic through the
-# dynamic batcher + shape-bucket ladder; SERVING.md); CPU-runnable.
-# Emits p50/p99, imgs/sec, occupancy, and the compile count proving
-# zero recompiles after warmup, as JSON lines + the artifact file
-serve:
-	JAX_PLATFORMS=cpu $(PY) bench.py --serve --out BENCH_serve_cpu.json
-
-# overlapped-serving bench (ISSUE 13): split dispatch/complete predict
-# path with a bounded per-replica in-flight window, measured against a
-# calibrated stub device stall (model FLOPs would hide the overlap on
-# CPU).  Emits depth=1 vs depth=2 throughput + speedup, stub-exact
-# device-busy fraction, byte-identity, and the depth=2 fault matrix
-# (zero lost, zero steady-state recompiles) as the artifact
-serve-overlap:
-	JAX_PLATFORMS=cpu $(PY) bench.py --serve_overlap \
-	      --out BENCH_serve_overlap_cpu.json
-
-# mask-family serving bench (ISSUE 14): device-side mask selection —
-# the jit gathers each survivor's S×S grid for its predicted class, so
-# the host fetches [max_det, S, S] instead of the raw (R, S, S, K)
-# stack.  Emits fetch bytes/batch raw vs device (the >=5x claim),
-# per-detection RLE byte-identity vs the host path across all buckets,
-# p50/p99 under mixed-size load, and the zero-steady-state-recompile
-# count, as JSON lines + the BENCH_serve_mask_cpu.json artifact
-serve-mask:
-	JAX_PLATFORMS=cpu $(PY) bench.py --serve_mask --serve_requests 24 \
-	      --serve_concurrency 6 --serve_max_batch 4 \
-	      --out BENCH_serve_mask_cpu.json
-
-# streaming-serve bench (ISSUE 20): device-side mask paste — survivors'
-# S×S grids resized/thresholded into their box footprints on the fixed
-# bucket canvas INSIDE the jit, so the host keeps only RLE.  Emits the
-# host-paste-ms/frame reduction at mask-flagship geometry (RLE
-# byte-identity vs the numpy fixed-point mirror), per-stream in-order
-# completion under the trip/stall chaos matrix with a mid-load hot-swap
-# (zero lost frames, bytes identical to the unfaulted run), the
-# zero-steady-state-recompile count, and the temporal-priming
-# recall/latency sweep, as the BENCH_streaming_cpu.json artifact
-streaming:
-	JAX_PLATFORMS=cpu $(PY) bench.py --streaming --serve_max_batch 4 \
-	      --out BENCH_streaming_cpu.json
-
-# tenant-fair front door bench (ISSUE 16): aggressor/victim isolation
-# with the aggressor blasting 4x its token-bucket rate (victim p99 must
-# hold within 10%), an autoscaler-initiated scale-down under live load
-# that loses zero requests and stays byte-identical to a fixed-size
-# control, diurnal + oscillating trace convergence through the flap
-# breaker, and zero steady-state recompiles at every pool size
-serve-scale:
-	JAX_PLATFORMS=cpu $(PY) bench.py --serve_scale \
-	      --out BENCH_serve_scale_cpu.json
-
-# multi-host fleet bench (ISSUE 19): a wire-protocol FleetGateway over
-# 1/2/4 backend engine PROCESSES (pipelined connection pools, host-
-# level health/hedging, requeue-never-drop) — N=1 gateway responses
-# byte-identical to the direct engine, near-linear aggregate imgs/s
-# scaling, and a SIGKILL chaos phase that loses zero requests with
-# surviving responses byte-identical to an unfaulted run; emits the
-# BENCH_serve_fleet_cpu.json artifact `make check` then guards
-serve-fleet:
-	JAX_PLATFORMS=cpu $(PY) bench.py --serve_fleet \
-	      --out BENCH_serve_fleet_cpu.json
-
-# fault-matrix serving bench (ISSUE 6): the same deterministic load
-# against a 3-replica health-gated pool under healthy / wedged-replica /
-# flapping-replica MX_RCNN_FAULTS scenarios; emits per-scenario p50/p99
-# + throughput, drain->rewarm->rejoin recovery time, shed/hedge/requeue
-# counts, and the zero-lost + byte-identical evidence, as JSON lines +
-# the BENCH_serve_fault_cpu.json artifact
-serve-fault:
-	JAX_PLATFORMS=cpu $(PY) bench.py --serve_fault --serve_requests 24 \
-	      --serve_concurrency 6 --serve_max_batch 2 \
-	      --out BENCH_serve_fault_cpu.json
-
-# model-lifecycle serving bench (ISSUE 7): live hot-swap under load on a
-# 2-replica pool (zero lost requests, byte-identical detections outside
-# the swap window, zero recompiles through the swap), the
-# verify/warm/canary fault-rollback matrix, and two model families
-# through one batcher with zero steady-state recompiles; emits JSON
-# lines + the BENCH_swap_cpu.json artifact
-swap:
-	JAX_PLATFORMS=cpu $(PY) bench.py --swap --serve_requests 24 \
-	      --serve_concurrency 6 --serve_max_batch 2 --serve_replicas 2 \
-	      --out BENCH_swap_cpu.json
-
-# progressive-rollout bench (ISSUE 17): traffic-split canary promote
-# under load (zero lost, byte-identical, zero recompiles), shadow-mode
-# divergence auto-rollback with the incumbent serving identical bytes
-# throughout, and the closed serve->distill->fine-tune->promote loop;
-# emits JSON lines + the BENCH_rollout_cpu.json artifact
-rollout:
-	JAX_PLATFORMS=cpu $(PY) bench.py --rollout --serve_requests 24 \
-	      --serve_concurrency 6 --serve_max_batch 2 \
-	      --out BENCH_rollout_cpu.json
-
-# compression ladder + confidence-gated cascade bench (ISSUE 18):
-# escalation-threshold sweep tracing cost-per-image vs matched
-# accuracy (cheap-first serving with flagship escalation on doubt),
-# 100%-escalation byte-identity control arm, per-rung parity matrix
-# ({box,mask} x {f32,bf16,int8} on real tiny models) and int8
-# compression stats; emits JSON lines + the BENCH_cascade_cpu.json
-# artifact, which `make check`'s lint artifact-parse pass then guards
-cascade:
-	JAX_PLATFORMS=cpu $(PY) bench.py --cascade --out BENCH_cascade_cpu.json
-
-# SLO-tier serving bench (ISSUE 11): sparse interactive probes against
-# a saturating bulk backlog, single-lane baseline vs two-lane scheduling
-# on ONE runner (so the compile cache spans both — the cross-lane
-# zero-recompile evidence); open-loop probes keep the offered
-# interactive rate identical across phases.  Emits per-lane p50/p99,
-# bulk-throughput retention, preemption counts, response-cache
-# byte-identity + hit rate, and the bf16 serve-graph parity report, as
-# JSON lines + the BENCH_serve_slo_cpu.json artifact
-slo:
-	JAX_PLATFORMS=cpu $(PY) bench.py --slo --out BENCH_serve_slo_cpu.json
-
-# query-of-death containment bench (ISSUE 12): ~5% deterministic poison
-# (per-size qod_image digests wired to poison_fail) inside healthy
-# traffic on a 2-replica pool with the quarantine table on; proves zero
-# healthy losses, healthy detections byte-identical to the unfaulted
-# run, every poison digest quarantined within <=K trips, and all
-# replicas HEALTHY at the end; emits JSON lines + the
-# BENCH_poison_cpu.json artifact
-poison:
-	JAX_PLATFORMS=cpu $(PY) bench.py --poison --serve_requests 48 \
-	      --serve_concurrency 6 --serve_max_batch 2 --serve_replicas 2 \
-	      --out BENCH_poison_cpu.json
-
-# device-resident step pipeline bench (ISSUE 4): feed occupancy, fetch
-# stalls, K=1 byte-identical check on the CPU smoke config; emits JSON
-# lines + the BENCH_pipeline.json artifact
-pipeline:
-	JAX_PLATFORMS=cpu $(PY) bench.py --pipeline --out BENCH_pipeline.json
-
-# elastic-training chaos matrix (ISSUE 9): 8 virtual CPU devices, four
-# deterministic device-fault scenarios (lose 1 of 8 mid-step, wedged
-# replica, lose-then-regrow at a checkpoint boundary, preemption during
-# the shrink's emergency save); proves zero lost steps beyond the
-# pipeline window, bitwise shrink-equivalence vs a fresh small-mesh run,
-# and records recovery seconds; emits JSON lines + the
-# BENCH_elastic_cpu.json artifact.  bench.py forces the 8-device CPU
-# platform itself (before jax init), so no env shim is needed here.
-elastic:
-	$(PY) bench.py --elastic --out BENCH_elastic_cpu.json
-
 # chaos gate (ISSUE 9 + 12): every deterministic fault-injection
 # surface in one target — the elastic loop's unit matrix plus the
 # preemption, resilience, and query-of-death quarantine suites, with
-# the lock-order checker armed — then the poison containment bench
+# the lock-order checker armed
 chaos:
 	JAX_PLATFORMS=cpu MX_RCNN_LOCK_CHECK=1 $(PY) -m pytest \
 	      tests/test_elastic.py tests/test_preemption.py \
 	      tests/test_resilience.py tests/test_quarantine.py -q
-	$(MAKE) poison
 
 # train→eval mAP gates on synthetic data, one per model family
 # (VERDICT r3 #7): C4 flagship shape, FPN, Mask (polygon gts + segm

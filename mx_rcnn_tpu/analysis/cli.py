@@ -1,8 +1,7 @@
 """graftlint CLI — ``python tools/lint.py`` / ``make lint``.
 
-Exit 0 only when the tree is clean: zero unsuppressed findings, zero
-stale baseline entries, and every committed ``BENCH_*.json`` artifact
-still parses (the artifact-schema piggyback guard).
+Exit 0 only when the tree is clean: zero unsuppressed findings and zero
+stale baseline entries.
 """
 
 from __future__ import annotations
@@ -11,615 +10,9 @@ import argparse
 import json
 import sys
 from pathlib import Path
-from typing import List, Optional, Sequence
+from typing import Optional, Sequence
 
 from mx_rcnn_tpu.analysis import engine as eng
-
-
-# per-artifact required report shape: {filename: (report_keys, scenario
-# names that must each carry the per-scenario keys)}.  Catches a bench
-# refactor silently committing an artifact that no longer proves what
-# the Makefile target's comment says it proves.
-_ELASTIC_SCENARIOS = (
-    "lose_1_of_8", "wedge", "lose_then_regrow", "preempt_during_shrink",
-)
-_ELASTIC_SCENARIO_KEYS = ("recovery_s", "zero_lost_steps", "bit_identical")
-
-
-def _check_elastic_schema(name: str, doc: dict) -> List[str]:
-    errors = []
-    report = doc.get("report") if isinstance(doc, dict) else None
-    if not isinstance(report, dict):
-        return [f"bench artifact {name}: missing report object"]
-    scenarios = report.get("scenarios")
-    if not isinstance(scenarios, dict):
-        return [f"bench artifact {name}: report.scenarios missing"]
-    for s in _ELASTIC_SCENARIOS:
-        if s not in scenarios:
-            errors.append(f"bench artifact {name}: scenario '{s}' missing")
-            continue
-        for k in _ELASTIC_SCENARIO_KEYS:
-            if k not in scenarios[s]:
-                errors.append(
-                    f"bench artifact {name}: scenario '{s}' missing '{k}'"
-                )
-    return errors
-
-
-# the SLO artifact must keep proving the two-lane claims: per-lane
-# latency phases, bulk-throughput retention, compile stability, and the
-# response-cache + bf16-parity evidence (ISSUE 11 acceptance shape)
-_SLO_REPORT_KEYS = ("baseline", "two_lane", "compile", "response_cache",
-                    "bf16")
-_SLO_PHASE_KEYS = ("interactive_ms", "bulk_imgs_per_sec", "lost_requests",
-                   "scheduler")
-_SLO_METRIC_PREFIXES = (
-    "serve_slo_interactive_p99_ms_baseline",
-    "serve_slo_interactive_p99_ms_two_lane",
-    "serve_slo_interactive_p99_speedup",
-    "serve_slo_bulk_retention",
-    "serve_slo_cache_hit_rate",
-    "serve_slo_steady_state_compile_misses",
-    "serve_slo_lost_requests",
-)
-
-
-def _check_slo_schema(name: str, doc: dict) -> List[str]:
-    errors = []
-    report = doc.get("report") if isinstance(doc, dict) else None
-    if not isinstance(report, dict):
-        return [f"bench artifact {name}: missing report object"]
-    for k in _SLO_REPORT_KEYS:
-        if k not in report:
-            errors.append(f"bench artifact {name}: report.{k} missing")
-    for phase in ("baseline", "two_lane"):
-        p = report.get(phase)
-        if not isinstance(p, dict):
-            continue
-        for k in _SLO_PHASE_KEYS:
-            if k not in p:
-                errors.append(
-                    f"bench artifact {name}: report.{phase}.{k} missing"
-                )
-    cache = report.get("response_cache")
-    if isinstance(cache, dict) and "byte_identical" not in cache:
-        errors.append(
-            f"bench artifact {name}: response_cache.byte_identical missing"
-        )
-    bf16 = report.get("bf16")
-    if isinstance(bf16, dict) and "parity" not in bf16:
-        errors.append(f"bench artifact {name}: bf16.parity missing")
-    metrics = {
-        r.get("metric", "")
-        for r in doc.get("records", [])
-        if isinstance(r, dict)
-    }
-    for prefix in _SLO_METRIC_PREFIXES:
-        if not any(m.startswith(prefix) for m in metrics):
-            errors.append(
-                f"bench artifact {name}: no record metric '{prefix}*'"
-            )
-    return errors
-
-
-# the poison artifact must keep proving the four ISSUE 12 containment
-# claims — a bench refactor that drops one (or lets it go false) is a
-# lint failure, not a quietly weaker artifact
-_POISON_CLAIMS = (
-    "zero_healthy_lost", "healthy_byte_identical",
-    "poison_quarantined_within_k", "all_replicas_healthy",
-)
-_POISON_METRIC_PREFIXES = (
-    "serve_poison_healthy_lost",
-    "serve_poison_healthy_byte_identical",
-    "serve_poison_quarantined_within_k",
-    "serve_poison_replicas_healthy",
-)
-
-
-def _check_poison_schema(name: str, doc: dict) -> List[str]:
-    errors = []
-    report = doc.get("report") if isinstance(doc, dict) else None
-    if not isinstance(report, dict):
-        return [f"bench artifact {name}: missing report object"]
-    claims = report.get("claims")
-    if not isinstance(claims, dict):
-        return [f"bench artifact {name}: report.claims missing"]
-    for c in _POISON_CLAIMS:
-        if c not in claims:
-            errors.append(f"bench artifact {name}: claim '{c}' missing")
-        elif claims[c] is not True:
-            errors.append(f"bench artifact {name}: claim '{c}' not true")
-    if not report.get("digests"):
-        errors.append(f"bench artifact {name}: report.digests empty — the "
-                      f"run drew no poison, so the claims are vacuous")
-    metrics = {
-        r.get("metric", "")
-        for r in doc.get("records", [])
-        if isinstance(r, dict)
-    }
-    for prefix in _POISON_METRIC_PREFIXES:
-        if not any(m.startswith(prefix) for m in metrics):
-            errors.append(
-                f"bench artifact {name}: no record metric '{prefix}*'"
-            )
-    return errors
-
-
-# the overlap artifact must keep proving the four ISSUE 13 acceptance
-# claims: the depth-2 speedup against the calibrated stub stall, the
-# byte-identity of detections across depths, and the fault-matrix
-# invariants (no request lost, no steady-state recompile) at depth=2
-_OVERLAP_CLAIMS = (
-    "speedup_ge_1_3", "byte_identical",
-    "zero_lost_under_faults", "zero_steady_state_recompiles",
-)
-_OVERLAP_METRIC_PREFIXES = (
-    "serve_overlap_speedup",
-    "serve_overlap_byte_identical",
-    "serve_overlap_fault_lost",
-    "serve_overlap_steady_state_compile_misses",
-)
-
-
-def _check_overlap_schema(name: str, doc: dict) -> List[str]:
-    errors = []
-    report = doc.get("report") if isinstance(doc, dict) else None
-    if not isinstance(report, dict):
-        return [f"bench artifact {name}: missing report object"]
-    claims = report.get("claims")
-    if not isinstance(claims, dict):
-        return [f"bench artifact {name}: report.claims missing"]
-    for c in _OVERLAP_CLAIMS:
-        if c not in claims:
-            errors.append(f"bench artifact {name}: claim '{c}' missing")
-        elif claims[c] is not True:
-            errors.append(f"bench artifact {name}: claim '{c}' not true")
-    for leg in ("depth1", "depth2"):
-        leg_doc = report.get(leg)
-        if not isinstance(leg_doc, dict) \
-                or "device_busy_fraction" not in leg_doc:
-            errors.append(
-                f"bench artifact {name}: report.{leg}.device_busy_fraction "
-                f"missing — the overlap claim has no utilization evidence"
-            )
-    metrics = {
-        r.get("metric", "")
-        for r in doc.get("records", [])
-        if isinstance(r, dict)
-    }
-    for prefix in _OVERLAP_METRIC_PREFIXES:
-        if not any(m.startswith(prefix) for m in metrics):
-            errors.append(
-                f"bench artifact {name}: no record metric '{prefix}*'"
-            )
-    return errors
-
-
-# mask-family serving bench (ISSUE 14): the device-side mask selection
-# artifact must carry the three closure claims — the >=5x fetch-byte
-# reduction, per-detection RLE byte-identity vs the host path, and zero
-# steady-state recompiles — plus the measured fetch-byte evidence the
-# reduction claim rests on.
-_MASK_CLAIMS = (
-    "fetch_reduction_ge_5x",
-    "rle_byte_identical",
-    "zero_steady_state_recompiles",
-)
-
-_MASK_METRIC_PREFIXES = (
-    "serve_mask_p50_ms",
-    "serve_mask_p99_ms",
-    "serve_mask_fetch_bytes_per_batch_raw",
-    "serve_mask_fetch_bytes_per_batch_device",
-    "serve_mask_fetch_reduction",
-    "serve_mask_rle_byte_identical",
-    "serve_mask_steady_state_compile_misses",
-)
-
-
-def _check_mask_schema(name: str, doc: dict) -> List[str]:
-    errors = []
-    report = doc.get("report") if isinstance(doc, dict) else None
-    if not isinstance(report, dict):
-        return [f"bench artifact {name}: missing report object"]
-    claims = report.get("claims")
-    if not isinstance(claims, dict):
-        return [f"bench artifact {name}: report.claims missing"]
-    for c in _MASK_CLAIMS:
-        if c not in claims:
-            errors.append(f"bench artifact {name}: claim '{c}' missing")
-        elif claims[c] is not True:
-            errors.append(f"bench artifact {name}: claim '{c}' not true")
-    fb = report.get("fetch_bytes")
-    if not isinstance(fb, dict) or not {
-        "raw_per_batch", "device_per_batch", "reduction"
-    } <= set(fb):
-        errors.append(
-            f"bench artifact {name}: report.fetch_bytes incomplete — the "
-            f"fetch-reduction claim has no measured byte evidence"
-        )
-    metrics = {
-        r.get("metric", "")
-        for r in doc.get("records", [])
-        if isinstance(r, dict)
-    }
-    for prefix in _MASK_METRIC_PREFIXES:
-        if not any(m.startswith(prefix) for m in metrics):
-            errors.append(
-                f"bench artifact {name}: no record metric '{prefix}*'"
-            )
-    return errors
-
-
-# tenant-fair front door bench (ISSUE 16): the scale artifact must
-# carry the four closure claims — victim p99 isolation under an
-# aggressor blast, the autoscaler-initiated zero-loss byte-identical
-# scale-down, bounded no-flap trace convergence with the breaker
-# engaging on the oscillating trace, and zero steady-state recompiles
-# at every pool size — plus the victim latency evidence the isolation
-# claim rests on.
-_SCALE_CLAIMS = (
-    "tenant_isolation",
-    "zero_loss_shrink",
-    "no_flap",
-    "zero_steady_state_recompiles",
-)
-
-_SCALE_METRIC_PREFIXES = (
-    "serve_scale_victim_solo_p99_ms",
-    "serve_scale_victim_contended_p99_ms",
-    "serve_scale_aggressor_shed",
-    "serve_scale_shrink_lost_requests",
-    "serve_scale_detections_match",
-    "serve_scale_shrink_recompiles",
-    "serve_scale_diurnal_events",
-    "serve_scale_oscillating_events",
-)
-
-
-def _check_scale_schema(name: str, doc: dict) -> List[str]:
-    errors = []
-    report = doc.get("report") if isinstance(doc, dict) else None
-    if not isinstance(report, dict):
-        return [f"bench artifact {name}: missing report object"]
-    claims = report.get("claims")
-    if not isinstance(claims, dict):
-        return [f"bench artifact {name}: report.claims missing"]
-    for c in _SCALE_CLAIMS:
-        if c not in claims:
-            errors.append(f"bench artifact {name}: claim '{c}' missing")
-        elif claims[c] is not True:
-            errors.append(f"bench artifact {name}: claim '{c}' not true")
-    victim = report.get("victim")
-    if not isinstance(victim, dict) or not {
-        "solo_p99_ms", "contended_p99_ms"
-    } <= set(victim):
-        errors.append(
-            f"bench artifact {name}: report.victim incomplete — the "
-            f"isolation claim has no latency evidence"
-        )
-    metrics = {
-        r.get("metric", "")
-        for r in doc.get("records", [])
-        if isinstance(r, dict)
-    }
-    for prefix in _SCALE_METRIC_PREFIXES:
-        if not any(m.startswith(prefix) for m in metrics):
-            errors.append(
-                f"bench artifact {name}: no record metric '{prefix}*'"
-            )
-    return errors
-
-
-# progressive rollout bench (ISSUE 17): the artifact must prove the
-# full closed loop — zero requests lost through split + promote, the
-# control arm byte-identical to a no-rollout run, the divergence-
-# injected candidate auto-rolled-back while the incumbent kept serving,
-# zero steady-state recompiles end to end, and the distilled candidate
-# promoted through the serve→train→serve loop — plus the shadow
-# divergence evidence the rollback claim rests on.
-_ROLLOUT_CLAIMS = (
-    "zero_lost_requests",
-    "control_arm_byte_identical",
-    "divergence_auto_rollback",
-    "zero_steady_state_recompiles",
-    "closed_loop_promoted",
-)
-
-_ROLLOUT_METRIC_PREFIXES = (
-    "rollout_split_served",
-    "rollout_shadow_compared",
-    "rollout_promote_lost_requests",
-    "rollout_rollback_incumbent_identical",
-    "rollout_steady_state_recompiles",
-    "rollout_distill_records",
-    "rollout_loop_promoted_version",
-)
-
-
-def _check_rollout_schema(name: str, doc: dict) -> List[str]:
-    errors = []
-    report = doc.get("report") if isinstance(doc, dict) else None
-    if not isinstance(report, dict):
-        return [f"bench artifact {name}: missing report object"]
-    claims = report.get("claims")
-    if not isinstance(claims, dict):
-        return [f"bench artifact {name}: report.claims missing"]
-    for c in _ROLLOUT_CLAIMS:
-        if c not in claims:
-            errors.append(f"bench artifact {name}: claim '{c}' missing")
-        elif claims[c] is not True:
-            errors.append(f"bench artifact {name}: claim '{c}' not true")
-    div = report.get("divergence")
-    if not isinstance(div, dict) or not {
-        "compared", "max_box_delta_px"
-    } <= set(div):
-        errors.append(
-            f"bench artifact {name}: report.divergence incomplete — the "
-            f"rollback claim has no shadow-comparison evidence"
-        )
-    metrics = {
-        r.get("metric", "")
-        for r in doc.get("records", [])
-        if isinstance(r, dict)
-    }
-    for prefix in _ROLLOUT_METRIC_PREFIXES:
-        if not any(m.startswith(prefix) for m in metrics):
-            errors.append(
-                f"bench artifact {name}: no record metric '{prefix}*'"
-            )
-    return errors
-
-
-_CASCADE_CLAIMS = (
-    "cost_reduction_ge_1p3x_at_matched_accuracy",
-    "full_escalation_byte_identical",
-    "zero_steady_state_recompiles",
-    "int8_parity_ok_box_and_mask",
-    "bf16_parity_ok_box_and_mask",
-)
-
-_CASCADE_METRIC_PREFIXES = (
-    "serve_cascade_cost_ms_per_image",
-    "serve_cascade_cost_reduction",
-    "serve_cascade_accuracy",
-    "serve_cascade_escalation_rate",
-    "serve_cascade_parity_rungs_ok",
-    "serve_cascade_int8_compression",
-    "serve_cascade_steady_state_compile_misses",
-)
-
-
-def _check_cascade_schema(name: str, doc: dict) -> List[str]:
-    errors = []
-    report = doc.get("report") if isinstance(doc, dict) else None
-    if not isinstance(report, dict):
-        return [f"bench artifact {name}: missing report object"]
-    claims = report.get("claims")
-    if not isinstance(claims, dict):
-        return [f"bench artifact {name}: report.claims missing"]
-    for c in _CASCADE_CLAIMS:
-        if c not in claims:
-            errors.append(f"bench artifact {name}: claim '{c}' missing")
-        elif claims[c] is not True:
-            errors.append(f"bench artifact {name}: claim '{c}' not true")
-    sweep = report.get("sweep")
-    if not isinstance(sweep, list) or len(sweep) < 2:
-        errors.append(
-            f"bench artifact {name}: report.sweep missing — the cost "
-            f"claim has no threshold-curve evidence"
-        )
-    matrix = report.get("parity_matrix")
-    if not isinstance(matrix, list) or {
-        (r.get("family"), r.get("precision"))
-        for r in matrix
-        if isinstance(r, dict)
-    } != {
-        (f, p)
-        for f in ("box", "mask")
-        for p in ("f32", "bf16", "int8")
-    }:
-        errors.append(
-            f"bench artifact {name}: report.parity_matrix must cover "
-            f"{{box,mask}} x {{f32,bf16,int8}}"
-        )
-    metrics = {
-        r.get("metric", "")
-        for r in doc.get("records", [])
-        if isinstance(r, dict)
-    }
-    for prefix in _CASCADE_METRIC_PREFIXES:
-        if not any(m.startswith(prefix) for m in metrics):
-            errors.append(
-                f"bench artifact {name}: no record metric '{prefix}*'"
-            )
-    return errors
-
-
-# multi-host fleet bench (ISSUE 19): the artifact must prove the
-# scale-out story end to end — N=1 gateway responses byte-identical to
-# the direct engine (the wire adds routing, never bytes), >=1.7x/>=3x
-# aggregate imgs/s at 2/4 backend processes, and the SIGKILL chaos
-# phase losing zero requests with surviving responses byte-identical
-# to an unfaulted run — plus the per-size scaling evidence and the
-# chaos accounting (lost/requeued) the claims rest on.
-_FLEET_CLAIMS = (
-    "n1_byte_identical",
-    "scaling_2x",
-    "scaling_4x",
-    "chaos_zero_lost",
-    "chaos_byte_identical",
-)
-
-_FLEET_METRIC_PREFIXES = (
-    "serve_fleet_imgs_per_sec",
-    "serve_fleet_speedup_2x",
-    "serve_fleet_speedup_4x",
-    "serve_fleet_n1_byte_identical",
-    "serve_fleet_chaos_lost",
-    "serve_fleet_chaos_requeued",
-    "serve_fleet_chaos_byte_identical",
-)
-
-
-def _check_fleet_schema(name: str, doc: dict) -> List[str]:
-    errors = []
-    report = doc.get("report") if isinstance(doc, dict) else None
-    if not isinstance(report, dict):
-        return [f"bench artifact {name}: missing report object"]
-    claims = report.get("claims")
-    if not isinstance(claims, dict):
-        return [f"bench artifact {name}: report.claims missing"]
-    for c in _FLEET_CLAIMS:
-        if c not in claims:
-            errors.append(f"bench artifact {name}: claim '{c}' missing")
-        elif claims[c] is not True:
-            errors.append(f"bench artifact {name}: claim '{c}' not true")
-    scaling = report.get("scaling")
-    if not isinstance(scaling, list) or not {
-        r.get("backends") for r in scaling if isinstance(r, dict)
-    } >= {1, 2, 4}:
-        errors.append(
-            f"bench artifact {name}: report.scaling must cover 1/2/4 "
-            f"backends — the speedup claims have no sweep evidence"
-        )
-    chaos = report.get("chaos")
-    if not isinstance(chaos, dict) or not {
-        "lost", "requeued", "byte_identical"
-    } <= set(chaos):
-        errors.append(
-            f"bench artifact {name}: report.chaos incomplete — the "
-            f"zero-loss claim has no kill-phase accounting"
-        )
-    metrics = {
-        r.get("metric", "")
-        for r in doc.get("records", [])
-        if isinstance(r, dict)
-    }
-    for prefix in _FLEET_METRIC_PREFIXES:
-        if not any(m.startswith(prefix) for m in metrics):
-            errors.append(
-                f"bench artifact {name}: no record metric '{prefix}*'"
-            )
-    return errors
-
-
-# streaming bench (ISSUE 20): the artifact must prove the streaming
-# closure — device-paste RLEs byte-identical to the host-paste path,
-# the >=5x host paste-ms/frame reduction at flagship geometry, zero
-# steady-state recompiles through warmup + hot-swap, per-stream
-# in-order completion with zero lost frames under the chaos matrix
-# with surviving bytes identical to the unfaulted run, and a monotone
-# priming recall/latency table — plus the paste-ms and ordering
-# evidence the claims rest on.
-_STREAMING_CLAIMS = (
-    "paste_rle_byte_identical",
-    "paste_reduction_ge_5x",
-    "zero_steady_state_recompiles",
-    "stream_in_order_under_chaos",
-    "chaos_bytes_identical",
-    "priming_monotone_tradeoff",
-)
-
-_STREAMING_METRIC_PREFIXES = (
-    "streaming_paste_host_ms_per_frame",
-    "streaming_paste_device_ms_per_frame",
-    "streaming_paste_reduction_x",
-    "streaming_paste_rle_byte_identical",
-    "streaming_steady_state_compile_misses",
-    "streaming_chaos_lost_frames",
-    "streaming_chaos_in_order",
-    "streaming_priming_recall_gain",
-)
-
-
-def _check_streaming_schema(name: str, doc: dict) -> List[str]:
-    errors = []
-    report = doc.get("report") if isinstance(doc, dict) else None
-    if not isinstance(report, dict):
-        return [f"bench artifact {name}: missing report object"]
-    claims = report.get("claims")
-    if not isinstance(claims, dict):
-        return [f"bench artifact {name}: report.claims missing"]
-    for c in _STREAMING_CLAIMS:
-        if c not in claims:
-            errors.append(f"bench artifact {name}: claim '{c}' missing")
-        elif claims[c] is not True:
-            errors.append(f"bench artifact {name}: claim '{c}' not true")
-    paste = report.get("paste")
-    if not isinstance(paste, dict) or not isinstance(
-        paste.get("stub"), dict
-    ) or not {
-        "host_paste_ms_per_frame", "device_paste_ms_per_frame",
-        "reduction_x",
-    } <= set(paste["stub"]):
-        errors.append(
-            f"bench artifact {name}: report.paste.stub incomplete — the "
-            f"paste-reduction claim has no measured ms evidence"
-        )
-    chaos = report.get("chaos")
-    if not isinstance(chaos, dict) or not all(
-        isinstance(s, dict) and {"in_order", "lost_frames"} <= set(s)
-        for s in chaos.values()
-    ) or len(chaos) < 2:
-        errors.append(
-            f"bench artifact {name}: report.chaos incomplete — the "
-            f"in-order claim has no per-scenario ordering evidence"
-        )
-    priming = report.get("priming")
-    if not isinstance(priming, dict) or not isinstance(
-        priming.get("table"), list
-    ) or len(priming["table"]) < 3:
-        errors.append(
-            f"bench artifact {name}: report.priming.table missing — the "
-            f"tradeoff claim has no sweep rows"
-        )
-    metrics = {
-        r.get("metric", "")
-        for r in doc.get("records", [])
-        if isinstance(r, dict)
-    }
-    for prefix in _STREAMING_METRIC_PREFIXES:
-        if not any(m.startswith(prefix) for m in metrics):
-            errors.append(
-                f"bench artifact {name}: no record metric '{prefix}*'"
-            )
-    return errors
-
-
-def check_bench_artifacts(root: Path) -> List[str]:
-    errors = []
-    for f in sorted(root.glob("BENCH_*.json")):
-        try:
-            doc = json.loads(f.read_text())
-        except (json.JSONDecodeError, OSError) as e:
-            errors.append(f"bench artifact {f.name}: unparseable ({e})")
-            continue
-        if not isinstance(doc, (dict, list)) or not doc:
-            errors.append(f"bench artifact {f.name}: empty or non-object")
-            continue
-        if f.name == "BENCH_elastic_cpu.json":
-            errors += _check_elastic_schema(f.name, doc)
-        if f.name == "BENCH_serve_slo_cpu.json":
-            errors += _check_slo_schema(f.name, doc)
-        if f.name == "BENCH_poison_cpu.json":
-            errors += _check_poison_schema(f.name, doc)
-        if f.name == "BENCH_serve_overlap_cpu.json":
-            errors += _check_overlap_schema(f.name, doc)
-        if f.name == "BENCH_serve_mask_cpu.json":
-            errors += _check_mask_schema(f.name, doc)
-        if f.name == "BENCH_serve_scale_cpu.json":
-            errors += _check_scale_schema(f.name, doc)
-        if f.name == "BENCH_rollout_cpu.json":
-            errors += _check_rollout_schema(f.name, doc)
-        if f.name == "BENCH_cascade_cpu.json":
-            errors += _check_cascade_schema(f.name, doc)
-        if f.name == "BENCH_serve_fleet_cpu.json":
-            errors += _check_fleet_schema(f.name, doc)
-        if f.name == "BENCH_streaming_cpu.json":
-            errors += _check_streaming_schema(f.name, doc)
-    return errors
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
@@ -633,10 +26,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         help="baseline suppressions (default: <root>/tools/lint_baseline.json)",
     )
     ap.add_argument("--format", choices=("text", "json"), default="text")
-    ap.add_argument(
-        "--no-bench-schema", action="store_true",
-        help="skip the BENCH_*.json parse guard",
-    )
     args = ap.parse_args(argv)
 
     root = args.root or Path(__file__).resolve().parents[2]
@@ -646,8 +35,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     )
 
     modules, errors = eng.load_modules(root)
-    if not args.no_bench_schema:
-        errors = list(errors) + check_bench_artifacts(root)
     report = eng.analyze(modules, eng.default_rules(), baseline, errors)
 
     if args.format == "json":

@@ -34,7 +34,7 @@ PRAGMA_RE = re.compile(
 )
 
 #: scan roots, relative to the repo root
-DEFAULT_TARGETS: Tuple[str, ...] = ("mx_rcnn_tpu", "bench.py")
+DEFAULT_TARGETS: Tuple[str, ...] = ("mx_rcnn_tpu",)
 EXCLUDE_PARTS = {"__pycache__"}
 
 

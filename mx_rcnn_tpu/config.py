@@ -161,9 +161,8 @@ class NetworkConfig:
     # OFF: the fold's fp-reassociation measurably rerouted random-init
     # training on the f32 integration gate (C4 gate 0.90@300 unfused vs
     # 0.43@500 folded, same seed) — a bad default for training fidelity.
-    # It is worth +2-3% on the bf16 flagship bench (where conv rounding
-    # dwarfs the fold delta), so bench.py's perf config enables it
-    # explicitly alongside bf16.
+    # No cell of the benchmark turns it on; the serve runner's bf16/int8
+    # rungs do (serve/runner.py), behind their parity gate.
     FOLD_BN: bool = False
 
 

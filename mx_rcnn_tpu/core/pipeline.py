@@ -1,9 +1,10 @@
 """Device-resident step pipeline: double-buffered host→device feed,
 K-late aux fetch, and a guarded loop that keeps training device-bound.
 
-ROOFLINE.md reconciles the flagship step to 103.9 ms device-busy plus
-**~5.4 ms/step of un-hidden host work** — aux fetch, loader hand-off,
-dispatch residue.  The reference paper hid that slice behind MXNet's
+A step that hands its losses to the host every step leaves un-hidden
+host work between steps — aux fetch, loader hand-off, dispatch residue
+(ROOFLINE.md, "The host gap of training"; what it costs on the chip is
+PERF.md §5).  The reference paper hid that slice behind MXNet's
 async dependency engine (``rcnn/core/loader.py``'s prefetching
 ``AnchorLoader`` + KVStore); our loader stopped at host-side numpy
 prefetch and every step blocked on a device→host ``aux`` fetch.  This
@@ -16,7 +17,7 @@ module closes the gap with three cooperating pieces:
   transfers are async, so the H2D copy itself overlaps device compute;
   the staged queue keeps the *dispatch* path free of host assembly too.
   Occupancy counters (staged hits, feed-starved gets) turn "is the feed
-  keeping up" into a measured number (``bench.py --pipeline``).
+  keeping up" into a counted number (``train_net(report=)["feed"]``).
 - :class:`AsyncAuxSink` — the non-blocking metrics half: train steps
   return ``aux`` as device arrays and the sink fetches them in one
   batched ``device_get`` per flush instead of one blocking fetch per
@@ -39,9 +40,8 @@ single chip → ``jax.device_put`` (optionally into the compiled step's
 input layouts, killing the input relayout copy), DP mesh →
 ``parallel/mesh.py :: shard_batch``, multi-host →
 ``parallel/distributed.py :: globalize_batch``.  ``core/fit.py``,
-``tools/train_end2end.py``, ``core/tester.py :: pipelined``,
-``tools/bench_eval.py`` and ``serve/runner.py`` all draw device-feed
-from here.
+``tools/train_end2end.py``, ``core/tester.py :: pipelined`` and
+``serve/runner.py`` all draw device-feed from here.
 """
 
 from __future__ import annotations
@@ -101,8 +101,9 @@ def input_layouts_for(jitted, args, argnum: int = 1):
     ``args`` may be real arrays or ``jax.ShapeDtypeStruct`` trees (no
     data needed — lowering is abstract).  Feeding ``device_put`` these
     formats makes the host→device transfer deliver device-native tiling
-    directly, so XLA stops inserting the input relayout copy that the
-    ROOFLINE layout-copy row charges ~1.1 ms/step to.  A failure to
+    directly, so XLA need not insert an input relayout copy (on this
+    round's chip the compiled formats ARE the default layouts, so it
+    changes nothing there: ROADMAP D3).  A failure to
     lower or compile raises: it would fail the real dispatch too.
     """
     in_args, _kwargs = jitted.lower(*args).compile().input_formats

@@ -103,37 +103,3 @@ def dequantize_tree(params: Any) -> Any:
 
     return jax.tree_util.tree_map(dq, params, is_leaf=is_quantized_leaf)
 
-
-def quantization_stats(params: Any, qtree: Any) -> Dict[str, Any]:
-    """Byte accounting + worst-case round-trip error of a quantized
-    tree vs its f32 source — the compression-ladder evidence the bench
-    records (int8 rung ≈ 4x smaller weights)."""
-    import jax
-
-    f32_bytes = sum(
-        int(np.asarray(leaf).nbytes)
-        for leaf in jax.tree_util.tree_leaves(params)
-    )
-    q_bytes = 0
-    max_rel_err = 0.0
-    quantized = 0
-    for leaf in jax.tree_util.tree_leaves(qtree, is_leaf=is_quantized_leaf):
-        if is_quantized_leaf(leaf):
-            quantized += 1
-            q_bytes += int(leaf["int8_q"].nbytes + leaf["int8_scale"].nbytes)
-            # per-leaf worst-case |dequant - orig| <= scale/2 by
-            # construction; report the bound relative to the leaf absmax
-            amax = float(np.max(leaf["int8_scale"]) * 127.0)
-            if amax > 0:
-                max_rel_err = max(
-                    max_rel_err, float(np.max(leaf["int8_scale"])) / 2.0 / amax
-                )
-        else:
-            q_bytes += int(np.asarray(leaf).nbytes)
-    return {
-        "f32_bytes": f32_bytes,
-        "int8_bytes": q_bytes,
-        "compression_x": round(f32_bytes / q_bytes, 3) if q_bytes else None,
-        "quantized_leaves": quantized,
-        "max_rel_round_err_bound": round(max_rel_err, 6),
-    }

@@ -109,8 +109,7 @@ class Predictor:
     def input_layouts(self, batch: Dict[str, np.ndarray]):
         """Compiled formats of the batch argument for this batch's
         shapes, usable as a ``jax.device_put`` target so the transfer
-        lands device-native and XLA inserts no input relayout copy
-        (ROOFLINE: ~1.1 ms/step on the flagship for the image tensor)."""
+        lands device-native and XLA inserts no input relayout copy."""
         from mx_rcnn_tpu.core.pipeline import input_layouts_for, shape_structs
 
         return input_layouts_for(
@@ -127,8 +126,8 @@ def pipelined(
     stats_out: Optional[Dict] = None,
     mode: str = "auto",
 ):
-    """Overlapped eval pipeline shared by pred_eval / generate_proposals
-    / bench_eval: keeps ``in_flight`` forwards in motion and yields
+    """Overlapped eval pipeline shared by pred_eval and
+    generate_proposals: keeps ``in_flight`` forwards in motion and yields
     ``(payload, batch, outputs)`` in input order.
 
     Two dispatch modes, selected by ``mode`` (``"auto"`` picks per

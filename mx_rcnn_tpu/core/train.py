@@ -131,7 +131,7 @@ def make_train_step(
     ``fold_step_rng=False`` keeps the sampling rng CONSTANT across steps
     (no fold_in of state.step): with per-image ``sample_seeds`` every
     image's roi/anchor subsample is then identical every step — the
-    zero-label-churn ablation mode (scripts/probe_mask_churn.py).
+    zero-label-churn ablation mode (tests/test_fpn.py).
 
     The returned step additionally accepts an optional ``lr_scale``
     keyword (default None = untouched): a scalar multiplied into the

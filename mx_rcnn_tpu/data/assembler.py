@@ -8,8 +8,8 @@ Reference anchor: the MXNet reference relied on the engine's async
 executor to hide ``rcnn/core/loader.py`` costs and ran the entire
 ``pred_eval`` postprocess serially on the driver thread.  Here both
 stages are explicit sized pools with the same counter discipline as
-``core/pipeline.py :: DeviceFeed``, so ``bench_eval`` reports where
-eval time goes instead of re-estimating it.
+``core/pipeline.py :: DeviceFeed``, so ``pred_eval(stats_out=)``
+reports where eval time goes instead of re-estimating it.
 
 Determinism is structural, not best-effort:
 
@@ -166,13 +166,12 @@ class _InlineResults:
 class AssemblyPool:
     """Sized worker pool for host batch assembly.
 
-    One instance fronts one stream (an eval sweep, a train epoch, a
-    bench run); the heavy shared state — the render LRU, the prepared
+    One instance fronts one stream (an eval sweep, a train epoch); the heavy shared state — the render LRU, the prepared
     canvas LRU, the loader fault budget — lives with its owners and is
     already locked, so N workers decode/resize/pad concurrently without
     coordination here.
 
-    Counters follow ``DeviceFeed.stats()``'s vocabulary so the bench
+    Counters follow ``DeviceFeed.stats()``'s vocabulary so a report
     can print both stages side by side: ``ready_hits`` — results that
     were already finished when the consumer asked (the pool ran ahead);
     ``starved`` / ``starved_after_first`` — gets that had to wait on a

@@ -46,7 +46,7 @@ class _RenderLRU:
     ``(uri, flipped, seed)``.
 
     Bounds render-cache memory (~7 MB/entry at flagship size, cap via
-    ``MX_RCNN_RENDER_CACHE``) while keeping the gate/bench sets — which
+    ``MX_RCNN_RENDER_CACHE``) while keeping the gate sets — which
     revisit the same few images every epoch/sweep — fully cached.  An
     LRU rather than the old first-come soft cap: that counter was
     unsynchronized across prefetch threads and never reclaimed, so a
@@ -100,21 +100,21 @@ _RENDER_CACHE = _RenderLRU(int(os.environ.get("MX_RCNN_RENDER_CACHE", "1024")))
 
 # Prepared-canvas LRU: the (padded image, im_info) PAIR after resize /
 # normalize-or-quantize / bucket-pad — the ~80 ms/img assembly tail the
-# render cache doesn't cover.  Eval sweeps and the bench revisit the
+# render cache doesn't cover.  Eval sweeps revisit the
 # same records every pass, so the second pass skips assembly entirely.
 # Keyed by record identity AND every input of the prep math (scales,
 # bucket, uint8 flag, normalization constants), so a hit is bit-identical
 # to recomputation by construction.  Default OFF (entries=0): a train
 # stream with flip augmentation rarely revisits a key before eviction,
 # and a flagship canvas is ~3 MB — opt in via MX_RCNN_PREPARED_CACHE or
-# :func:`set_prepared_cache` where revisits are the workload (bench,
-# repeated eval).
+# :func:`set_prepared_cache` where revisits are the workload (repeated
+# eval).
 _PREPARED_CACHE = _RenderLRU(int(os.environ.get("MX_RCNN_PREPARED_CACHE", "0")))
 
 
 def set_prepared_cache(max_entries: int) -> None:
-    """Resize (and clear) the prepared-canvas LRU at runtime — the
-    bench/tools hook; the env var covers child processes."""
+    """Resize (and clear) the prepared-canvas LRU at runtime; the env
+    var covers child processes."""
     _PREPARED_CACHE.clear()
     _PREPARED_CACHE.max_entries = max(0, int(max_entries))
 
@@ -389,7 +389,7 @@ class _AssembledStream:
     the loader's fault counters); worker exceptions — including
     :class:`LoaderFaultBudgetExceeded` — surface at their submission
     position, exactly where the serial loop would have raised.
-    ``stats()`` exposes the pool's occupancy counters for the bench.
+    ``stats()`` exposes the pool's occupancy counters.
     """
 
     def __init__(self, pool: AssemblyPool, results):

@@ -55,7 +55,7 @@ def paste_mask_canvas(
     quantized to 8 fractional bits, weights to 7) thresholded at logit
     0 (= probability 0.5).  Integer arithmetic is exact on every
     backend, so this function and the device canvas are bitwise equal
-    by construction — the streaming bench's RLE byte-identity bar.
+    by construction (tests/test_streaming.py::TestCanvasParity).
     """
     s = logits.shape[0]
     x1 = np.clip(np.float32(box[0]), 0.0, wc - 1.0)

@@ -611,7 +611,7 @@ class FPNFasterRCNN(nn.Module):
         # the top_k, quota FG_FRACTION·BATCH_ROIS) — so the branch runs
         # on just the first nfg roi slots.  EXACT: every fg roi lives in
         # that prefix; bg rows that pad it get zero loss weight either
-        # way.  At the bench config this is 4× less mask-branch work
+        # way.  At the published config this is 4× less mask-branch work
         # (second ROIAlign, 4conv+deconv head, target resampling: 128 →
         # 32 rois).
         nfg = min(

@@ -75,8 +75,8 @@ def make_test_postprocess(
     7 on the interpolation weights — int32 throughout, no overflow by
     construction), so the device canvas is bitwise identical to the
     numpy mirror ``eval/segm.py::paste_mask_canvas`` on every backend:
-    the streaming bench's RLE byte-identity bar is structural, not
-    float luck."""
+    the RLE byte-identity of tests/test_streaming.py::TestCanvasParity
+    is structural, not float luck."""
     te = cfg.TEST
     max_det = te.MAX_PER_IMAGE if te.MAX_PER_IMAGE > 0 \
         else (num_classes - 1) * max_out

@@ -231,7 +231,7 @@ class ElasticLoop:
     at W costs re-executing steps [W, S] on the survivor mesh — with the
     default ``aux_interval=1`` the anchor IS the poison step, so exactly
     one step replays.  The replay equals a fresh run started on the
-    small mesh from the emergency checkpoint (the chaos bench compares
+    small mesh from the emergency checkpoint (tests/test_elastic.py compares
     the two final states).
 
     Call :meth:`flush` then :meth:`checkpoint_boundary` wherever the

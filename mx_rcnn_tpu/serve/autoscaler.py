@@ -17,7 +17,8 @@ target size through the replica lifecycle that already exists:
   replica, which fails its queued and in-flight dispatches with
   ``ReplicaDrained`` — and the router's requeue-never-drop loop
   re-dispatches them on a sibling, so a scale-down under load loses
-  zero requests by construction (the bench proves it byte-for-byte).
+  zero requests by construction (tests/test_autoscale.py::
+  TestPoolElasticity asserts it byte-for-byte).
 
 Oscillation control is :class:`ScaleBreaker`, a wall-clock port of
 ``parallel/elastic.py``'s :class:`RegrowPolicy`: every scale event
@@ -131,7 +132,7 @@ class ScaleBreaker:
 class AutoScaler:
     """Background replica-count controller for a ReplicaPool.
 
-    ``signal_fn`` (injectable for tests/bench) returns the decision
+    ``signal_fn`` (injectable for tests) returns the decision
     inputs: ``{"queue_depth": int, "healthy": int, "p99_ms": float|None}``.
     The default reads the engine's batcher and the pool's routable set —
     both O(replicas) counter reads, no heavy snapshots on the tick path.
@@ -234,7 +235,7 @@ class AutoScaler:
             self.tick()
 
     def tick(self, now: Optional[float] = None) -> Optional[str]:
-        """One decision cycle (public so tests and the bench can drive
+        """One decision cycle (public so tests can drive
         the controller synchronously with an injected clock).  Returns
         the action taken ("up"/"down") or None."""
         now = time.monotonic() if now is None else now

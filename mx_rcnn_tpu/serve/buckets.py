@@ -19,8 +19,7 @@ compile a fresh graph mid-traffic.
 tracks distinct jit input signatures seen by the runner.  Because the
 runner's jitted callable and params are fixed for its lifetime, a new
 signature is exactly a new XLA compile, so ``misses`` after warmup must
-stay 0 (asserted by tests/test_serve_runner.py and reported by
-``bench.py --serve``).
+stay 0 (asserted by tests/test_serve_runner.py).
 """
 
 from __future__ import annotations

@@ -1,9 +1,9 @@
 """Multi-host serving fleet: a wire-protocol gateway over N engine
 processes (ISSUE 19).
 
-The single-host serve path is device-bound (``BENCH_serve_overlap``:
-device-busy 0.97 at depth 2), so the remaining throughput headroom is
-ABOVE the host: run N complete engines — each its own process with its
+Once a single host's serve path is device-bound (the benchmark's cell
+``c4_serve_closed32`` is: PERF.md §5), the remaining throughput headroom
+is ABOVE the host: run N complete engines — each its own process with its
 own device, batcher, and :class:`~mx_rcnn_tpu.serve.frontend.Frontend`
 — and fan live traffic over them through one :class:`FleetGateway`.
 The ISSUE 16 length-prefixed wire protocol is the seam: the gateway is
@@ -29,8 +29,8 @@ Three layers, mirroring the replica pool one level up:
   bucket- and cache-affinity survive the hop; slow hosts hedge on a
   deadline-derived clock (``ReplicaPool._hedge_s`` one level up); a
   dead backend's in-flight requests REQUEUE to survivors
-  (requeue-never-drop: a SIGKILL'd process loses zero requests, proven
-  by ``bench.py --serve_fleet``'s chaos phase).  Wire error codes are
+  (requeue-never-drop: a SIGKILL'd process loses zero requests,
+  asserted by ``tests/test_fleet.py::TestChaosProcessKill``).  Wire error codes are
   rebuilt into the SAME typed exceptions the engine raises in-process
   (``UnknownTenant``, ``TenantOverBudget``, ``PoisonRequest``, …), so
   the taxonomy propagates verbatim through the gateway.
@@ -52,8 +52,8 @@ Lock order (one-way, leaf-ward): gateway → link → conn.  Cross-layer
 upcalls (reader → link → gateway) always run with NO lock held.
 
 ``python -m mx_rcnn_tpu.serve.fleet --port 0 --service_ms 25`` runs a
-stub backend process (digest runner with a calibrated device stall —
-the ``_OverlapStubRunner`` idiom) used by the bench and chaos tests;
+stub backend process (digest runner that sleeps ``service_ms`` a
+batch: a test fake, not a device model) used by the chaos tests;
 ``tools/serve.py --fleet N`` spawns real-model backends the same way.
 """
 
@@ -931,12 +931,12 @@ class FleetGateway:
 # ----------------------------------------------------- backend process
 
 class _FleetStubRunner:
-    """Digest runner with a CALIBRATED device stall (the bench's
-    ``_OverlapStubRunner`` idiom): ``run`` sleeps ``service_ms`` per
-    batch — one modeled device, serial per process — and returns a
-    pure-function-of-pixels digest, so gateway scaling is measured
-    against the serve path rather than CPU model FLOPs and every
-    byte-identity comparison is exact (float64 survives JSON)."""
+    """Digest runner, the fake that ``tests/test_fleet.py`` puts behind
+    a backend process: ``run`` sleeps ``service_ms`` per batch, serial
+    per process, and returns a pure-function-of-pixels digest, so every
+    byte-identity comparison through the gateway is exact (float64
+    survives JSON).  The sleep keeps requests in flight long enough to
+    kill a backend under them; it models no device."""
 
     LADDER = ((32, 32), (48, 64))
 
@@ -1124,7 +1124,7 @@ def spawn_stub_backends(n: int, service_ms: float = 25.0,
                         startup_timeout: float = 120.0
                         ) -> List[BackendProc]:
     """N stub backend processes (``python -m mx_rcnn_tpu.serve.fleet``)
-    — the bench/chaos harness."""
+    — the chaos tests' harness."""
     # -c (not -m): serve/__init__ imports this module, so runpy's -m
     # would execute it twice and warn about the sys.modules shadow
     argv = [

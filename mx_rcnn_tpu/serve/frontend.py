@@ -687,7 +687,7 @@ class Frontend:
 
 
 class FrontendClient:
-    """Minimal blocking client for tests/bench: one socket, one request
+    """Minimal blocking client for tests: one socket, one request
     at a time.  ``request`` returns the parsed response dict;
     ``send_raw`` ships arbitrary bytes (the malformed-frame matrix)."""
 

@@ -45,15 +45,15 @@ def synthetic_image(index: int, h: int, w: int, seed: int = 0) -> np.ndarray:
 
 #: poison_mix flavors (ISSUE 12).  The malformed three must be rejected
 #: at the admission gate; "qod" is a WELL-FORMED query of death — valid
-#: pixels whose digest the bench wires to a ``poison_*`` fault injector.
+#: pixels whose digest a test wires to a ``poison_*`` fault injector.
 POISON_FLAVORS = ("qod", "nan", "empty", "objdtype")
 
 
 def qod_image(h: int, w: int, seed: int = 0) -> np.ndarray:
     """The deterministic query-of-death image for size ``(h, w)``.
     Depends on (h, w, seed) only — NOT the request index — so every qod
-    request of one size shares a single digest, which is what lets the
-    bench compute ``request_digest(qod_image(...))`` up front and key
+    request of one size shares a single digest, which is what lets a
+    test compute ``request_digest(qod_image(...))`` up front and key
     its fault spec on it."""
     rng = np.random.RandomState((seed * 7_777_777 + h * 10_007 + w)
                                 % (2**31 - 1))
@@ -195,7 +195,7 @@ def run_load(
     ``backoff_give_up`` (optional) bounds QueueFull/over-budget retries
     per request: after that many rejections the request resolves as its
     last rejection kind instead of retrying forever — shed traffic must
-    be COUNTABLE for the fairness bench, not retried into admission.
+    be COUNTABLE, not retried into admission.
 
     ``collect=True`` additionally stores each request's resolution under
     ``report["_results"]`` — ``{index: ("ok", detections) | (kind, repr)}``
@@ -203,7 +203,7 @@ def run_load(
     an unfaulted one (pop the key before JSON-dumping the report), plus
     per-request submit/done monotonic timestamps under
     ``report["_times"]`` — ``{index: (t_submit, t_done)}`` — which is how
-    the swap bench classifies requests as entirely-before / entirely-
+    a swap test classifies requests as entirely-before / entirely-
     after / straddling a live swap window.  Because traffic is derived
     from ``seed + index`` alone, equal indices mean equal input images
     across runs."""

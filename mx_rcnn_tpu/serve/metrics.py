@@ -160,9 +160,7 @@ class OverlapStats:
     * ``paste_ms`` / ``paste_bytes`` (+ ``_by_model``) (ISSUE 20) —
       host wall spent in the mask paste+RLE stage and the mask payload
       it consumed (device canvas bytes vs host S×S grid bytes).  These
-      are first-class pool-merged counters alongside ``fetch_bytes``:
-      the measured evidence behind the streaming bench's device-paste
-      host-cost reduction.
+      are first-class pool-merged counters alongside ``fetch_bytes``.
 
     All methods are O(1) and lock-protected; ``note_depth`` is called at
     every window size change, ``note_fetch`` once per ``complete()``,
@@ -416,7 +414,7 @@ class ServeMetrics:
                       ok: bool = True, expired: bool = False,
                       shed: bool = False, rejected: bool = False) -> None:
         """Per-tenant counters + latency histograms — same partition
-        shape as :meth:`record_lane` so the fairness bench can hold one
+        shape as :meth:`record_lane` so a fairness test can hold one
         tenant's p99 against another's shed count.  No-op for untagged
         requests (``tenant=None``): the single-tenant deployment pays
         and reports nothing extra."""

@@ -85,7 +85,7 @@ class PoisonBatch(RuntimeError):
 def request_digest(im: Any) -> str:
     """Stable identity of a raw input image: blake2b over shape, dtype
     and bytes (same construction as ``ResponseCache.digest``).  Computed
-    on the *raw* submitted array so external tooling (bench, fault
+    on the *raw* submitted array so external tooling (tests, fault
     specs) can reproduce it without a runner."""
     arr = np.ascontiguousarray(im)
     h = hashlib.blake2b(digest_size=16)

@@ -39,7 +39,7 @@ The closed loop rides on top: ``tools/distill.py`` harvests served
 detections into ``data/synthetic.py``-schema records, fine-tunes with
 the existing trainer, and submits the resulting checkpoint right back
 through :meth:`RolloutController.start` — serve → collect → train →
-verify → promote, end-to-end (``bench.py --rollout``).
+verify → promote, end-to-end.
 
 Locking: ``RolloutController._lock`` guards only the split/shadow
 tables and counters — never device work, never a registry call (R4

@@ -366,7 +366,7 @@ class _ModelSlot:
 
 
 class ServeRunner:
-    """Device-facing predict path shared by the engine, bench, and tests.
+    """Device-facing predict path shared by the engine and tests.
 
     Since ISSUE 7 the runner holds NO params of its own: every model's
     params are a versioned resource in a
@@ -476,7 +476,6 @@ class ServeRunner:
         # split-path counters (ISSUE 13 overlap accounting; cumulative,
         # read unlocked by snapshots like the staging counters above)
         self.split_dispatches = 0
-        self.split_completes = 0
         self.fetch_stall_s = 0.0  # wall time blocked in complete()'s fetch
         # fetch-byte accounting (ISSUE 14): every complete() sums the
         # nbytes of the host-copied output tree — the measured evidence
@@ -487,20 +486,17 @@ class ServeRunner:
         self.fetch_bytes_by_model: Dict[str, int] = {}
         self.last_fetch_bytes = 0
         # per-request cost accounting (ISSUE 18): dispatch→complete wall
-        # per batch, attributed to the serving model — the counter the
-        # cascade's cost-per-image claim is backed by.  On real
-        # accelerators this is device compute + fetch; bench stub
-        # runners book their calibrated device model here instead.
-        self.device_ms_total = 0.0
+        # per batch (device compute + fetch), attributed to the serving
+        # model.  last_device_ms is read by Replica._finish like
+        # last_fetch_bytes.
         self.device_ms_by_model: Dict[str, float] = {}
         self.last_device_ms = 0.0
         # mask canvas paste (ISSUE 20): None defers to each model cfg's
         # TEST.MASK_CANVAS; True/False overrides for every mask family
         self._mask_canvas = mask_canvas
         # paste accounting (ISSUE 20): host wall ms and mask payload
-        # bytes consumed by the paste+RLE stage (mask_rles_for) — the
-        # streaming bench's host-paste-reduction evidence, per model and
-        # in total.  ``overlap`` is the owning Replica's OverlapStats
+        # bytes consumed by the paste+RLE stage (mask_rles_for), per
+        # model and in total.  ``overlap`` is the owning Replica's OverlapStats
         # hook (set by Replica.__init__/_recover) so the same numbers
         # pool-merge through the router snapshot alongside fetch_bytes.
         self.pastes = 0
@@ -788,7 +784,6 @@ class ServeRunner:
         with tracing.span(tracing.SERVE_FETCH, batch=tracing.current_batch()):
             out = host_copy(handle.outputs)
         self.fetch_stall_s += time.monotonic() - t0
-        self.split_completes += 1
         nbytes = sum(
             int(getattr(leaf, "nbytes", 0))
             for leaf in jax.tree_util.tree_leaves(out)
@@ -799,10 +794,9 @@ class ServeRunner:
             self.fetch_bytes_by_model.get(handle.model, 0) + nbytes
         )
         # cost accounting: dispatch→complete wall, attributed to the
-        # serving model (cascade cost-per-image evidence, ISSUE 18)
+        # serving model
         dt_ms = (time.monotonic() - handle.dispatch_t) * 1000.0
         self.last_device_ms = dt_ms
-        self.device_ms_total += dt_ms
         self.device_ms_by_model[handle.model] = (
             self.device_ms_by_model.get(handle.model, 0.0) + dt_ms
         )
@@ -919,7 +913,7 @@ class ServeRunner:
         OFF the serving path — it is deliberately not recorded in the
         compile cache, whose signatures account the programs that serve
         traffic.  The report lands in ``self.parity["model:precision"]``
-        and engine/bench snapshots."""
+        and engine snapshots."""
         mid = self.default_model if model is None else model
         slot = self._slot(mid)
         if slot.precision not in ("bf16", "int8"):
@@ -1153,9 +1147,7 @@ class ServeRunner:
         Accounts ``paste_ms`` (host wall in the paste+RLE stage) and
         ``paste_bytes`` (mask payload consumed: canvas bytes vs grid
         bytes) per model, and mirrors both into the owning replica's
-        :class:`~mx_rcnn_tpu.serve.metrics.OverlapStats` when attached
-        — the pool-merged evidence behind the streaming bench's
-        host-paste-reduction claim."""
+        :class:`~mx_rcnn_tpu.serve.metrics.OverlapStats` when attached."""
         from mx_rcnn_tpu.eval.segm import canvas_rles
         from mx_rcnn_tpu.native import rle as rle_mod
 

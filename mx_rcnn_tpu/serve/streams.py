@@ -34,10 +34,8 @@ run — in order — while the others just deposit and leave.
 Temporal proposal priming (train-free): frame N−1's detections are
 likely frame N's objects moved a little, so seeding frame N's proposal
 pool with the previous detections buys recall at small budgets without
-touching any weights.  :func:`prime_proposals` implements the merge;
-the streaming bench sweeps the primed budget against
-``eval/recall.py::proposal_recall`` for the recall/latency tradeoff
-table.
+touching any weights.  :func:`prime_proposals` implements the merge.
+No caller outside its tests yet, and no measured recall (ROADMAP D8).
 """
 
 from __future__ import annotations
@@ -272,9 +270,7 @@ def prime_proposals(
     (at ``prime_score``, above any RPN score — a tracked object is
     stronger evidence than one frame's objectness), then the top RPN
     proposals filling the remainder.  Train-free: nothing about the
-    model changes, only which boxes the second stage gets to look at —
-    a pure recall/latency tradeoff swept by the streaming bench via
-    ``eval/recall.py::proposal_recall``.
+    model changes, only which boxes the second stage gets to look at.
     """
     budget = int(budget)
     props = np.asarray(proposals, np.float32).reshape(-1, 5)

@@ -164,8 +164,8 @@ class TenantTable:
               now: Optional[float] = None) -> None:
         """Admission gate: unknown tenant (strict) raises
         :class:`UnknownTenant`; an empty token bucket raises
-        :class:`TenantOverBudget`.  ``now`` is injectable so tests and
-        the bench can drive the bucket clock deterministically."""
+        :class:`TenantOverBudget`.  ``now`` is injectable so tests
+        can drive the bucket clock deterministically."""
         if tenant is None:
             return
         t = time.monotonic() if now is None else now

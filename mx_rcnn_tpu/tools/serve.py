@@ -20,9 +20,7 @@ Examples:
       --model tenant=vgg:random:1 --swap tenant=ckpts/epoch_0002
 
   # mask family as a tenant: device postprocess ships selected
-  # ``det_masks`` grids, not the raw (R, S, S, K) stack (ISSUE 14);
-  # ``make serve-mask`` runs this shape through bench.py with the
-  # fetch-byte counters on
+  # ``det_masks`` grids, not the raw (R, S, S, K) stack (ISSUE 14)
   python -m mx_rcnn_tpu.tools.serve --small \
       --model masks=mask_resnet_fpn:random:1
 

@@ -27,8 +27,8 @@ def compile_cache_dir() -> str:
     cache key (``--xla_force_host_platform_device_count`` is excluded
     from it): an executable the test env compiled under 8 virtual CPU
     devices, replayed in a 1-device tool process, is not even run-to-run
-    deterministic (measured: it flips ``bench.py --pipeline``'s K=1
-    bitwise check on identical inputs).  Derived from the environment
+    deterministic (seen once: it flipped a K=1 bitwise check of the step
+    pipeline on identical inputs).  Derived from the environment
     string alone — no backend is initialised to answer.
     """
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")

@@ -6,7 +6,6 @@ hard error, and the BENCH artifact parse guard.
 Everything here is stdlib + numpy speed — no jax execution, so the
 whole file runs in well under a second of tier-1 budget."""
 
-import json
 import threading
 import time
 from pathlib import Path
@@ -15,7 +14,6 @@ import pytest
 
 from mx_rcnn_tpu.analysis import engine as eng
 from mx_rcnn_tpu.analysis import lockcheck
-from mx_rcnn_tpu.analysis.cli import check_bench_artifacts
 from mx_rcnn_tpu.analysis.rules_faults import FaultCoverage
 from mx_rcnn_tpu.analysis.rules_futures import ExactlyOnce
 from mx_rcnn_tpu.analysis.rules_hostcopy import HostCopyEscape, UseAfterDonate
@@ -1110,169 +1108,6 @@ def test_valid_fault_specs_still_parse(monkeypatch):
     faults.reset()
 
 
-# ------------------------------------------------- bench artifacts
-
-
-def test_bench_artifacts_parse():
-    assert check_bench_artifacts(REPO) == []
-    found = sorted(p.name for p in REPO.glob("BENCH_*.json"))
-    assert found, "committed BENCH_*.json artifacts should exist"
-    for p in REPO.glob("BENCH_*.json"):
-        doc = json.loads(p.read_text())
-        assert isinstance(doc, (dict, list)) and doc
-
-
-def test_elastic_artifact_schema_guard(tmp_path):
-    """BENCH_elastic_cpu.json must carry all four chaos scenarios, each
-    with the zero-lost / bit-identical / recovery fields — a bench
-    refactor dropping one is a lint failure, not a silent hole."""
-    good = {
-        "records": [],
-        "report": {
-            "scenarios": {
-                name: {
-                    "recovery_s": 0.1,
-                    "zero_lost_steps": True,
-                    "bit_identical": True,
-                }
-                for name in (
-                    "lose_1_of_8", "wedge", "lose_then_regrow",
-                    "preempt_during_shrink",
-                )
-            }
-        },
-    }
-    art = tmp_path / "BENCH_elastic_cpu.json"
-    art.write_text(json.dumps(good))
-    assert check_bench_artifacts(tmp_path) == []
-
-    del good["report"]["scenarios"]["wedge"]
-    good["report"]["scenarios"]["lose_1_of_8"].pop("bit_identical")
-    art.write_text(json.dumps(good))
-    errs = " | ".join(check_bench_artifacts(tmp_path))
-    assert "scenario 'wedge' missing" in errs
-    assert "'lose_1_of_8' missing 'bit_identical'" in errs
-
-
-def test_poison_artifact_schema_guard(tmp_path):
-    """BENCH_poison_cpu.json must carry the four ISSUE 12 containment
-    claims — all true — plus a non-empty poison digest list and the
-    per-claim metric records."""
-    claims = {
-        "zero_healthy_lost": True,
-        "healthy_byte_identical": True,
-        "poison_quarantined_within_k": True,
-        "all_replicas_healthy": True,
-    }
-    good = {
-        "records": [
-            {"metric": f"serve_poison_{m}_r50", "value": 1}
-            for m in ("healthy_lost", "healthy_byte_identical",
-                      "quarantined_within_k", "replicas_healthy")
-        ],
-        "report": {"claims": dict(claims), "digests": ["abc123"]},
-    }
-    art = tmp_path / "BENCH_poison_cpu.json"
-    art.write_text(json.dumps(good))
-    assert check_bench_artifacts(tmp_path) == []
-
-    good["report"]["claims"]["healthy_byte_identical"] = False
-    del good["report"]["claims"]["all_replicas_healthy"]
-    good["report"]["digests"] = []
-    good["records"] = good["records"][1:]
-    art.write_text(json.dumps(good))
-    errs = " | ".join(check_bench_artifacts(tmp_path))
-    assert "'healthy_byte_identical' not true" in errs
-    assert "'all_replicas_healthy' missing" in errs
-    assert "digests empty" in errs
-    assert "no record metric 'serve_poison_healthy_lost*'" in errs
-
-
-def test_overlap_artifact_schema_guard(tmp_path):
-    """BENCH_serve_overlap_cpu.json must carry the four ISSUE 13
-    acceptance claims — all true — plus per-depth device-busy fractions
-    and the speedup/identity/fault metric records."""
-    claims = {
-        "speedup_ge_1_3": True,
-        "byte_identical": True,
-        "zero_lost_under_faults": True,
-        "zero_steady_state_recompiles": True,
-    }
-    good = {
-        "records": [
-            {"metric": m, "value": 1}
-            for m in ("serve_overlap_speedup",
-                      "serve_overlap_byte_identical",
-                      "serve_overlap_fault_lost",
-                      "serve_overlap_steady_state_compile_misses")
-        ],
-        "report": {
-            "claims": dict(claims),
-            "depth1": {"device_busy_fraction": 0.6},
-            "depth2": {"device_busy_fraction": 0.95},
-        },
-    }
-    art = tmp_path / "BENCH_serve_overlap_cpu.json"
-    art.write_text(json.dumps(good))
-    assert check_bench_artifacts(tmp_path) == []
-
-    good["report"]["claims"]["speedup_ge_1_3"] = False
-    del good["report"]["claims"]["byte_identical"]
-    del good["report"]["depth2"]["device_busy_fraction"]
-    good["records"] = good["records"][1:]
-    art.write_text(json.dumps(good))
-    errs = " | ".join(check_bench_artifacts(tmp_path))
-    assert "'speedup_ge_1_3' not true" in errs
-    assert "'byte_identical' missing" in errs
-    assert "depth2.device_busy_fraction missing" in errs
-    assert "no record metric 'serve_overlap_speedup*'" in errs
-
-
-def test_mask_artifact_schema_guard(tmp_path):
-    """BENCH_serve_mask_cpu.json must carry the three ISSUE 14 closure
-    claims — all true — plus the measured fetch-byte evidence and the
-    serve_mask metric records."""
-    claims = {
-        "fetch_reduction_ge_5x": True,
-        "rle_byte_identical": True,
-        "zero_steady_state_recompiles": True,
-    }
-    good = {
-        "records": [
-            {"metric": m, "value": 1}
-            for m in ("serve_mask_p50_ms",
-                      "serve_mask_p99_ms",
-                      "serve_mask_fetch_bytes_per_batch_raw",
-                      "serve_mask_fetch_bytes_per_batch_device",
-                      "serve_mask_fetch_reduction",
-                      "serve_mask_rle_byte_identical",
-                      "serve_mask_steady_state_compile_misses")
-        ],
-        "report": {
-            "claims": dict(claims),
-            "fetch_bytes": {
-                "raw_per_batch": 3237120.0,
-                "device_per_batch": 205056.0,
-                "reduction": 15.79,
-            },
-        },
-    }
-    art = tmp_path / "BENCH_serve_mask_cpu.json"
-    art.write_text(json.dumps(good))
-    assert check_bench_artifacts(tmp_path) == []
-
-    good["report"]["claims"]["fetch_reduction_ge_5x"] = False
-    del good["report"]["claims"]["rle_byte_identical"]
-    del good["report"]["fetch_bytes"]["reduction"]
-    good["records"] = good["records"][1:]
-    art.write_text(json.dumps(good))
-    errs = " | ".join(check_bench_artifacts(tmp_path))
-    assert "'fetch_reduction_ge_5x' not true" in errs
-    assert "'rle_byte_identical' missing" in errs
-    assert "fetch_bytes incomplete" in errs
-    assert "no record metric 'serve_mask_p50_ms*'" in errs
-
-
 # R4 against the ISSUE 17 rollout shape: the controller lock guards
 # only the split/shadow tables — device work (shadow scoring, warm
 # placement) and registry calls happen OUTSIDE it.  A controller that
@@ -1404,49 +1239,6 @@ def test_r5_silent_on_pop_after_stop_check():
                     path="mx_rcnn_tpu/serve/rollout.py") == []
 
 
-def test_rollout_artifact_schema_guard(tmp_path):
-    """BENCH_rollout_cpu.json must carry the five ISSUE 17 closure
-    claims — all true — plus the shadow divergence evidence and the
-    rollout metric records."""
-    claims = {
-        "zero_lost_requests": True,
-        "control_arm_byte_identical": True,
-        "divergence_auto_rollback": True,
-        "zero_steady_state_recompiles": True,
-        "closed_loop_promoted": True,
-    }
-    good = {
-        "records": [
-            {"metric": m, "value": 1}
-            for m in ("rollout_split_served",
-                      "rollout_shadow_compared",
-                      "rollout_promote_lost_requests",
-                      "rollout_rollback_incumbent_identical",
-                      "rollout_steady_state_recompiles",
-                      "rollout_distill_records",
-                      "rollout_loop_promoted_version")
-        ],
-        "report": {
-            "claims": dict(claims),
-            "divergence": {"compared": 12, "max_box_delta_px": 0.002},
-        },
-    }
-    art = tmp_path / "BENCH_rollout_cpu.json"
-    art.write_text(json.dumps(good))
-    assert check_bench_artifacts(tmp_path) == []
-
-    good["report"]["claims"]["divergence_auto_rollback"] = False
-    del good["report"]["claims"]["closed_loop_promoted"]
-    del good["report"]["divergence"]["compared"]
-    good["records"] = good["records"][1:]
-    art.write_text(json.dumps(good))
-    errs = " | ".join(check_bench_artifacts(tmp_path))
-    assert "'divergence_auto_rollback' not true" in errs
-    assert "'closed_loop_promoted' missing" in errs
-    assert "divergence incomplete" in errs
-    assert "no record metric 'rollout_split_served*'" in errs
-
-
 # R4 against the ISSUE 18 cascade shape: the router lock is a LEAF
 # guarding only the gate counters — the confidence gate itself runs on
 # host arrays and escalation re-entry goes back through the engine
@@ -1569,57 +1361,6 @@ def test_r5_silent_on_escalation_pop_after_drain_check():
                     path="mx_rcnn_tpu/serve/cascade.py") == []
 
 
-def test_cascade_artifact_schema_guard(tmp_path):
-    """BENCH_cascade_cpu.json must carry the five ISSUE 18 claims —
-    all true — plus the threshold-sweep evidence, the full
-    {box,mask} x {f32,bf16,int8} parity matrix, and the cascade metric
-    records."""
-    claims = {
-        "cost_reduction_ge_1p3x_at_matched_accuracy": True,
-        "full_escalation_byte_identical": True,
-        "zero_steady_state_recompiles": True,
-        "int8_parity_ok_box_and_mask": True,
-        "bf16_parity_ok_box_and_mask": True,
-    }
-    good = {
-        "records": [
-            {"metric": m, "value": 1}
-            for m in ("serve_cascade_cost_ms_per_image_matched",
-                      "serve_cascade_cost_reduction_x",
-                      "serve_cascade_accuracy_matched",
-                      "serve_cascade_escalation_rate_matched",
-                      "serve_cascade_parity_rungs_ok",
-                      "serve_cascade_int8_compression_x_box",
-                      "serve_cascade_steady_state_compile_misses")
-        ],
-        "report": {
-            "claims": dict(claims),
-            "sweep": [{"min_score": 0.0}, {"min_score": 0.6}],
-            "parity_matrix": [
-                {"family": f, "precision": p, "ok": True}
-                for f in ("box", "mask")
-                for p in ("f32", "bf16", "int8")
-            ],
-        },
-    }
-    art = tmp_path / "BENCH_cascade_cpu.json"
-    art.write_text(json.dumps(good))
-    assert check_bench_artifacts(tmp_path) == []
-
-    good["report"]["claims"]["cost_reduction_ge_1p3x_at_matched_accuracy"] = False
-    del good["report"]["claims"]["bf16_parity_ok_box_and_mask"]
-    good["report"]["sweep"] = good["report"]["sweep"][:1]
-    good["report"]["parity_matrix"] = good["report"]["parity_matrix"][1:]
-    good["records"] = good["records"][1:]
-    art.write_text(json.dumps(good))
-    errs = " | ".join(check_bench_artifacts(tmp_path))
-    assert "'cost_reduction_ge_1p3x_at_matched_accuracy' not true" in errs
-    assert "'bf16_parity_ok_box_and_mask' missing" in errs
-    assert "report.sweep missing" in errs
-    assert "parity_matrix must cover" in errs
-    assert "no record metric 'serve_cascade_cost_ms_per_image*'" in errs
-
-
 # R4 against the ISSUE 19 fleet gateway: the gateway routes by calling
 # into per-backend links, each with its own lock.  Calling a link
 # method while holding the gateway lock (or an upcall re-entering the
@@ -1735,53 +1476,3 @@ def test_r5_fires_on_droppable_correlated_response():
 def test_r5_silent_on_response_always_handed_off():
     assert run_rule(R5_FLEET_GOOD, ExactlyOnce(),
                     path="mx_rcnn_tpu/serve/fleet.py") == []
-
-
-def test_fleet_artifact_schema_guard(tmp_path):
-    """BENCH_serve_fleet_cpu.json must carry the five ISSUE 19 claims
-    — all true — plus the 1/2/4-backend scaling sweep and the chaos
-    kill-phase accounting."""
-    claims = {
-        "n1_byte_identical": True,
-        "scaling_2x": True,
-        "scaling_4x": True,
-        "chaos_zero_lost": True,
-        "chaos_byte_identical": True,
-    }
-    good = {
-        "records": [
-            {"metric": m, "value": 1}
-            for m in ("serve_fleet_imgs_per_sec_1",
-                      "serve_fleet_speedup_2x",
-                      "serve_fleet_speedup_4x",
-                      "serve_fleet_n1_byte_identical",
-                      "serve_fleet_chaos_lost",
-                      "serve_fleet_chaos_requeued",
-                      "serve_fleet_chaos_byte_identical")
-        ],
-        "report": {
-            "claims": dict(claims),
-            "scaling": [
-                {"backends": n, "imgs_per_sec": 100.0 * n,
-                 "speedup_x": float(n)}
-                for n in (1, 2, 4)
-            ],
-            "chaos": {"lost": 0, "requeued": 3, "byte_identical": True},
-        },
-    }
-    art = tmp_path / "BENCH_serve_fleet_cpu.json"
-    art.write_text(json.dumps(good))
-    assert check_bench_artifacts(tmp_path) == []
-
-    good["report"]["claims"]["chaos_zero_lost"] = False
-    del good["report"]["claims"]["scaling_4x"]
-    good["report"]["scaling"] = good["report"]["scaling"][:2]
-    del good["report"]["chaos"]["requeued"]
-    good["records"] = good["records"][1:]
-    art.write_text(json.dumps(good))
-    errs = " | ".join(check_bench_artifacts(tmp_path))
-    assert "'chaos_zero_lost' not true" in errs
-    assert "'scaling_4x' missing" in errs
-    assert "report.scaling must cover 1/2/4" in errs
-    assert "report.chaos incomplete" in errs
-    assert "no record metric 'serve_fleet_imgs_per_sec*'" in errs

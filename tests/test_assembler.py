@@ -1,6 +1,6 @@
 """Host data plane (ISSUE 5): assembly/completion pools, parallel ==
 serial bit-identical streams, fault-budget propagation from workers,
-overlapped pred_eval equivalence, and the eval bench record schema.
+and overlapped pred_eval equivalence.
 
 Everything here is numpy-only — no model build, no jit compile — so the
 whole file runs in a few seconds.
@@ -415,41 +415,3 @@ class TestOverlappedPredEval:
                 assert overlapped[j][i] == serial[j][i]
                 n_rles += len(serial[j][i])
         assert n_rles > 0, "degenerate run: no masks compared"
-
-
-# ------------------------------------------------------------ bench schema
-def test_eval_records_schema():
-    """BENCH_eval_cpu.json must carry the throughput, stage-counter, and
-    bitwise-equivalence fields (pure-function check — no benchmark run)."""
-    import importlib.util
-    import pathlib
-
-    path = pathlib.Path(__file__).resolve().parents[1] / "bench.py"
-    spec = importlib.util.spec_from_file_location("_bench_mod_eval", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    report = {
-        "overlapped_imgs_per_sec": 92.0,
-        "baseline_imgs_per_sec": 47.7,
-        "speedup": 1.93,
-        "byte_identical": True,
-        "in_flight": 2,
-        "overlapped": {
-            "assembly": {"occupancy": 0.5, "queue_depth_max": 3},
-            "completion": {"inflight_max": 4, "block_s": 0.0001},
-        },
-        "prepared_cache_stats": {"hits": 64, "misses": 64, "entries": 64},
-    }
-    records = bench._eval_records(report)
-    metrics = {r["metric"]: r for r in records}
-    assert metrics["eval_data_plane_imgs_per_sec"]["value"] == 92.0
-    assert metrics["eval_data_plane_imgs_per_sec"]["vs_baseline"] == 1.93
-    assert metrics["eval_data_plane_serial_imgs_per_sec"]["value"] == 47.7
-    assert metrics["eval_assembly_occupancy"]["value"] == 0.5
-    assert metrics["eval_completion_inflight_max"]["value"] == 4
-    assert metrics["eval_in_flight_window"]["value"] == 2
-    assert metrics["eval_prepared_cache_hits"]["value"] == 64
-    assert metrics["eval_byte_identical"]["value"] == 1
-    for r in records:
-        assert set(r) == {"metric", "value", "unit", "vs_baseline"}

@@ -283,6 +283,33 @@ class TestPoolElasticity:
         finally:
             pool.close()
 
+    def test_grow_costs_one_ladder_warmup_and_shrink_none(self):
+        """Compile misses by pool size: a scale-up is exactly one ladder
+        warm-up on the new replica, traffic at any size and a scale-down
+        add none."""
+        pool = ReplicaPool(make_factory(), 1, policy=FAST)
+        try:
+            pool.warmup()
+            assert pool.compile_cache.misses == len(LADDER)
+            r = pool.add_replica()
+            t_end = time.monotonic() + 10.0
+            while not r.routable and time.monotonic() < t_end:
+                time.sleep(0.01)
+            assert r.routable
+            assert pool.compile_cache.misses == 2 * len(LADDER)
+            ref = FakeRunner()
+            for i in range(8):
+                h, w = LADDER[i % 2]
+                pool.run(ref.assemble([ref.make_request(image(i, h, w))]))
+            assert pool.remove_replica() is r
+            pool.run(ref.assemble([ref.make_request(image(9))]))
+            assert [
+                x.runner.compile_cache.misses for x in pool.replicas
+            ] == [len(LADDER)]
+            assert r.runner.compile_cache.misses == len(LADDER)
+        finally:
+            pool.close()
+
     def test_remove_replica_never_strands_the_anchor(self):
         pool = ReplicaPool(make_factory(), 2, policy=FAST)
         try:

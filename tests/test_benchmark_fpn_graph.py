@@ -4,14 +4,7 @@
 the tier-1 run holds them: the cases live in
 ``benchmark/tests/test_fpn_graph.py`` (fast, no JAX)."""
 
-import os
-import sys
-
-_BENCH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
-for _p in (os.path.join(_BENCH, "tests"), _BENCH):
-    if _p not in sys.path:
-        sys.path.insert(0, _p)
+import benchmark_cases  # noqa: F401 — sys.path for the imports below
 
 from test_fpn_graph import *  # noqa: E402,F401,F403 — the cases themselves
 

@@ -276,6 +276,21 @@ class TestEngineCascade:
         assert snap["requests"]["completed"] == 2
         assert snap["requests"]["submitted"] == 2
 
+    def test_cascade_adds_no_compile_signature(self):
+        """A first pass is a cheap-family batch and an escalation a
+        flagship batch on the bucket both already have: one signature a
+        (family, bucket), however much traffic escalates."""
+        eng, runner = make_engine()
+        with eng:
+            eng.attach_cascade(POLICY)
+            for i in range(6):
+                im = hard_image(i) if i % 2 else easy_image(i)
+                eng.submit(im, model="flag").result(5)
+            eng.submit(easy_image(9), model="cheap").result(5)
+            snap = eng.snapshot()
+        assert snap["cascade"]["escalations"] == 3
+        assert runner.compile_cache.misses == 2 * len(LADDER)
+
     def test_direct_cheap_and_other_traffic_bypass_gate(self):
         eng, _ = make_engine()
         with eng:

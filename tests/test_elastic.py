@@ -6,9 +6,10 @@ The loop logic runs here against cheap NUMPY factories through the same
 ElasticContext interface the real shard_map substrate implements — every
 membership/replay/breaker assertion is jax-free and fast.  One @slow
 test at the bottom drives the REAL ``make_elastic_factory`` (two
-shard_map compiles); the chaos bench (``make elastic``) is the full
-real-mesh matrix.
+shard_map compiles).
 """
+
+import os
 
 import numpy as np
 import pytest
@@ -266,6 +267,50 @@ def test_emergency_checkpoint_is_committed_and_restorable(
     assert ref["w"] == state["w"]
 
 
+def test_crash_inside_the_emergency_save_resumes_to_the_same_state(
+    monkeypatch, tmp_path
+):
+    """The shrink's emergency save is itself killed between its data
+    write and its commit: the orphan stays uncommitted, the restarted
+    job resumes from the last committed dump, meets the same device
+    fault, shrinks cleanly this time, and ends on the state of a run
+    whose save was never interrupted."""
+    td = str(tmp_path)
+    bs = batches(5)
+
+    def ckpt(host_state, idx, meta):
+        return save_checkpoint(td, host_state, 0, idx, meta=meta)
+
+    set_faults(monkeypatch, "device_lost@2.1")
+    clean = ElasticLoop(fake_factory(8), 8)
+    want = clean.ctx.place_state(fake_state())
+    for b in bs:
+        want, _r, _ok = clean.step(want, b, None)
+
+    save_checkpoint(td, fake_state(), 0, 0)  # the last committed dump
+    set_faults(monkeypatch, "device_lost@2.1,save_crash@1")
+    loop = ElasticLoop(fake_factory(8), 8, checkpoint_fn=ckpt)
+    state = loop.ctx.place_state(fake_state())
+    with pytest.raises(faults.SimulatedCrash):
+        for b in bs:
+            state, _r, _ok = loop.step(state, b, None)
+    assert any(d.endswith(".tmp") for d in os.listdir(td))
+    assert loop.emergency_ckpts == []
+
+    # the restart, in the same fault registry: ``save_crash@1`` is spent,
+    # the device fault is still live
+    (epoch, pos), restored = load_restorable(td, fake_state())
+    assert (epoch, pos) == (0, 0)
+    again = ElasticLoop(fake_factory(8), 8, checkpoint_fn=ckpt)
+    state = again.ctx.place_state(restored)
+    for b in bs[pos:]:
+        state, _r, _ok = again.step(state, b, None)
+    assert again.monitor.shrinks == 1 and again.active == clean.active
+    assert len(again.emergency_ckpts) == 1
+    assert is_committed(again.emergency_ckpts[0])
+    assert state["w"] == want["w"] and int(state["step"]) == len(bs)
+
+
 def test_window_replay_with_deferred_aux(monkeypatch):
     """aux_interval=2: the fault strikes the second step of a window —
     the already-dispatched first step re-executes too, and every aux is
@@ -395,8 +440,7 @@ def test_stats_shape(monkeypatch):
 @pytest.mark.slow
 @pytest.mark.deadline(1800)
 def test_real_mesh_shrink_bitwise(monkeypatch, tmp_path):
-    """One real shard_map scenario (the chaos bench runs the full
-    matrix): lose 1 of 8 mid-run, finish on 7, and match a fresh
+    """One real shard_map scenario: lose 1 of 8 mid-run, finish on 7, and match a fresh
     survivor-mesh run restored from the emergency checkpoint bytewise."""
     import jax
 

@@ -244,19 +244,27 @@ class TestGatewayServing:
         gate = threading.Event()
         victim = Backend(runner=GatedStub(gate))
         survivor = Backend()
+        # the test owns the survivor's state: no revive probe (the
+        # monitor would ping the healthy survivor back "up" within a
+        # revive interval and route some of the six there) and no hedge
+        # (a gated request older than the hedge clock would reach the
+        # survivor a second time)
         gw = FleetGateway(
-            [victim.addr, survivor.addr], fail_threshold=1
-        ).start()
+            [victim.addr, survivor.addr], fail_threshold=1,
+            revive_interval=float("inf"), hedge_timeout=float("inf"),
+        )
+        gw._links[1].state = "down"
+        gw.start()
         try:
             # force every dispatch onto the gated victim, then sever its
             # connections with responses still in flight
             victim_link = gw._links[0]
-            gw._links[1].state = "down"
             futs = [gw.submit(image(10 + i)) for i in range(6)]
-            t_end = time.time() + 5.0
-            while victim_link.load() < 6 and time.time() < t_end:
+            t_end = time.monotonic() + 30.0
+            while victim_link.load() < 6 and time.monotonic() < t_end:
                 time.sleep(0.005)
             assert victim_link.load() == 6
+            assert gw._links[1].dispatched == 0
             gw._links[1].state = "up"
             with victim_link._lock:
                 conns = list(victim_link._conns)
@@ -354,6 +362,13 @@ class TestChaosProcessKill:
             procs[0].kill()  # SIGKILL: no goodbye on the wire
             results = [f.result(timeout=120.0) for f in futs]
             assert all(len(r) == 1 for r in results)
+            # every answer, requeued or not, is the unfaulted run's bytes
+            # (the digest is a pure function of the pixels)
+            ref = _FleetStubRunner(service_ms=0.0)
+            for im, got in zip(imgs, results):
+                batch = ref.assemble([ref.make_request(im)])
+                want = ref.detections_for(ref.run(batch), batch, 0)
+                assert dets_equal(got, want)
             snap = gw.snapshot()["gateway"]
             assert snap["completed"] == 60
             assert snap["failed"] == 0

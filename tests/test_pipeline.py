@@ -411,37 +411,3 @@ def test_prefetch_iterator_close_reclaims_worker():
     with PrefetchIterator(iter(range(3)), prefetch=2) as it2:
         assert next(it2) == 0
     assert it2._thread is None or not it2._thread.is_alive()
-
-
-# ------------------------------------------------------------- bench schema
-def test_bench_pipeline_records_schema():
-    """BENCH_pipeline.json must carry the feed-occupancy and fetch-stall
-    fields the roofline reconciliation reads (pure-function check — no
-    model run)."""
-    import importlib.util
-    import pathlib
-
-    path = pathlib.Path(__file__).resolve().parents[1] / "bench.py"
-    spec = importlib.util.spec_from_file_location("_bench_mod", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    report = {
-        "feed": {"occupancy": 0.95, "feed_starved_after_first": 0},
-        "loop": {"fetches": 4, "fetch_stalls": 1, "fetch_stall_ms": 2.5,
-                 "flushes": 4},
-        "min_staged_ahead": 1,
-        "interflush_blocking_fetches": 0,
-        "k1_byte_identical": True,
-        "imgs_per_sec": 1.0,
-    }
-    records = bench._pipeline_records(report)
-    metrics = {r["metric"]: r["value"] for r in records}
-    assert metrics["pipeline_feed_occupancy"] == 0.95
-    assert metrics["pipeline_feed_starved_steps"] == 0
-    assert metrics["pipeline_fetch_stalls"] == 1
-    assert metrics["pipeline_fetch_stall_ms"] == 2.5
-    assert metrics["pipeline_interflush_blocking_fetches"] == 0
-    assert metrics["pipeline_k1_byte_identical"] == 1
-    for r in records:
-        assert set(r) == {"metric", "value", "unit", "vs_baseline"}

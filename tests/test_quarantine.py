@@ -591,3 +591,57 @@ def test_loadgen_poison_mix_accounts_per_flavor(no_faults):
     assert out["ok"] == 24 - n_nan                # healthy traffic untouched
     assert report["poison_outcomes"]["nan"] == {"invalid": n_nan}
     assert report["engine"]["requests"]["invalid"] == n_nan
+
+
+def test_poison_mix_under_load_is_contained(monkeypatch):
+    """A sixth of a live load is a query of death: no healthy request is
+    lost, every healthy answer is byte-identical to the unfaulted run's,
+    every poison digest ends quarantined within K trips each, and every
+    replica is healthy again at the end."""
+    sizes, seed, n = ((24, 24), (16, 16)), 5, 36
+    mix = [None] * 5 + ["qod"]
+    # run_load's own draws (sizes, then the poison mix) say which
+    # requests are poison and which digests the fault spec must name
+    rng = np.random.RandomState(seed)
+    req_sizes = [sizes[rng.randint(len(sizes))] for _ in range(n)]
+    req_poison = [mix[rng.randint(len(mix))] for _ in range(n)]
+    healthy = [i for i, fl in enumerate(req_poison) if fl is None]
+    digests = sorted({
+        request_digest(qod_image(h, w, seed))
+        for (h, w), fl in zip(req_sizes, req_poison) if fl == "qod"
+    })
+    assert digests and len(healthy) > n // 2
+
+    def one_run(spec):
+        monkeypatch.setenv(faults.ENV_VAR, spec)
+        faults.reset()
+        # the budget outlasts a lap in which both replicas are rewarming
+        qt, pool, engine = _containment_stack(
+            retry_budget=32, max_linger=0.002)
+        try:
+            with engine:
+                report = run_load(
+                    engine, num_requests=n, concurrency=4, sizes=sizes,
+                    seed=seed, collect=True, poison_mix=mix,
+                )
+            wait_for(
+                lambda: all(r.state is ReplicaState.HEALTHY
+                            for r in pool.replicas),
+                timeout=30.0, msg="every replica to rejoin",
+            )
+            return report["_results"], qt
+        finally:
+            pool.close()
+            faults.reset()
+
+    clean, _ = one_run("")
+    dirty, qt = one_run(
+        ",".join(f"poison_fail@{d[:12]}" for d in digests))
+    for i in healthy:
+        assert clean[i][0] == "ok" and dirty[i][0] == "ok", i
+        np.testing.assert_array_equal(clean[i][1][0], dirty[i][1][0])
+    quarantined = set(qt.snapshot()["quarantined"])
+    assert {d[:12] for d in digests} <= quarantined
+    assert qt.trips <= len(digests) * (qt.k + 1)
+    for i in set(range(n)) - set(healthy):
+        assert dirty[i][0] != "ok", i

@@ -359,26 +359,39 @@ def test_swap_under_load_zero_lost_and_byte_identical(no_faults, ckpts):
     eng = ServingEngine(runner, max_linger=0.001, max_queue=64).start()
     try:
         N = 60
-        report = {}
+        waves = []  # (seed, report)
+        swapped = threading.Event()
 
         def load():
-            report.update(run_load(
-                eng, num_requests=N, concurrency=4, sizes=SIZES, seed=7,
-                collect=True,
-            ))
+            # wave after wave until one has been sent WHOLE after the swap
+            # returned: a fixed count can be over before the swap lands,
+            # and then nothing is "post"
+            seed = 7
+            while True:
+                last = swapped.is_set()
+                waves.append((seed, run_load(
+                    eng, num_requests=N, concurrency=4, sizes=SIZES,
+                    seed=seed, collect=True,
+                )))
+                if last:
+                    return
+                seed += 1
 
         t = threading.Thread(target=load)
         t.start()
-        wait_for(lambda: eng.metrics.completed >= N // 4, msg="mid-load")
-        t_sw0 = time.monotonic()
-        result = eng.swap("det", ckpts["v2"], block=True, timeout=30)
-        t_sw1 = time.monotonic()
-        t.join()
+        try:
+            wait_for(lambda: eng.metrics.completed >= N // 4,
+                     msg="mid-load")
+            t_sw0 = time.monotonic()
+            result = eng.swap("det", ckpts["v2"], block=True, timeout=30)
+            t_sw1 = time.monotonic()
+        finally:
+            swapped.set()
+            t.join(timeout=60)
+        assert not t.is_alive()
 
         assert result["model"] == "det" and result["version"] == 2
         assert result["previous"] == 1 and result["warmed"] >= 1
-        assert report["outcomes"]["ok"] == N
-        assert report["outcomes"]["error"] == 0
         snap = eng.snapshot()
         assert snap["requests"]["failed"] == 0
         assert snap["registry"]["swaps"]["completed"] == 1
@@ -389,29 +402,34 @@ def test_swap_under_load_zero_lost_and_byte_identical(no_faults, ckpts):
         # before the swap started → v1 bytes; submitted after the swap
         # returned → v2 bytes; straddling → exactly one of the two
         # (exactly-once: never a mixture, never a loss)
-        sizes_rng = np.random.RandomState(7)
-        req_sizes = [SIZES[sizes_rng.randint(len(SIZES))] for _ in range(N)]
         from mx_rcnn_tpu.serve.loadgen import synthetic_image
 
         pre = post = straddle = 0
-        for i in range(N):
-            kind, dets = report["_results"][i]
-            assert kind == "ok", f"request {i} resolved {kind}"
-            got = dets[0].tobytes()
-            h, w = req_sizes[i]
-            im = synthetic_image(i, h, w, 7)
-            v1 = expected(im, 1.0).tobytes()
-            v2 = expected(im, 2.0).tobytes()
-            t_submit, t_done = report["_times"][i]
-            if t_done <= t_sw0:
-                assert got == v1, f"pre-swap request {i} not v1 bytes"
-                pre += 1
-            elif t_submit >= t_sw1:
-                assert got == v2, f"post-swap request {i} not v2 bytes"
-                post += 1
-            else:
-                assert got in (v1, v2), f"straddling request {i} mixed"
-                straddle += 1
+        for seed, report in waves:
+            assert report["outcomes"]["ok"] == N
+            assert report["outcomes"]["error"] == 0
+            sizes_rng = np.random.RandomState(seed)
+            req_sizes = [
+                SIZES[sizes_rng.randint(len(SIZES))] for _ in range(N)
+            ]
+            for i in range(N):
+                kind, dets = report["_results"][i]
+                assert kind == "ok", f"wave {seed} request {i}: {kind}"
+                got = dets[0].tobytes()
+                h, w = req_sizes[i]
+                im = synthetic_image(i, h, w, seed)
+                v1 = expected(im, 1.0).tobytes()
+                v2 = expected(im, 2.0).tobytes()
+                t_submit, t_done = report["_times"][i]
+                if t_done <= t_sw0:
+                    assert got == v1, f"pre-swap request {i} not v1 bytes"
+                    pre += 1
+                elif t_submit >= t_sw1:
+                    assert got == v2, f"post-swap request {i} not v2 bytes"
+                    post += 1
+                else:
+                    assert got in (v1, v2), f"straddling request {i} mixed"
+                    straddle += 1
         assert pre > 0 and post > 0, (pre, straddle, post)
         # retired v1 released its params (PR 4 free-the-retired discipline)
         v1_ver = reg.entry("det").versions[0]

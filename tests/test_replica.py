@@ -566,6 +566,13 @@ def test_pool_snapshot_merges_replica_histograms(no_faults):
         for i in range(4):
             batch = ref.assemble([ref.make_request(image(40 + i))])
             pool.run(batch)
+        # a replica resolves a dispatch's future BEFORE it books the
+        # latency, so ``pool.run`` can return with the last result still
+        # unbooked: read the snapshot once all four are in
+        wait_for(
+            lambda: sum(r.latency.count for r in pool.replicas) == 4,
+            msg="every replica to book its traffic results",
+        )
         snap = pool.snapshot()
         merged = snap["latency"]["replica_predict_merged"]["count"]
         assert merged == sum(

@@ -284,6 +284,40 @@ def ckpts(tmp_path_factory):
     return out
 
 
+def rollout_under_load(eng, ckpt, n=48, seed=7):
+    """Run ``rollout det <ckpt>`` to its end with live load beside it;
+    → ``(result, waves)``, one ``(seed, report)`` a wave of ``n``
+    requests.  The load runs wave after wave until the rollout has
+    settled: it needs traffic for its evidence from the moment its
+    candidate has restored and warmed until it promotes, and a fixed
+    count can be over before a slow restore is."""
+    waves, settled = [], threading.Event()
+
+    def load():
+        s = seed
+        while True:
+            waves.append((s, run_load(
+                eng, num_requests=n, concurrency=4, sizes=SIZES, seed=s,
+                collect=True,
+            )))
+            if settled.is_set():
+                return
+            s += 1
+
+    t = threading.Thread(target=load)
+    t.start()
+    try:
+        wait_for(lambda: eng.metrics.completed >= n // 6, msg="mid-load")
+        result = eng.rollout.start(
+            "det", ckpt, policy=fast_policy(), block=True, timeout=60,
+        )
+    finally:
+        settled.set()
+        t.join(timeout=60)
+    assert not t.is_alive()
+    return result, waves
+
+
 def fast_policy(**over):
     base = dict(
         split_pct=30.0, shadow=True, min_compared=4, min_served=3,
@@ -519,26 +553,9 @@ def test_promote_under_load_zero_lost_zero_recompile(ckpts):
         eng.attach_rollout()
         misses0 = runner.compile_cache.misses
         N = 48
-        report = {}
-
-        def load():
-            report.update(run_load(
-                eng, num_requests=N, concurrency=4, sizes=SIZES, seed=7,
-                collect=True,
-            ))
-
-        t = threading.Thread(target=load)
-        t.start()
-        wait_for(lambda: eng.metrics.completed >= N // 6, msg="mid-load")
-        result = eng.rollout.start(
-            "det", ckpts["good"], policy=fast_policy(), block=True,
-            timeout=60,
-        )
-        t.join()
+        result, waves = rollout_under_load(eng, ckpts["good"], N)
         assert result["version"] == 2 and result["previous"] == 1
         assert result["split_served"] >= 3 and result["split_errors"] == 0
-        assert report["outcomes"]["ok"] == N
-        assert report["outcomes"].get("error", 0) == 0
         snap = eng.snapshot()
         assert snap["requests"]["failed"] == 0
         assert snap["rollout"]["promoted"] == 1
@@ -546,15 +563,24 @@ def test_promote_under_load_zero_lost_zero_recompile(ckpts):
         # zero steady-state recompiles across split + shadow + promote
         assert runner.compile_cache.misses == misses0
         # every response was one version's bytes, never a mixture
-        sizes_rng = np.random.RandomState(7)
-        req_sizes = [SIZES[sizes_rng.randint(len(SIZES))] for _ in range(N)]
-        for i in range(N):
-            kind, dets = report["_results"][i]
-            assert kind == "ok", f"request {i} resolved {kind}"
-            im = synthetic_image(i, *req_sizes[i], 7)
-            assert dets[1].tobytes() in (
-                expected_bytes(im, 1.0), expected_bytes(im, W_GOOD)
-            ), f"request {i} served mixed-version bytes"
+        served = set()
+        for seed, report in waves:
+            assert report["outcomes"]["ok"] == N
+            assert report["outcomes"].get("error", 0) == 0
+            sizes_rng = np.random.RandomState(seed)
+            req_sizes = [
+                SIZES[sizes_rng.randint(len(SIZES))] for _ in range(N)
+            ]
+            for i in range(N):
+                kind, dets = report["_results"][i]
+                assert kind == "ok", f"wave {seed} request {i}: {kind}"
+                im = synthetic_image(i, *req_sizes[i], seed)
+                got = dets[1].tobytes()
+                assert got in (
+                    expected_bytes(im, 1.0), expected_bytes(im, W_GOOD)
+                ), f"wave {seed} request {i} served mixed-version bytes"
+                served.add(got == expected_bytes(im, W_GOOD))
+        assert served == {False, True}  # both versions answered the load
         # per-version metrics partition recorded both arms
         assert {"det:v1", "det:v2"} <= set(snap["versions"])
         # post-promote traffic is candidate bytes
@@ -660,6 +686,68 @@ def test_quarantine_suspects_ring_counts_drops():
     # the ring kept the NEWEST suspects
     assert "digest-0009"[:12] in snap["suspects"]
     assert "digest-0000"[:12] not in snap["suspects"]
+
+
+# ------------------------------- closed loop: serve -> distill -> promote
+
+def test_closed_loop_distilled_checkpoint_promotes(tmp_path, monkeypatch):
+    """Served detections are harvested into records, ``fine_tune`` turns
+    them into a rollout-ready checkpoint, and that checkpoint promotes
+    under load with nothing lost.  Host-only: the two device halves of
+    ``fine_tune`` (the trainer, the model's serve-time init) are stood
+    in for; harvest, the JSONL round-trip, ``merge_params`` (a train-time
+    tree carries subtrees the serve tree lacks), the checkpoint, the
+    structure gate and the rollout are the real code."""
+    import mx_rcnn_tpu.core.fit as fit_mod
+    import mx_rcnn_tpu.models as models_mod
+    from mx_rcnn_tpu.tools import distill
+
+    reg = make_registry()
+    runner = FakeRolloutRunner(reg, service_s=0.002)
+    eng = ServingEngine(runner, max_linger=0.001, max_queue=64).start()
+    try:
+        eng.attach_rollout()
+        first = run_load(eng, num_requests=12, concurrency=4, sizes=SIZES,
+                         seed=3, collect=True)
+        sizes_rng = np.random.RandomState(3)
+        records = distill.harvest(
+            [(first["_results"][i][1],
+              SIZES[sizes_rng.randint(len(SIZES))]) for i in range(12)],
+            min_score=0.5, num_classes=2,
+        )
+        # (a box the 16x16 images clip to nothing gives no record)
+        assert 8 <= len(records) <= 12
+        path = str(tmp_path / "distilled.jsonl")
+        distill.write_records(records, path)
+
+        seen = {}
+
+        def fake_fit(model, cfg, roidb, **kw):
+            seen["records"] = len(roidb)
+            return {**params_tree(W_GOOD),
+                    "sampling_head": np.zeros(3, np.float32)}
+
+        class ServeInit:
+            def init(self, *a, train, **kw):
+                assert train is False
+                return {"params": params_tree(0.0)}
+
+        monkeypatch.setattr(fit_mod, "fit", fake_fit)
+        monkeypatch.setattr(models_mod, "build_model", lambda cfg: ServeInit())
+        ck = distill.fine_tune(distill.read_records(path),
+                               out_dir=str(tmp_path / "loop"))
+        assert seen["records"] == len(records)
+
+        result, waves = rollout_under_load(eng, ck)
+        assert result["version"] == 2 and reg.live("det").version == 2
+        for _seed, report in waves:
+            assert report["outcomes"]["ok"] == 48
+        assert eng.snapshot()["requests"]["failed"] == 0
+        im = synthetic_image(4242, 24, 24, 3)
+        assert eng.submit(im).result(5)[1].tobytes() == \
+            expected_bytes(im, W_GOOD)
+    finally:
+        eng.stop()
 
 
 # -------------------------------------- closed loop: distill round-trip

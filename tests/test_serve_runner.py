@@ -51,9 +51,7 @@ def _tiny_cfg():
     )
 
 
-@pytest.fixture(scope="module")
-def runner():
-    cfg = _tiny_cfg()
+def _init(cfg):
     model = build_model(cfg)
     h, w = cfg.SHAPE_BUCKETS[0]
     params = model.init(
@@ -62,7 +60,20 @@ def runner():
         np.array([[h, w, 1.0]], np.float32),
         train=False,
     )["params"]
-    r = ServeRunner(model, params, cfg, max_batch=MAX_BATCH)
+    return model, params
+
+
+@pytest.fixture(scope="module")
+def box_env():
+    cfg = _tiny_cfg()
+    model, params = _init(cfg)
+    return {"cfg": cfg, "model": model, "params": params}
+
+
+@pytest.fixture(scope="module")
+def runner(box_env):
+    r = ServeRunner(box_env["model"], box_env["params"], box_env["cfg"],
+                    max_batch=MAX_BATCH)
     assert r.warmup() == len(BUCKETS)
     return r
 
@@ -202,7 +213,7 @@ def _damped(params):
     """De-saturate the score/delta/mask heads: at random init the
     softmax scores every roi at EXACTLY 1.0, so host-vs-device keep
     order on those exact float ties is undefined and parity would
-    measure tie-break luck (same trick as bench.py --serve_mask)."""
+    measure tie-break luck."""
     def damp(path, leaf):
         name = "/".join(str(getattr(p, "key", p)) for p in path)
         if any(f in name for f in ("rpn_cls_score", "rpn_bbox_pred",
@@ -219,20 +230,15 @@ def mask_env():
     from mx_rcnn_tpu.serve.registry import ModelRegistry
 
     cfg = _mask_cfg()
-    model = build_model(cfg)
-    h, w = cfg.SHAPE_BUCKETS[0]
-    params = _damped(model.init(
-        {"params": jax.random.key(0)},
-        np.zeros((1, h, w, 3), np.float32),
-        np.array([[h, w, 1.0]], np.float32),
-        train=False,
-    )["params"])
+    model, raw_params = _init(cfg)
+    params = _damped(raw_params)
     registry = ModelRegistry()
     registry.register("masks", model, cfg, params)
     dev = ServeRunner(registry=registry, max_batch=MAX_BATCH)
     assert dev.warmup() == len(BUCKETS)
     raw = ServeRunner(model, params, cfg, max_batch=MAX_BATCH, device_postprocess=False)
     return {"cfg": cfg, "model": model, "params": params,
+            "raw_params": raw_params,
             "registry": registry, "dev": dev, "raw": raw}
 
 
@@ -345,6 +351,40 @@ class TestDeviceMaskServing:
                 max_batch=MAX_BATCH, precision="bfloat16",
                 parity_check=False,
             )
+
+
+class TestReducedPrecisionRungs:
+    """The compression ladder's rungs on the two real tiny families: a
+    bf16 or int8 runner's warm-up runs the f32 parity gate (boxes,
+    scores and, for the mask family, mask probabilities), passes it,
+    compiles one program a bucket and serving adds none."""
+
+    @pytest.mark.parametrize("precision", ["bfloat16", "int8"])
+    @pytest.mark.parametrize("family", ["box", "mask"])
+    def test_rung_passes_the_parity_gate_and_adds_no_compile(
+        self, family, precision, box_env, mask_env
+    ):
+        tag = {"bfloat16": "bf16", "int8": "int8"}[precision]
+        env = box_env if family == "box" else mask_env
+        # the raw random init: its saturated scores rank the proposals
+        # with wide margins, so the gate reads numeric drift and not the
+        # flip of an NMS tie between near-equal scores (which the damped
+        # mask parameters are full of)
+        params = env.get("raw_params", env["params"])
+        r = ServeRunner(env["model"], params, env["cfg"],
+                        max_batch=MAX_BATCH, precision=precision)
+        assert r.warmup() == len(BUCKETS)
+        report = r.parity[f"{r.default_model}:{tag}"]
+        assert report["checked"] and report["ok"]
+        assert report["precision"] == tag
+        assert report["unmatched_confident"] == 0
+        assert report["max_box_delta_px"] <= report["box_tol_px"]
+        assert report["max_score_delta"] <= report["score_tol"]
+        if family == "mask":
+            assert report["mask_pairs"] > 0
+            assert report["max_mask_prob_delta"] <= report["mask_tol"]
+        r.run(r.assemble([r.make_request(_image(21, 64, 64))]))
+        assert r.compile_cache.misses == len(BUCKETS)
 
 
 class TestServingEngine:

@@ -569,9 +569,9 @@ def canvas_env():
         train=False,
     )["params"]
 
-    # de-saturate the heads (bench.py --serve_mask trick): at random
-    # init every roi scores exactly 1.0 and keep order on exact float
-    # ties would measure tie-break luck, not parity
+    # de-saturate the heads (as tests/test_serve_runner.py::_damped): at
+    # random init every roi scores exactly 1.0 and keep order on exact
+    # float ties would measure tie-break luck, not parity
     def damp(path, leaf):
         name = "/".join(str(getattr(p, "key", p)) for p in path)
         if any(f in name for f in ("rpn_cls_score", "rpn_bbox_pred",
@@ -657,3 +657,14 @@ class TestCanvasParity:
             assert r.pastes >= 1
             assert r.paste_ms_total >= 0.0
             assert r.paste_bytes_total > 0
+
+    def test_canvas_serving_adds_no_compile_after_warmup(self, canvas_env):
+        """One canvas shape a (model, bucket) rung: frames of any size
+        that resizes into the bucket run the warm-up's program, paste
+        included."""
+        dev, host = canvas_env["dev"], canvas_env["host"]
+        for i, (h, w) in enumerate(((64, 64), (48, 48), (32, 32))):
+            for r in (dev, host):
+                r.run(r.assemble([r.make_request(_canvas_image(7 + i, h, w))]))
+        assert dev.compile_cache.misses == 1
+        assert host.compile_cache.misses == 1
