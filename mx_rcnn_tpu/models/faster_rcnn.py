@@ -27,6 +27,7 @@ import numpy as np
 
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.models.heads import RCNNHead
+from mx_rcnn_tpu.models.layers import per_image
 from mx_rcnn_tpu.models.rpn import RPNHead
 from mx_rcnn_tpu.ops.anchors import shifted_anchors
 from mx_rcnn_tpu.ops.losses import (
@@ -157,9 +158,10 @@ class FasterRCNN(nn.Module):
         # ``backbone``, ``rpn`` and ``rcnn`` itself.  Metadata only.
         # --- RPN anchor targets (reference: rcnn/io/rpn.py :: assign_anchor)
         with jax.named_scope("anchor_targets"):
-            atgt = jax.vmap(
-                lambda gtb, gtv, info, k: assign_anchor(anchors, gtb[:, :4], gtv, info, k, cfg)
-            )(gt_boxes, gt_valid, im_info, keys[:, 0])
+            atgt = per_image(
+                lambda gtb, gtv, info, k: assign_anchor(anchors, gtb[:, :4], gtv, info, k, cfg),
+                gt_boxes, gt_valid, im_info, keys[:, 0],
+            )
 
         # --- proposals (stop-gradient: reference proposal op has no backward)
         with jax.named_scope("proposal"):

@@ -36,7 +36,7 @@ import numpy as np
 
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.models.heads import MaskHead, RCNNHead
-from mx_rcnn_tpu.models.layers import conv
+from mx_rcnn_tpu.models.layers import conv, per_image
 from mx_rcnn_tpu.models.resnet import (
     RESNET_BLOCK_ORDER,
     ResNetBackbone,
@@ -115,19 +115,6 @@ class FPNTopHead(nn.Module):
         x = nn.Dense(self.width, dtype=self.dtype, param_dtype=jnp.float32,
                      name="fc2")(x)
         return nn.relu(x)
-
-
-def per_image(fn, *args):
-    """``jax.vmap(fn)(*args)`` over the leading (image) axis — except at
-    batch 1, where ``fn`` runs on the one image without the batch axis.
-    Same values either way; the shapes differ, and that is the point:
-    the TPU compiler aborts in its TopK emitter on a ``[1, N]`` operand
-    at the finest level's N = 152·256·3 = 116736 (ROADMAP R1), while the
-    rank-1 ``[N]`` and every batch ≥ 2 compile."""
-    if args[0].shape[0] != 1:
-        return jax.vmap(fn)(*args)
-    out = fn(*(a[0] for a in args))
-    return jax.tree_util.tree_map(lambda x: x[None], out)
 
 
 def roi_levels(rois: jnp.ndarray, k0: int = 4, canonical: float = 224.0,
@@ -408,11 +395,12 @@ class FPNFasterRCNN(nn.Module):
 
         # stage scopes as in FasterRCNN.train_forward (metadata only)
         with jax.named_scope("anchor_targets"):
-            atgt = jax.vmap(
+            atgt = per_image(
                 lambda gtb, gtv, info, k: assign_anchor(
                     anchors, gtb[:, :4], gtv, info, k, cfg
-                )
-            )(gt_boxes, gt_valid, im_info, keys[:, 0])
+                ),
+                gt_boxes, gt_valid, im_info, keys[:, 0],
+            )
 
         if proposals is not None:
             # frozen-proposal mode (ROIIter role / churn ablation): the

@@ -258,3 +258,18 @@ def conv(
         param_dtype=jnp.float32,
         name=name,
     )
+
+
+def per_image(fn, *args):
+    """``jax.vmap(fn)(*args)`` over the leading (image) axis — except at
+    batch 1, where ``fn`` runs on the one image without the batch axis.
+    Same values either way; the shapes differ, and that is the point:
+    the TPU compiler aborts in its TopK emitter on a ``[1, N]`` operand
+    at the pyramid's finest level's N = 152·256·3 = 116736 (ROADMAP R1),
+    while the rank-1 ``[N]`` and every batch ≥ 2 compile.  Every per-image
+    ``top_k`` over anchors goes through here: the pyramid's proposals and
+    both families' anchor targets (``ops/targets.py::_random_keep_k``)."""
+    if args[0].shape[0] != 1:
+        return jax.vmap(fn)(*args)
+    out = fn(*(a[0] for a in args))
+    return jax.tree_util.tree_map(lambda x: x[None], out)

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.models.heads import RCNNHead
+from mx_rcnn_tpu.models.layers import per_image
 from mx_rcnn_tpu.models.resnet import (
     RESNET_BLOCK_ORDER,
     ResNetBackbone,
@@ -138,11 +139,12 @@ class RPNOnly(nn.Module):
             keys = jax.vmap(lambda s: jax.random.fold_in(key, s))(sample_seeds)
         else:
             keys = jax.random.split(key, b)
-        atgt = jax.vmap(
+        atgt = per_image(
             lambda gtb, gtv, info, k: assign_anchor(
                 anchors, gtb[:, :4], gtv, info, k, cfg
-            )
-        )(gt_boxes, gt_valid, im_info, keys)
+            ),
+            gt_boxes, gt_valid, im_info, keys,
+        )
 
         rpn_norm = float(t.RPN_BATCH_SIZE * b)
         rpn_cls_loss = softmax_cross_entropy(

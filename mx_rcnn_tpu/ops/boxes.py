@@ -37,26 +37,43 @@ def bbox_overlaps(boxes: jnp.ndarray, query_boxes: jnp.ndarray) -> jnp.ndarray:
     return inter / jnp.maximum(union, 1e-12)
 
 
-def bbox_transform(ex_rois: jnp.ndarray, gt_rois: jnp.ndarray) -> jnp.ndarray:
-    """Encode gt boxes w.r.t. example rois → (N, 4) [dx, dy, dw, dh].
-
-    Reference: ``rcnn/processing/bbox_transform.py :: nonlinear_transform``.
+def bbox_transform_planes(ex, gt):
+    """:func:`bbox_transform` on coordinate planes: ``ex`` and ``gt`` are
+    each ``(x1, y1, x2, y2)`` of equal-shaped arrays; → ``(dx, dy, dw, dh)``
+    of that shape.  The one place the arithmetic lives: a caller with N on
+    the minor axis (``ops/targets.py::assign_anchor``) never forms an
+    ``(N, 4)`` operand, which the TPU pads to 128 lanes.
     """
-    ex_w = ex_rois[:, 2] - ex_rois[:, 0] + 1.0
-    ex_h = ex_rois[:, 3] - ex_rois[:, 1] + 1.0
-    ex_cx = ex_rois[:, 0] + 0.5 * (ex_w - 1.0)
-    ex_cy = ex_rois[:, 1] + 0.5 * (ex_h - 1.0)
+    ex_x1, ex_y1, ex_x2, ex_y2 = ex
+    gt_x1, gt_y1, gt_x2, gt_y2 = gt
+    ex_w = ex_x2 - ex_x1 + 1.0
+    ex_h = ex_y2 - ex_y1 + 1.0
+    ex_cx = ex_x1 + 0.5 * (ex_w - 1.0)
+    ex_cy = ex_y1 + 0.5 * (ex_h - 1.0)
 
-    gt_w = gt_rois[:, 2] - gt_rois[:, 0] + 1.0
-    gt_h = gt_rois[:, 3] - gt_rois[:, 1] + 1.0
-    gt_cx = gt_rois[:, 0] + 0.5 * (gt_w - 1.0)
-    gt_cy = gt_rois[:, 1] + 0.5 * (gt_h - 1.0)
+    gt_w = gt_x2 - gt_x1 + 1.0
+    gt_h = gt_y2 - gt_y1 + 1.0
+    gt_cx = gt_x1 + 0.5 * (gt_w - 1.0)
+    gt_cy = gt_y1 + 0.5 * (gt_h - 1.0)
 
     dx = (gt_cx - ex_cx) / (ex_w + 1e-14)
     dy = (gt_cy - ex_cy) / (ex_h + 1e-14)
     dw = jnp.log(jnp.maximum(gt_w, 1.0) / jnp.maximum(ex_w, 1e-14))
     dh = jnp.log(jnp.maximum(gt_h, 1.0) / jnp.maximum(ex_h, 1e-14))
-    return jnp.stack([dx, dy, dw, dh], axis=1)
+    return dx, dy, dw, dh
+
+
+def bbox_transform(ex_rois: jnp.ndarray, gt_rois: jnp.ndarray) -> jnp.ndarray:
+    """Encode gt boxes w.r.t. example rois → (N, 4) [dx, dy, dw, dh].
+
+    Reference: ``rcnn/processing/bbox_transform.py :: nonlinear_transform``.
+    """
+    return jnp.stack(
+        bbox_transform_planes(
+            [ex_rois[:, i] for i in range(4)], [gt_rois[:, i] for i in range(4)]
+        ),
+        axis=1,
+    )
 
 
 def bbox_pred(boxes: jnp.ndarray, box_deltas: jnp.ndarray) -> jnp.ndarray:

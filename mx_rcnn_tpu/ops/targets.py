@@ -17,6 +17,10 @@ shapes.  Known, documented deviations from the reference:
 - when fewer than ``BATCH_ROIS`` fg+bg candidates exist (pathological,
   e.g. tiny unit tests), remaining slots are filled with zero-weight
   ignore rois instead of the reference's sample-with-replacement padding.
+
+Layout rule (``assign_anchor``, ``_random_keep_k``): everything of length N
+is a dense ``(N,)`` plane (``(B, N)`` under ``vmap``, N on the TPU's lanes),
+and nothing of length N is sorted, scattered or gathered by row.
 """
 
 from __future__ import annotations
@@ -27,23 +31,33 @@ import jax
 import jax.numpy as jnp
 
 from mx_rcnn_tpu.config import Config
-from mx_rcnn_tpu.ops.boxes import bbox_overlaps, bbox_transform
+from mx_rcnn_tpu.ops.boxes import (
+    bbox_overlaps,
+    bbox_transform,
+    bbox_transform_planes,
+)
+from mx_rcnn_tpu.ops.losses import one_hot_select
 
 _BIG = 1e9
 
 
-def _random_keep_k(key, candidate_mask: jnp.ndarray, k) -> jnp.ndarray:
+def _random_keep_k(key, candidate_mask: jnp.ndarray, k, k_max: int) -> jnp.ndarray:
     """Keep a uniformly-random size-``min(k, n_candidates)`` subset.
 
-    Returns a bool mask.  ``k`` may be a traced scalar.
-    Ranks candidates by iid uniforms; non-candidates rank last.
+    Returns a bool mask.  ``k`` may be a traced scalar, ``k_max`` is its
+    static upper bound.  Ranks candidates by iid uniforms (non-candidates
+    rank last) and keeps the first ``k`` of the ``k_max`` best: the set a
+    full descending sort would keep, since ``top_k`` like the stable sort
+    puts the lower index first among equal priorities.
     """
     n = candidate_mask.shape[0]
+    k_max = min(k_max, n)
     priority = jax.random.uniform(key, (n,)) - (~candidate_mask) * 2.0
-    # rank[i] = position of i in descending priority order
-    order = jnp.argsort(-priority)
-    rank = jnp.zeros((n,), jnp.int32).at[order].set(jnp.arange(n, dtype=jnp.int32))
-    return candidate_mask & (rank < k)
+    _, best = jax.lax.top_k(priority, k_max)
+    kept = jnp.zeros((n,), bool).at[best].set(
+        jnp.arange(k_max) < k, unique_indices=True
+    )
+    return candidate_mask & kept
 
 
 def bbox_denorm_vectors(cfg: Config, num_classes: int):
@@ -94,14 +108,14 @@ def assign_anchor(
     to RPN_FG_FRACTION·RPN_BATCH_SIZE fg and the remainder bg.
     """
     t = cfg.TRAIN
-    n = anchors.shape[0]
     h, w = im_info[0], im_info[1]
+    ax1, ay1, ax2, ay2 = (anchors[:, i] for i in range(4))       # (N,) planes
 
     inside = (
-        (anchors[:, 0] >= -allowed_border)
-        & (anchors[:, 1] >= -allowed_border)
-        & (anchors[:, 2] < w + allowed_border)
-        & (anchors[:, 3] < h + allowed_border)
+        (ax1 >= -allowed_border)
+        & (ay1 >= -allowed_border)
+        & (ax2 < w + allowed_border)
+        & (ay2 < h + allowed_border)
     )
 
     overlaps = bbox_overlaps(anchors, gt_boxes[:, :4])          # (N, G)
@@ -124,15 +138,18 @@ def assign_anchor(
 
     k_fg, k_bg = jax.random.split(key)
     num_fg = int(t.RPN_FG_FRACTION * t.RPN_BATCH_SIZE)
-    fg = _random_keep_k(k_fg, fg, num_fg)
-    bg = _random_keep_k(k_bg, bg, t.RPN_BATCH_SIZE - fg.sum())
+    fg = _random_keep_k(k_fg, fg, num_fg, num_fg)
+    bg = _random_keep_k(k_bg, bg, t.RPN_BATCH_SIZE - fg.sum(), t.RPN_BATCH_SIZE)
 
     labels = jnp.where(fg, 1, jnp.where(bg, 0, -1)).astype(jnp.int32)
 
-    targets = bbox_transform(anchors, gt_boxes[argmax_gt, :4])
-    targets = jnp.where(fg[:, None], targets, 0.0)
-    weights = jnp.where(
-        fg[:, None], jnp.asarray(t.RPN_BBOX_WEIGHTS, jnp.float32)[None, :], 0.0
+    # the matched gt box as four (N,) planes, selected over the G axis
+    # (exact: one value plus zeros), not a row gather into (N, 4)
+    gt_planes = [one_hot_select(gt_boxes[None, :, i], argmax_gt) for i in range(4)]
+    deltas = bbox_transform_planes((ax1, ay1, ax2, ay2), gt_planes)
+    targets = jnp.stack([jnp.where(fg, d, 0.0) for d in deltas], axis=1)
+    weights = jnp.stack(
+        [jnp.where(fg, wt, 0.0) for wt in t.RPN_BBOX_WEIGHTS], axis=1
     )
     return AnchorTargets(labels, targets.astype(jnp.float32), weights)
 
@@ -189,8 +206,8 @@ def sample_rois(
 
     k_fg, k_bg, k_tie = jax.random.split(key, 3)
     num_fg = int(round(t.FG_FRACTION * r_out))
-    fg_sel = _random_keep_k(k_fg, fg_cand, num_fg)
-    bg_sel = _random_keep_k(k_bg, bg_cand, r_out - fg_sel.sum())
+    fg_sel = _random_keep_k(k_fg, fg_cand, num_fg, num_fg)
+    bg_sel = _random_keep_k(k_bg, bg_cand, r_out - fg_sel.sum(), r_out)
 
     # pack: fg first, then bg, then ignore padding — fixed R_out rows.
     # LOAD-BEARING ordering: the Mask R-CNN branch (models/fpn.py::

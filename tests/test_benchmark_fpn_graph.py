@@ -16,3 +16,7 @@ import test_fpn_graph  # noqa: E402
 # (``roi_align_p3_device_ms.train``: the second streaming level); the set
 # the cases check is brought up to it here until such a PR edits the file.
 test_fpn_graph.FPN_METRICS.add("roi_align_p3_device_ms.train")
+# PR 31 adds ``anchor_targets_device_ms.train`` the same way, with no
+# ``workloads`` list (both model families open the scope), so it attaches
+# to every train cell like the accepted unlisted ``.train`` metrics.
+test_fpn_graph.UNLISTED_TRAIN.add("anchor_targets_device_ms.train")

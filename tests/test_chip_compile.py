@@ -15,9 +15,11 @@ a call at import would make the workers collect different tests.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -159,9 +161,9 @@ def test_pyramid_top_k_compiles_at_batch_one(one_chip):
     """The finest level's 152x256x3 anchor scores at per-chip batch 1: as
     ``[1, 116736]`` the chip's compiler aborts the PROCESS in its TopK
     emitter (ROADMAP R1; not tried here for that reason), so
-    ``models/fpn.py::per_image`` hands it the one image without the batch
+    ``models/layers.py::per_image`` hands it the one image without the batch
     axis.  Batch 2 goes through ``vmap`` as before."""
-    from mx_rcnn_tpu.models.fpn import per_image
+    from mx_rcnn_tpu.models.layers import per_image
 
     for batch in (1, 2):
         text = _compiled_text(
@@ -169,6 +171,68 @@ def test_pyramid_top_k_compiles_at_batch_one(one_chip):
             one_chip, ((batch, 116736), jnp.float32),
         )
         assert f"f32[{batch},2400]" in text  # it compiled, whole
+
+
+_HLO_ARRAY = re.compile(r"\b[a-z]+\d*\[([\d,]+)\]\{([\d,]+):T\(8,128\)")
+
+
+def _lane_padded(text: str, at_least: int):
+    """Arrays of ``at_least`` elements or more in ``T(8,128)`` tiling
+    whose MINOR dimension (the first of the layout's minor-to-major
+    list) is 1 or 4: the TPU pads that dimension to 128 lanes."""
+    found = set()
+    for dims, layout in _HLO_ARRAY.findall(text):
+        dims = [int(d) for d in dims.split(",")]
+        minor = dims[int(layout.split(",")[0])]
+        if minor in (1, 4) and np.prod(dims) >= at_least:
+            found.add((tuple(dims), layout))
+    return found
+
+
+@pytest.mark.parametrize("n", [
+    pytest.param(38 * 64 * 9, id="c4-21888"),
+    # the pyramid's five levels: a minute to compile here
+    pytest.param(155520, id="fpn-155520", marks=pytest.mark.slow),
+])
+def test_anchor_targets_are_dense_planes(one_chip, n):
+    """``vmap(assign_anchor)`` as the train cells call it (batch 8, 100
+    gt slots, N anchors): nothing of length N is sorted, no array of N
+    elements keeps a dimension of 1 or 4 on the lanes, and the program
+    needs next to no temporaries.  Before PR 31 it held, at C4's N, two
+    sorts of ``[8,21888]``, a row gather into ``f32[175104,4]`` padded to
+    128 lanes, four ``f32[8,21888,1]`` slices of it and 0.36 GB of
+    temporaries (3.19 GB at the pyramid's N)."""
+    from mx_rcnn_tpu.config import generate_config
+    from mx_rcnn_tpu.ops.targets import assign_anchor
+
+    cfg = generate_config("resnet", "PascalVOC")
+    batch, g = 8, 100
+
+    def targets(anchors, gt, gt_valid, im_info, keys):
+        return jax.vmap(
+            lambda gtb, gtv, info, k: assign_anchor(
+                anchors, gtb[:, :4], gtv, info, k, cfg)
+        )(gt, gt_valid, im_info, keys)
+
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in (
+            ((n, 4), jnp.float32), ((batch, g, 5), jnp.float32),
+            ((batch, g), jnp.bool_), ((batch, 3), jnp.float32),
+            ((batch, 2), jnp.uint32),
+        )
+    ]
+    compiled = jax.jit(targets).lower(*args).compile()
+    text = compiled.as_text()
+    sorted_lengths = [
+        int(np.prod([int(d) for d in dims.split(",")]))
+        for dims in re.findall(r"= \(?[a-z]+\d*\[([\d,]+)\][^=]* sort\(", text)
+    ]
+    assert all(length < n for length in sorted_lengths), sorted_lengths
+    assert text.count('custom_call_target="TopK"') == 2
+    assert " gather(" not in text
+    assert not _lane_padded(text, at_least=n)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
 @pytest.mark.parametrize("n,max_keep", [(12000, 2000), (6000, 300)],
