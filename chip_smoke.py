@@ -2,11 +2,11 @@
 """Chip smoke: the flagship trainer and the serving engine, through their
 normal entry points, once, on the TPU.
 
-    python chip_smoke.py               # one chip: kernels, train (C4 bf16 b8, f32 b1; pyramid bf16 b8, f32 b1), serve
+    python chip_smoke.py               # one chip: kernels, train (C4 bf16 b8, f32 b1; pyramid bf16 b8, f32 b1; VGG-16 bf16 b8), serve
     python chip_smoke.py --multichip   # four chips: DP train + DP-vs-single check
 
-Full width (ResNet-101 C4 and the ResNet-50 pyramid on the (608, 1024)
-bucket, the default serve ladder), random weights from a seed, synthetic
+Full width (ResNet-101 C4, the ResNet-50 pyramid and VGG-16 on the
+(608, 1024) bucket, the default serve ladder), random weights from a seed, synthetic
 data from a seed; depth of the RUN is cut (a handful of steps, 16 requests), not the model.  Every
 phase checks its own output by the repo's means — the kernels against
 their jnp/numpy references on a small input, the trainer's guard counters,
@@ -62,6 +62,14 @@ FPN_STEP_KERNELS = (
     "pallas_roi_features_stream_fwd", "pallas_roi_features_stream_bwd",
     "pallas_roi_features_fwd", "pallas_roi_features_bwd", "pallas_nms_mask",
 )
+#: the upstream's default recipe (Faster R-CNN VGG-16 on VOC) as the cell
+#: vgg_train_b8 runs it: ROI max pooling forward and differentiated, the
+#: fc6 / fc7 head with dropout
+TRAIN_VGG_BF16_ARGV = [
+    "--network", "vgg", "--dataset", "PascalVOC", "--synthetic", "64",
+    "--epochs", "1", "--frequent", "1", "--lr", "1e-05",
+    "--batch_images", "8", "--compute_dtype", "bfloat16", "--max_steps", "6",
+]
 #: tools/serve.py without --small: flagship, default ladder, f32
 SERVE_ARGV = [
     "--network", "resnet", "--max_batch", "4", "--requests", "16",
@@ -377,20 +385,21 @@ def _compiled_step_text(cli, found: dict):
     return make
 
 
-def train_phase(argv, name: str = "train", kernels=()):
+def train_phase(argv, name: str = "train", kernels=(), scopes=()):
     """``train_net`` on ``argv`` exactly as ``train_end2end.main`` calls
     it; fails unless every planned step was applied with a finite loss
     and the NaN guard never had to act (it would otherwise turn a broken
     step into a clean exit).  ``kernels``: names the compiled step must
     hold; with them the loss must also stay flat or fall (no loss over
     twice the first: the default LR's spike on these weights was 2000x).
-    → (final state, report)."""
+    ``scopes``: stage scopes some operation of the compiled step must lie
+    under (a component of its ``op_name``).  → (final state, report)."""
     from mx_rcnn_tpu.tools import train_end2end as cli
 
     args = cli.parse_args(argv)
     report: dict = {}
     compiled: dict = {}
-    make = _compiled_step_text(cli, compiled) if kernels else None
+    make = _compiled_step_text(cli, compiled) if kernels or scopes else None
     t0 = time.monotonic()
     try:
         state = cli.train_net(args, report=report)
@@ -398,11 +407,12 @@ def train_phase(argv, name: str = "train", kernels=()):
         if make is not None:
             cli.make_train_step = make
     wall = time.monotonic() - t0
-    if kernels and "text" not in compiled:
+    if make is not None and "text" not in compiled:
         raise RuntimeError(
             f"{name}: train_net did not build its step through "
             f"make_train_step (more than one device?)")
     held = {k: compiled["text"].count(f"%{k}") for k in kernels}
+    held.update({f"/{s}/": compiled["text"].count(f"/{s}/") for s in scopes})
     losses = [loss for _step, loss in report["losses"]]
     say(phase=name, wall_s=round(wall, 1), steps=report["steps"],
         steps_applied=report["steps_applied"], losses=losses,
@@ -708,6 +718,9 @@ def main(argv=None) -> int:
                   "train_fpn_bf16_b8", kernels=FPN_STEP_KERNELS)
             phase(train_phase, TRAIN_FPN_F32_ARGV + prefix("fpn_f32"),
                   "train_fpn_f32_b1")
+            phase(train_phase, TRAIN_VGG_BF16_ARGV + prefix("vgg_bf16"),
+                  "train_vgg_bf16_b8", kernels=("pallas_nms_mask",),
+                  scopes=("roi_pool",))
             phase(serve_phase, SERVE_ARGV, "serve")
 
     stats = devices[0].memory_stats() or {}

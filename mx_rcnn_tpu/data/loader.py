@@ -43,7 +43,7 @@ class LoaderFaultBudgetExceeded(RuntimeError):
 
 class _RenderLRU:
     """Locked LRU of rendered synthetic images, keyed by
-    ``(uri, flipped, seed)``.
+    ``(uri, flipped, seed, geometry)``.
 
     Bounds render-cache memory (~7 MB/entry at flagship size, cap via
     ``MX_RCNN_RENDER_CACHE``) while keeping the gate sets — which
@@ -131,6 +131,17 @@ def _prepared_key(rec: Dict, scales, bucket, uint8: bool, means, stds):
     return base + (tuple(scales), tuple(bucket), uint8, norm)
 
 
+def _render_geometry(rec: Dict) -> Tuple:
+    """What ``synthetic_image`` renders from besides the seed: the extent,
+    the boxes with their classes, and whether polygons shape them."""
+    return (
+        int(rec["height"]), int(rec["width"]),
+        np.asarray(rec["boxes"]).tobytes(),
+        np.asarray(rec["gt_classes"]).tobytes(),
+        rec.get("segmentation") is not None,
+    )
+
+
 def _load_record_image(rec: Dict) -> np.ndarray:
     if str(rec["image"]).startswith("synthetic://"):
         from mx_rcnn_tpu.data.synthetic import synthetic_image
@@ -144,8 +155,13 @@ def _load_record_image(rec: Dict) -> np.ndarray:
         # generation, one core) re-rendering was the e2e eval
         # bottleneck once the eval pipeline overlapped (disk-backed
         # datasets get the same effect from the OS page cache).
-        # Read-only downstream: prepare_image copies.
-        key = (rec["image"], bool(rec.get("flipped")), rec["synthetic_seed"])
+        # The uri names a record only within ITS dataset (every synthetic
+        # dataset counts from ``synthetic://0``), and the cache is the
+        # process's: the record's geometry is part of the key, or a second
+        # dataset of another size or other boxes is served the first's
+        # pixels.  Read-only downstream: prepare_image copies.
+        key = (rec["image"], bool(rec.get("flipped")), rec["synthetic_seed"],
+               _render_geometry(rec))
         im = _RENDER_CACHE.get(key)
         if im is None:
             im = synthetic_image(rec, rec["synthetic_seed"])

@@ -87,12 +87,16 @@ class FasterRCNN(nn.Module):
 
     def _roi_features(
         self, feat: jnp.ndarray, rois: jnp.ndarray, fwd_only: bool = False,
-        valid_hw=None,
+        valid_hw=None, sample_keys=None,
     ) -> jnp.ndarray:
-        """(B, Hf, Wf, C) × (B, R, 4) → (B*R, D) head trunk features."""
+        """(B, Hf, Wf, C) × (B, R, 4) → (B*R, D) head trunk features.
+        ``sample_keys`` (B,): the images' roi-sampling keys, given in
+        training: a head that drops units draws its masks from them."""
         net = self.cfg.network
-        # closes before top_head: the scope's device time is the pooling's
-        with jax.named_scope("roi_align"):
+        # closes before top_head: the scope's device time is the pooling's.
+        # Named after the mode (``roi_align`` | ``roi_pool``): a trace says
+        # which pooling it timed
+        with jax.named_scope(net.ROI_MODE):
             pooled = extract_roi_features_batched(
                 feat,
                 rois,
@@ -104,7 +108,9 @@ class FasterRCNN(nn.Module):
                 valid_hw=valid_hw,
             )
         b, r = pooled.shape[0], pooled.shape[1]
-        return self.top_head(pooled.reshape((b * r,) + pooled.shape[2:]))
+        return self.top_head(
+            pooled.reshape((b * r,) + pooled.shape[2:]), sample_keys
+        )
 
     def __call__(
         self,
@@ -188,7 +194,9 @@ class FasterRCNN(nn.Module):
         # --- second stage (the ROIAlign kernels stay innermost-scoped by
         # flax's ``FasterRCNN._roi_features``: the benchmark finds them so)
         with jax.named_scope("roi_head"):
-            trunk = self._roi_features(feat, samples.rois)     # (B*R, D)
+            trunk = self._roi_features(
+                feat, samples.rois, sample_keys=keys[:, 1]
+            )                                                   # (B*R, D)
             cls_logits, bbox_pred_out = self.rcnn(trunk)       # (B*R, K), (B*R, 4K)
 
         labels = samples.labels.reshape(-1)

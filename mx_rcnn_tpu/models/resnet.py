@@ -165,6 +165,9 @@ class ResNetTopHead(nn.Module):
 
     Reference: the post-ROIPooling conv5 + global-average-pool tail of
     ``rcnn/symbol/symbol_resnet.py :: get_resnet_train``.
+
+    ``drop_keys``: the top heads' one signature (``build_backbone``); this
+    head drops nothing and reads none.
     """
 
     depth: int = 101
@@ -172,7 +175,7 @@ class ResNetTopHead(nn.Module):
     fold_bn: bool = False
 
     @nn.compact
-    def __call__(self, rois_feat: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, rois_feat: jnp.ndarray, drop_keys=None) -> jnp.ndarray:
         blocks = _BLOCKS[self.depth]
         x = ResNetStage(512, blocks[3], 2, self.dtype,
                         fold_bn=self.fold_bn, name="stage4")(rois_feat)

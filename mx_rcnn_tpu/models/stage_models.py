@@ -48,7 +48,9 @@ def build_backbone(
 ) -> Tuple[nn.Module, nn.Module]:
     """(backbone, top_head) for the configured network — shared across
     FasterRCNN / RPNOnly / FastRCNN so param trees align for
-    ``combine_model``.
+    ``combine_model``.  Every top head is called ``head(pooled,
+    drop_keys=None)``: one key an image in training, for a head that drops
+    units.
 
     The backbone stops gradients at the contiguous-prefix boundary of
     the freeze set: those params get zero updates from the optimizer
@@ -188,8 +190,11 @@ class FastRCNN(nn.Module):
         self.rcnn = RCNNHead(num_classes=cfg.dataset.NUM_CLASSES, dtype=dtype)
 
     def _roi_features(
-        self, feat: jnp.ndarray, rois: jnp.ndarray, fwd_only: bool = False
+        self, feat: jnp.ndarray, rois: jnp.ndarray, fwd_only: bool = False,
+        sample_keys=None,
     ) -> jnp.ndarray:
+        """``sample_keys`` (B,): the images' roi-sampling keys, given in
+        training: a head that drops units draws its masks from them."""
         net = self.cfg.network
         pooled = extract_roi_features_batched(
             feat, rois, net.ROI_MODE, net.POOLED_SIZE,
@@ -197,7 +202,9 @@ class FastRCNN(nn.Module):
             fwd_only=fwd_only,
         )
         b, r = pooled.shape[0], pooled.shape[1]
-        return self.top_head(pooled.reshape((b * r,) + pooled.shape[2:]))
+        return self.top_head(
+            pooled.reshape((b * r,) + pooled.shape[2:]), sample_keys
+        )
 
     def __call__(
         self,
@@ -241,7 +248,7 @@ class FastRCNN(nn.Module):
             lambda r, rv, gtb, gtv, kk: sample_rois(r, rv, gtb, gtv, kk, cfg)
         )(proposals, prop_valid, gt_boxes, gt_valid, keys)
 
-        trunk = self._roi_features(feat, samples.rois)
+        trunk = self._roi_features(feat, samples.rois, sample_keys=keys)
         cls_logits, bbox_pred_out = self.rcnn(trunk)
         labels = samples.labels.reshape(-1)
         bbox_targets = samples.bbox_targets.reshape(bbox_pred_out.shape)

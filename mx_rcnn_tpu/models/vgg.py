@@ -57,15 +57,46 @@ class VGGBackbone(nn.Module):
         return x
 
 
+#: upstream's ``Dropout(p=0.5)`` after relu6 and relu7 (``drop6`` / ``drop7``)
+DROPOUT_RATE = 0.5
+#: folds the head's dropout stream out of an image's roi-sampling key
+DROP_STREAM = 0x64726F70  # "drop"
+
+
+def dropout_rows(x: jnp.ndarray, keys: jnp.ndarray, rate: float) -> jnp.ndarray:
+    """Inverted dropout (MXNet's ``Dropout``: kept units scaled by
+    ``1 / (1 - rate)``) on (B*R, D) rows held image by image, image ``i``
+    drawing its (R, D) mask from ``keys[i]`` alone: a row's mask then
+    depends on its image's key and not on the batch it sits in."""
+    b = keys.shape[0]
+    keep = jax.vmap(
+        lambda k: jax.random.bernoulli(k, 1.0 - rate, (x.shape[0] // b, x.shape[1]))
+    )(keys).reshape(x.shape)
+    return jnp.where(keep, x / (1.0 - rate), 0).astype(x.dtype)
+
+
 class VGGTopHead(nn.Module):
-    """fc6/fc7 on pooled rois: (R, 7, 7, 512) → (R, 4096)."""
+    """fc6/fc7 on pooled rois: (R, 7, 7, 512) → (R, 4096).
+
+    ``drop_keys`` (B,) = one key an image (its roi-sampling key: the head
+    folds a stream of its own out of it), the rows being B images' rois in
+    image order: training applies dropout 0.5 after each ReLU, as
+    ``get_vgg_train`` does, and a row's masks depend on (step rng, image
+    id) only; without keys (``test_forward``, serving) nothing is dropped.
+    """
 
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, rois_feat: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, rois_feat: jnp.ndarray, drop_keys=None) -> jnp.ndarray:
         x = rois_feat.reshape(rois_feat.shape[0], -1)
-        x = nn.Dense(4096, dtype=self.dtype, param_dtype=jnp.float32, name="fc6")(x)
-        x = nn.relu(x)
-        x = nn.Dense(4096, dtype=self.dtype, param_dtype=jnp.float32, name="fc7")(x)
-        return nn.relu(x)
+        if drop_keys is not None:
+            drop_keys = jax.vmap(
+                lambda k: jax.random.fold_in(k, DROP_STREAM))(drop_keys)
+        for i, name in enumerate(("fc6", "fc7")):
+            x = nn.Dense(4096, dtype=self.dtype, param_dtype=jnp.float32, name=name)(x)
+            x = nn.relu(x)
+            if drop_keys is not None:
+                keys = jax.vmap(lambda k: jax.random.fold_in(k, i))(drop_keys)
+                x = dropout_rows(x, keys, DROPOUT_RATE)
+        return x

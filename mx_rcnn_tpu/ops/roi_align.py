@@ -119,32 +119,44 @@ def roi_align(
     return out.reshape(-1, pooled[0], pooled[1], feat.shape[2])[:r]
 
 
+def _round_half_away(v):
+    """C's ``round`` (MXNet's): a half cell goes away from zero, where
+    ``jnp.round`` takes it to the even neighbour."""
+    t = jnp.trunc(v)
+    return t + jnp.where(jnp.abs(v - t) >= 0.5, jnp.sign(v), 0.0)
+
+
 def _maxpool_one_roi(feat, roi, pooled, spatial_scale, valid_hw=None):
     """Exact MXNet ROIPooling for one roi via masked-max contractions."""
     hf, wf = feat.shape[0], feat.shape[1]
     ph, pw = pooled
-    # quantized roi in feature cells (+1 width convention)
-    x1 = jnp.round(roi[0] * spatial_scale)
-    y1 = jnp.round(roi[1] * spatial_scale)
-    x2 = jnp.round(roi[2] * spatial_scale)
-    y2 = jnp.round(roi[3] * spatial_scale)
-    roi_w = jnp.maximum(x2 - x1 + 1.0, 1.0)
-    roi_h = jnp.maximum(y2 - y1 + 1.0, 1.0)
-    bin_w = roi_w / pw
-    bin_h = roi_h / ph
+    # quantized roi in feature cells (+1 width convention), as whole
+    # numbers: a bin's edges are then exact, where ``ceil(start + (p + 1)
+    # * (extent / ph))`` in float32 took one cell more on a sixth of the
+    # (start, extent) pairs (PERF.md, PR 33).  The bound keeps ``ph *
+    # extent`` inside int32 for any float a roi can hold
+    x1, y1, x2, y2 = (
+        jnp.clip(_round_half_away(roi[i] * spatial_scale),
+                 -(2.0 ** 24), 2.0 ** 24).astype(jnp.int32)
+        for i in range(4)
+    )
+    roi_w = jnp.maximum(x2 - x1 + 1, 1)
+    roi_h = jnp.maximum(y2 - y1 + 1, 1)
 
-    def bin_mask(start, bin_sz, nbins, size, lim):
-        # mask[b, i]: cell i belongs to bin b (floor/ceil edges, clipped
-        # to the valid feature extent so padded cells never win the max)
-        b = jnp.arange(nbins, dtype=jnp.float32)
-        lo = jnp.clip(jnp.floor(start + b * bin_sz), 0, lim)           # (nb,)
-        hi = jnp.clip(jnp.ceil(start + (b + 1.0) * bin_sz), 0, lim)
-        i = jnp.arange(size, dtype=jnp.float32)
+    def bin_mask(start, extent, nbins, size, lim):
+        # mask[b, i]: cell i belongs to bin b, which spans
+        # floor(b * extent / nbins) .. ceil((b + 1) * extent / nbins) from
+        # ``start``, clipped to the valid feature extent so padded cells
+        # never win the max
+        b = jnp.arange(nbins, dtype=jnp.int32)
+        lo = jnp.clip(start + (b * extent) // nbins, 0, lim)           # (nb,)
+        hi = jnp.clip(start - (-(b + 1) * extent) // nbins, 0, lim)
+        i = jnp.arange(size, dtype=jnp.int32)
         return (i[None, :] >= lo[:, None]) & (i[None, :] < hi[:, None])
 
-    (lh, _), (lw, _) = _feat_limits((hf, wf), valid_hw, spatial_scale)
-    mh = bin_mask(y1, bin_h, ph, hf, lh)   # (ph, H)
-    mw = bin_mask(x1, bin_w, pw, wf, lw)   # (pw, W)
+    (_, lh), (_, lw) = _feat_limits((hf, wf), valid_hw, spatial_scale)
+    mh = bin_mask(y1, roi_h, ph, hf, lh)   # (ph, H)
+    mw = bin_mask(x1, roi_w, pw, wf, lw)   # (pw, W)
 
     neg = jnp.finfo(feat.dtype).min
     # max over h per bin row, then over w per bin col

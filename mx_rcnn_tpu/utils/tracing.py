@@ -54,11 +54,15 @@ SERVE_SPANS = (
 # before the head's trunk (flax's ``<Model>._roi_features`` lies between).
 # A pyramid also has flax's ``neck``, and under ``roi_align`` one more
 # component a level, ``roi_align/p2`` .. ``roi_align/p5`` (models/fpn.py).
+# The pooling's scope is named after ``ROI_MODE``: under ROI max pooling
+# (VGG-16) the single-map graph opens ``roi_pool`` where ``roi_align``
+# stands here, and flax's ``top_head`` holds fc6 / fc7.
 TRAIN_SCOPES = (
     "backbone", "rpn", "anchor_targets", "proposal", "roi_sample",
     "roi_head", "roi_align", "losses", "update",
 )
 FPN_SCOPES = ("neck",) + tuple(f"roi_align/p{lv}" for lv in range(2, 6))
+ROI_POOL_SCOPES = ("roi_pool", "top_head")
 SERVE_SCOPES = (
     "postprocess/decode", "postprocess/class_nms", "postprocess/cap",
     "postprocess/mask_select", "postprocess/mask_paste",

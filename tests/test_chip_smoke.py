@@ -110,6 +110,26 @@ def test_pyramid_phases_ask_for_the_cell_s_configuration():
     assert chip_smoke.TRAIN_FPN_BF16_ARGV[:4] == cell_argv
 
 
+def test_vgg_phase_asks_for_the_cell_s_configuration():
+    """The VGG phase's argv through the CLI's own parser: the network and
+    dataset of ``frcnn_vgg16_voc``, batch 8 in bf16 as the cell
+    ``vgg_train_b8``, ROI max pooling, conv1-conv2 fixed."""
+    from mx_rcnn_tpu.tools import train_end2end as cli
+
+    args = cli.parse_args(chip_smoke.TRAIN_VGG_BF16_ARGV
+                          + ["--prefix", "/nowhere"])
+    cfg = cli.config_from_args(args)
+    assert cfg.network.name == "vgg" and cfg.network.ROI_MODE == "roi_pool"
+    assert cfg.network.FIXED_PARAMS == ("conv1", "conv2")
+    assert cfg.dataset.NUM_CLASSES == 21 and cfg.TRAIN.BATCH_IMAGES == 8
+    assert cfg.network.COMPUTE_DTYPE == "bfloat16"
+    assert args.max_steps == 6 and args.lr == 1e-05
+    with open(os.path.join(REPO_ROOT, "benchmark", "configs",
+                           "frcnn_vgg16_voc.json")) as f:
+        cell_argv = json.load(f)["train_argv"]
+    assert chip_smoke.TRAIN_VGG_BF16_ARGV[:4] == cell_argv
+
+
 def test_streaming_train_shapes_are_the_pyramid_s_p2_and_p3():
     from mx_rcnn_tpu.ops.pallas.roi_align import fits_vmem
 

@@ -137,6 +137,29 @@ def test_prefetch_iter_propagates_worker_exception():
         list(_prefetch_iter(source(), prefetch=0))
 
 
+def test_synthetic_render_cache_tells_two_datasets_apart():
+    """Every synthetic dataset names its records ``synthetic://0`` .. with
+    the same seeds, and the render LRU is the process's: a second dataset
+    of another extent (or other boxes) must not be served the first's
+    pixels (what an earlier test in the same worker left in the cache made
+    ``test_synthetic_render_cache_is_flip_safe`` fail in the whole run)."""
+    from mx_rcnn_tpu.data.loader import _load_record_image
+    from mx_rcnn_tpu.data.synthetic import synthetic_image
+
+    first = SyntheticDataset(num_images=2, num_classes=4,
+                             image_size=(128, 176), max_boxes=2).gt_roidb()
+    for rec in first:
+        _load_record_image(rec)                                   # caches
+    for size, classes in (((160, 128), 4), ((128, 176), 7)):
+        other = SyntheticDataset(num_images=2, num_classes=classes,
+                                 image_size=size, max_boxes=3).gt_roidb()
+        assert [r["image"] for r in other] == [r["image"] for r in first]
+        for rec in other:
+            np.testing.assert_array_equal(
+                _load_record_image(rec),
+                synthetic_image(rec, rec["synthetic_seed"]))
+
+
 def test_synthetic_render_cache_is_flip_safe():
     """A flipped twin shallow-copies its source record; the render LRU
     keys on (uri, flipped, seed), so the twin must MISS the unflipped

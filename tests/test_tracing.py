@@ -271,12 +271,16 @@ def _scoped_names(lowered_text):
 def lowered_train_step():
     """The tiny train step lowered FOR THE TPU (no compile, no chip), with
     the Pallas kernels in: the text the chip's compiler would be given."""
+    return _lowered_step(_tiny_generate_config("resnet50", "PascalVOC"))
+
+
+def _lowered_step(cfg):
+    """``cfg``'s train step lowered FOR THE TPU (no compile, no chip)."""
     from mx_rcnn_tpu.core.train import (
         create_train_state, make_lr_schedule, make_optimizer, make_train_step,
     )
     from mx_rcnn_tpu.models import build_model
 
-    cfg = _tiny_generate_config("resnet50", "PascalVOC")
     model = build_model(cfg)
     h, w = cfg.SHAPE_BUCKETS[0]
     g = cfg.dataset.MAX_GT_BOXES
@@ -300,6 +304,27 @@ def lowered_train_step():
         return lowered.as_text(debug_info=True)
     finally:
         mp.undo()
+
+
+def test_roi_max_pooling_opens_its_own_scope_and_no_roi_align():
+    """Under ``ROI_MODE`` ``roi_pool`` (VGG-16) the pooling's scope says
+    what it is: ``roi_pool`` inside ``roi_head``, closed before flax's ``top_head`` (fc6 / fc7), and nothing under the
+    name ``roi_align``, which every accepted metric file reads as
+    ROIAlign.  ``roi_pool_device_ms.train``, ``roi_pool_roofline.vgg_train``
+    and ``top_head_device_ms.train`` read these two components."""
+    names = _scoped_names(_lowered_step(_tiny_generate_config(
+        "vgg", "PascalVOC")))
+    for scope in tracing.ROI_POOL_SCOPES:
+        assert any(f"/{scope}/" in n + "/" for n in names), scope
+    assert not any("/roi_align/" in n + "/" for n in names)
+    under = [n for n in names if "/roi_pool/" in n + "/"]
+    assert all("/roi_head/" in n for n in under)
+    assert not any("/top_head/" in n + "/" for n in under)
+    for name in ("roi_pool_device_ms.train", "roi_pool_roofline.vgg_train",
+                 "top_head_device_ms.train"):
+        with open(os.path.join(REPO_ROOT, "benchmark", "metrics",
+                               name + ".json")) as f:
+            assert json.load(f)["args"]["scope"] in tracing.ROI_POOL_SCOPES
 
 
 @pytest.mark.parametrize("scope", tracing.TRAIN_SCOPES)
