@@ -70,6 +70,11 @@ TRAIN_VGG_BF16_ARGV = [
     "--epochs", "1", "--frequent", "1", "--lr", "1e-05",
     "--batch_images", "8", "--compute_dtype", "bfloat16", "--max_steps", "6",
 ]
+#: Pallas kernels the compiled VGG step must hold: the proposal NMS and
+#: the ROI max pooling pair (no ``while`` sweep in their place)
+VGG_STEP_KERNELS = (
+    "pallas_nms_mask", "pallas_roi_pool_fwd", "pallas_roi_pool_bwd",
+)
 #: tools/serve.py without --small: flagship, default ladder, f32
 SERVE_ARGV = [
     "--network", "resnet", "--max_batch", "4", "--requests", "16",
@@ -186,6 +191,13 @@ STREAM_TRAIN_MAPS = (
 )
 
 
+#: the map the VGG train step hands the ROI max pooling pair (cell
+#: vgg_train_b8): conv5_3 of eight (608, 1024) images, 128 rois an image,
+#: 7x7; under the interpreter a map small enough for it
+ROI_POOL_TRAIN_MAP = (8, 38, 64, 512)
+ROI_POOL_TINY_MAP = (2, 12, 20, 128)
+
+
 def _level_rois(rng, b, r, h_img, w_img, stride):
     """(B, R, 4) boxes of the level that pools them at ``stride``: sides up
     to 28 cells (eq. 1 hands P2 the rois under 112 pixels, P3 those under
@@ -206,7 +218,10 @@ def kernels_phase(interpret: bool = False, name: str = "kernels",
     kernels' arithmetic in interpret mode; what Mosaic compiled from them
     only a chip can check.  ROIAlign (resident and streaming, forward and
     backward; the resident forward with ``valid_hw`` as serving calls it)
-    against the gather reference ``ops.roi_align.roi_align``;
+    against the gather reference ``ops.roi_align.roi_align``; ROI max
+    pooling (forward and backward, at the VGG train step's shape on the
+    chip) against the jnp sweep ``ops.roi_align.roi_pool``, EQUAL on a map
+    no two cells of which tie;
     NMS against the numpy oracle ``ops.nms.nms_numpy``, on boxes chosen
     so that no pair sits within 1e-4 of the IoU threshold.
     On the chip (not under the interpreter, where they would take many
@@ -224,7 +239,8 @@ def kernels_phase(interpret: bool = False, name: str = "kernels",
     from mx_rcnn_tpu.ops.pallas.nms import nms_mask_sorted_pallas
     from mx_rcnn_tpu.ops.pallas.roi_align import roi_align_pallas
     from mx_rcnn_tpu.ops.pallas.roi_align_stream import roi_align_stream
-    from mx_rcnn_tpu.ops.roi_align import roi_align
+    from mx_rcnn_tpu.ops.pallas.roi_pool import roi_pool_pallas
+    from mx_rcnn_tpu.ops.roi_align import roi_align, roi_pool
 
     rng = np.random.RandomState(0)
     errs = {}
@@ -323,6 +339,27 @@ def kernels_phase(interpret: bool = False, name: str = "kernels",
             errs[f"{tag}_valid_hw_{dtype}_fwd"] = rel(
                 np.asarray(clamped(f), np.float32), ref_out)
 
+    # ROI max pooling: a permutation of whole numbers (exact in f32, no
+    # two cells tie, so MXNet's first-cell rule and the sweep's shared
+    # gradient name the same cell) and whole-number cotangents (exact
+    # sums): the pair equals the sweep, one image after the other
+    shape = ROI_POOL_TINY_MAP if interpret else ROI_POOL_TRAIN_MAP
+    pool_rng = np.random.RandomState(2)
+    feat = jnp.asarray(pool_rng.permutation(int(np.prod(shape))).reshape(
+        shape).astype(np.float32))
+    rois = jnp.asarray(_random_rois(
+        pool_rng, shape[0], 128, shape[1] * 16, shape[2] * 16))
+    cot = jnp.asarray(pool_rng.randint(
+        1, 9, (shape[0], 128, 7, 7, shape[3])).astype(np.float32))
+    ref_out, ref_grad = fwd_bwd(lambda f, r: jax.lax.map(
+        lambda fr: roi_pool(fr[0], fr[1], (7, 7), 1 / 16), (f, r)
+    ))(feat, rois, cot)
+    out, grad = fwd_bwd(lambda f, r: roi_pool_pallas(
+        f, r, (7, 7), 1 / 16, interpret))(feat, rois, cot)
+    exact = {"roi_pool_f32_fwd": rel(out, ref_out),
+             "roi_pool_f32_bwd": rel(grad, ref_grad)}
+    errs.update(exact)
+
     # NMS: a dense field of boxes, score-sorted as the proposal path hands
     # them over
     n, thresh = 2048, 0.7
@@ -344,6 +381,8 @@ def kernels_phase(interpret: bool = False, name: str = "kernels",
         f32_rtol=KERNEL_F32_RTOL, bf16_rtol=KERNEL_BF16_RTOL)
     for key, err in errs.items():
         tol = KERNEL_BF16_RTOL if "bf16" in key else KERNEL_F32_RTOL
+        if key in exact:
+            tol = 0.0
         if not err <= tol:  # also catches NaN
             raise RuntimeError(f"{name}: {key} off by {err:.3g} > {tol}")
     if nms_diff:
@@ -719,7 +758,7 @@ def main(argv=None) -> int:
             phase(train_phase, TRAIN_FPN_F32_ARGV + prefix("fpn_f32"),
                   "train_fpn_f32_b1")
             phase(train_phase, TRAIN_VGG_BF16_ARGV + prefix("vgg_bf16"),
-                  "train_vgg_bf16_b8", kernels=("pallas_nms_mask",),
+                  "train_vgg_bf16_b8", kernels=VGG_STEP_KERNELS,
                   scopes=("roi_pool",))
             phase(serve_phase, SERVE_ARGV, "serve")
 

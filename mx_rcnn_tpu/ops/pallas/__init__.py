@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (ROIAlign resident + streaming, NMS)."""
+"""Pallas TPU kernels (ROIAlign resident + streaming, ROI max pooling, NMS)."""
 
 import jax
 

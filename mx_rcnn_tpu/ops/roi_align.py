@@ -9,14 +9,17 @@ behind one signature:
   (align_corners=False convention, `sample_ratio`² points per bin,
   averaged).  Differentiable by construction (pure gather + arithmetic;
   XLA derives the scatter-add backward automatically — no hand-written
-  ``custom_vjp`` needed for correctness; the Pallas kernel in
-  ``ops/pallas/`` is the perf path).
+  ``custom_vjp`` needed for correctness; the Pallas kernels in
+  ``ops/pallas/roi_align*.py`` are the perf path).
 - :func:`roi_pool` — exact MXNet ROIPooling semantics: rois quantized by
-  ``round(x * scale)``, bin edges floor/ceil, max over each bin, computed
-  as two masked-max contractions (no data-dependent shapes).
+  C's ``round(x * scale)``, bin edges floor/ceil in whole numbers, max
+  over each bin, computed as two masked-max contractions (no
+  data-dependent shapes).  The oracle of, and the path without a TPU
+  beside, the Pallas pair in ``ops/pallas/roi_pool.py``.
 
-Both are chunked with ``lax.map`` over rois to bound the gather
-intermediates in HBM (R×grid×W×C blow-up otherwise).
+Both jnp forms are chunked with ``lax.map`` over rois to bound their
+intermediates in HBM (R×grid×W×C blow-up otherwise);
+:func:`extract_roi_features_batched` is where a batch picks a kernel.
 """
 
 from __future__ import annotations
@@ -126,9 +129,13 @@ def _round_half_away(v):
     return t + jnp.where(jnp.abs(v - t) >= 0.5, jnp.sign(v), 0.0)
 
 
-def _maxpool_one_roi(feat, roi, pooled, spatial_scale, valid_hw=None):
-    """Exact MXNet ROIPooling for one roi via masked-max contractions."""
-    hf, wf = feat.shape[0], feat.shape[1]
+def _bin_edges(roi, pooled, spatial_scale, feat_hw, valid_hw=None):
+    """MXNet ROIPooling's bins for one roi, in whole numbers: →
+    ``(hlo, hhi, wlo, whi)``, int32 ``(ph,)`` / ``(pw,)`` vectors; bin
+    ``p`` reads the cells ``lo[p] <= i < hi[p]`` (none where ``hi <= lo``).
+    The one place the edges are computed: the masked sweep below and the
+    Pallas pair (``ops/pallas/roi_pool.py``, which takes them as scalars)
+    both read them from here."""
     ph, pw = pooled
     # quantized roi in feature cells (+1 width convention), as whole
     # numbers: a bin's edges are then exact, where ``ceil(start + (p + 1)
@@ -143,20 +150,32 @@ def _maxpool_one_roi(feat, roi, pooled, spatial_scale, valid_hw=None):
     roi_w = jnp.maximum(x2 - x1 + 1, 1)
     roi_h = jnp.maximum(y2 - y1 + 1, 1)
 
-    def bin_mask(start, extent, nbins, size, lim):
-        # mask[b, i]: cell i belongs to bin b, which spans
-        # floor(b * extent / nbins) .. ceil((b + 1) * extent / nbins) from
-        # ``start``, clipped to the valid feature extent so padded cells
-        # never win the max
+    def edges(start, extent, nbins, lim):
+        # bin b spans floor(b * extent / nbins) .. ceil((b + 1) * extent /
+        # nbins) from ``start``, clipped to the valid feature extent so
+        # padded cells never win the max
         b = jnp.arange(nbins, dtype=jnp.int32)
         lo = jnp.clip(start + (b * extent) // nbins, 0, lim)           # (nb,)
         hi = jnp.clip(start - (-(b + 1) * extent) // nbins, 0, lim)
+        return lo, hi
+
+    (_, lh), (_, lw) = _feat_limits(feat_hw, valid_hw, spatial_scale)
+    return edges(y1, roi_h, ph, lh) + edges(x1, roi_w, pw, lw)
+
+
+def _maxpool_one_roi(feat, roi, pooled, spatial_scale, valid_hw=None):
+    """Exact MXNet ROIPooling for one roi via masked-max contractions."""
+    hf, wf = feat.shape[0], feat.shape[1]
+    hlo, hhi, wlo, whi = _bin_edges(roi, pooled, spatial_scale, (hf, wf),
+                                    valid_hw)
+
+    def bin_mask(lo, hi, size):
+        # mask[b, i]: cell i belongs to bin b
         i = jnp.arange(size, dtype=jnp.int32)
         return (i[None, :] >= lo[:, None]) & (i[None, :] < hi[:, None])
 
-    (_, lh), (_, lw) = _feat_limits((hf, wf), valid_hw, spatial_scale)
-    mh = bin_mask(y1, roi_h, ph, hf, lh)   # (ph, H)
-    mw = bin_mask(x1, roi_w, pw, wf, lw)   # (pw, W)
+    mh = bin_mask(hlo, hhi, hf)   # (ph, H)
+    mw = bin_mask(wlo, whi, wf)   # (pw, W)
 
     neg = jnp.finfo(feat.dtype).min
     # max over h per bin row, then over w per bin col
@@ -182,11 +201,12 @@ def roi_pool(
     chunked roi holds ~17 MB, so chunk=4 keeps the scan body ~70 MB.
     The body is rematerialized (jax.checkpoint): reverse-mode through
     lax.map otherwise SAVES each iteration's masked intermediate as a
-    scan residual — the full (chunks, chunk, ph, H, W, C) tensor,
-    16.6 GB at flagship across a batch of 8 (observed HBM OOM).
-    Callers must also not vmap over the batch dim (vmap batches the
-    scan body the same way); extract_roi_features_batched runs a
-    sequential batch loop for roi_pool."""
+    scan residual, the full (chunks, chunk, ph, H, W, C) tensor.
+    Callers must also not vmap over the batch dim when they
+    differentiate (vmap batches the scan body the same way):
+    extract_roi_features_batched runs a sequential batch loop then.  On
+    a TPU the batch goes to ``ops/pallas/roi_pool.py`` instead; this
+    sweep is the path where there is none, and the kernels' oracle."""
     r = rois.shape[0]
     pad = (-r) % chunk
     rois_p = jnp.concatenate([rois, jnp.zeros((pad, 4), rois.dtype)], axis=0)
@@ -246,6 +266,19 @@ def roi_align_kernel(feat, pooled, fwd_only: bool = False, valid_hw=None):
     return None
 
 
+def roi_pool_kernel(feat, pooled) -> bool:
+    """Whether the Pallas ROI max pooling pair (``ops/pallas/roi_pool.py``)
+    pools this (B, H, W, C) map: on a TPU, and the map within the pair's
+    own VMEM bound.  Elsewhere the jnp sweep does."""
+    from mx_rcnn_tpu.ops.pallas.roi_pool import fits_vmem
+    from mx_rcnn_tpu.utils.platform import use_pallas
+
+    return use_pallas() and fits_vmem(
+        feat.shape[1], feat.shape[2], feat.shape[3], pooled,
+        feat.dtype.itemsize,
+    )
+
+
 def extract_roi_features_batched(
     feat: jnp.ndarray,
     rois: jnp.ndarray,
@@ -259,9 +292,11 @@ def extract_roi_features_batched(
 ) -> jnp.ndarray:
     """(B, H, W, C) × (B, R, 4) → (B, R, ph, pw, C).
 
-    On TPU backends the roi_align path uses the Pallas MXU kernel
-    (``ops/pallas/roi_align.py``); elsewhere (and for roi_pool) the
-    chunked-gather jnp implementations under vmap.
+    On TPU backends the roi_align path uses the Pallas MXU kernels
+    (``ops/pallas/roi_align.py``, ``roi_align_stream.py``) and the
+    roi_pool path the Pallas pair of ``ops/pallas/roi_pool.py``, forward
+    and differentiated, with or without ``valid_hw``; elsewhere the
+    chunked jnp implementations.
 
     ``fwd_only``: callers that never differentiate this op (eval /
     test_forward) should set it.  For over-VMEM maps the streaming
@@ -306,15 +341,19 @@ def extract_roi_features_batched(
         return roi_align_stream(
             feat, rois, pooled, spatial_scale, sample_ratio, span=span
         )
+    if mode == "roi_pool" and roi_pool_kernel(feat, pooled):
+        from mx_rcnn_tpu.ops.pallas.roi_pool import roi_pool_pallas
+
+        return roi_pool_pallas(
+            feat, rois, pooled, spatial_scale, valid_hw=valid_hw
+        )
     if mode == "roi_pool" and not fwd_only:
-        # SEQUENTIAL over the batch: differentiating roi_pool's chunked
-        # masked-max under vmap saves every chunk's intermediate as a
-        # batched scan residual — one (chunks, B, chunk, ph, H, W, C)
-        # allocation, 16.6 GB at the flagship VGG shape (observed HBM
-        # OOM).  lax.map keeps one image's chunk live at a time.
-        # Forward-only graphs (eval) have no residuals, so they fall
-        # through to the batch-parallel vmap below: only one chunk's
-        # live body exists at a time (~0.5 GB at flagship).
+        # The differentiated pooling where no TPU is (or a map over the
+        # kernels' VMEM bound).  SEQUENTIAL over the batch: under vmap
+        # the chunked masked-max's remat body is batched, and its live
+        # intermediate with it; lax.map keeps one image's chunk live at
+        # a time.  Forward-only graphs (eval) fall through to the
+        # batch-parallel vmap below.
         if valid_hw is None:
             return jax.lax.map(
                 lambda fr: extract_roi_features(

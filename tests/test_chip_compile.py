@@ -1,8 +1,8 @@
 """The main path's Pallas kernels, compiled by the TPU's own compiler at
 real widths — for a v5e that is DESCRIBED, not attached.
 
-Interpret mode (tests/test_pallas_roi_align.py, tests/test_pallas_nms.py)
-checks what the kernels compute; it cannot see what Mosaic refuses: a
+Interpret mode (tests/test_pallas_roi_align.py, tests/test_pallas_roi_pool.py,
+tests/test_pallas_nms.py) checks what the kernels compute; it cannot see what Mosaic refuses: a
 block over the scoped-VMEM limit, an unaligned slice, a kernel that does
 not fit.  These compiles can, in about two seconds each and with no chip
 time (ISSUE 21: the f32 resident backward had passed every interpret
@@ -27,6 +27,7 @@ from mx_rcnn_tpu.ops.nms import batched_class_nms
 from mx_rcnn_tpu.ops.pallas.nms import nms_mask_sorted_pallas
 from mx_rcnn_tpu.ops.pallas.roi_align import fits_vmem, roi_align_pallas
 from mx_rcnn_tpu.ops.pallas.roi_align_stream import roi_align_stream
+from mx_rcnn_tpu.ops.pallas.roi_pool import roi_pool_pallas
 
 
 @pytest.fixture(scope="module")
@@ -155,6 +156,89 @@ def test_roi_align_serve_valid_hw_compiles(one_chip, dtype):
     )
     assert "pallas_roi_features_fwd" in text
     assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_roi_pool_fwd_bwd_compiles(one_chip, dtype):
+    """The ROI max pooling pair at the VGG train step's shape (cell
+    ``vgg_train_b8``): conv5_3 of eight 608x1024 images, 128 rois each.
+    Loops with the bins' bounds, dynamic row indices into the resident
+    map and an int32 output are what Mosaic could refuse."""
+    def fwd_bwd(f, rois):
+        def loss(x):
+            out = roi_pool_pallas(x, rois, (7, 7), 1 / 16)
+            return (out.astype(jnp.float32) ** 2).sum()
+
+        return jax.value_and_grad(loss)(f)
+
+    text = _compiled_text(
+        fwd_bwd, one_chip,
+        ((8, 38, 64, 512), dtype), ((8, 128, 4), jnp.float32),
+    )
+    assert "pallas_roi_pool_fwd" in text and "pallas_roi_pool_bwd" in text
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_roi_pool_valid_hw_compiles(one_chip, dtype):
+    """The forward as ``test_forward`` hands it over: the map padded to the
+    ladder's extent, 300 rois an image (a count the roi block does not
+    divide), every image's valid extent in the edges."""
+    text = _compiled_text(
+        lambda f, rois, valid_hw: roi_pool_pallas(
+            f, rois, (7, 7), 1 / 16, valid_hw=valid_hw
+        ),
+        one_chip, ((8, 64, 64, 512), dtype), ((8, 300, 4), jnp.float32),
+        ((8, 2), jnp.float32),
+    )
+    assert "pallas_roi_pool_fwd" in text
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_vgg_step_holds_the_roi_pool_pair_and_no_sweep(one_chip, monkeypatch):
+    """The bf16 batch-8 VGG-16 train step (cell ``vgg_train_b8``) compiled
+    whole: the pooling is the two kernels, under the ``roi_pool`` scope
+    both ways, and none of the four ``while`` loops the jnp sweep was is
+    left under it.  ``use_pallas`` asks the backend, which is the CPU
+    here, so the test steers it."""
+    from mx_rcnn_tpu.core.train import (
+        create_train_state, make_lr_schedule, make_optimizer, make_train_step,
+    )
+    from mx_rcnn_tpu.models import build_model
+    from mx_rcnn_tpu.tools import train_end2end as cli
+
+    monkeypatch.setenv("MX_RCNN_TPU_PALLAS", "1")
+    cfg = cli.config_from_args(cli.parse_args([
+        "--network", "vgg", "--dataset", "PascalVOC", "--synthetic", "64",
+        "--batch_images", "8", "--compute_dtype", "bfloat16",
+        "--prefix", "/nowhere"]))
+    model = build_model(cfg)
+    b, (h, w), g = 8, cfg.SHAPE_BUCKETS[0], cfg.dataset.MAX_GT_BOXES
+    batch = {
+        "images": jnp.zeros((b, h, w, 3)),
+        "im_info": jnp.tile(jnp.array([[h, w, 1.0]]), (b, 1)),
+        "gt_boxes": jnp.zeros((b, g, 5)),
+        "gt_valid": jnp.zeros((b, g), bool),
+    }
+    params = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.key(0), "sampling": jax.random.key(1)},
+        train=True, **batch)["params"])
+    tx = make_optimizer(cfg, make_lr_schedule(cfg, 10))
+    state = jax.eval_shape(lambda p: create_train_state(p, tx), params)
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (state, batch, jax.eval_shape(lambda: jax.random.key(2))))
+    text = make_train_step(model, tx).lower(*args).compile().as_text()
+    for kernel in ("pallas_roi_pool_fwd", "pallas_roi_pool_bwd"):
+        assert re.search(
+            rf"%{kernel}[.\d]* = .*op_name=\"[^\"]*/roi_pool/", text), kernel
+    # the sweep was four: ``%while.66 = (s32[], bf16[8,38,64,512], ...)
+    # while(...), ... op_name=".../roi_pool/while"``
+    loops = [line[:80] for line in text.splitlines()
+             if " while(" in line and "/roi_pool/" in line]
+    assert not loops, loops
 
 
 def test_pyramid_top_k_compiles_at_batch_one(one_chip):

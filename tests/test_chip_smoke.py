@@ -72,8 +72,11 @@ def test_kernels_phase_in_interpret_mode():
         f"{kernel}_{dtype}_{pass_}"
         for kernel in ("resident", "stream")
         for dtype in ("f32", "bf16") for pass_ in ("fwd", "bwd")
-    } | {f"resident_valid_hw_{dtype}_fwd" for dtype in ("f32", "bf16")}
+    } | {f"resident_valid_hw_{dtype}_fwd" for dtype in ("f32", "bf16")} | {
+        "roi_pool_f32_fwd", "roi_pool_f32_bwd"}
     assert max(v for k, v in errs.items() if "f32" in k) < 1e-5
+    # ROI max pooling against the sweep: equal, not close
+    assert errs["roi_pool_f32_fwd"] == errs["roi_pool_f32_bwd"] == 0.0
 
 
 def test_kernels_phase_span_cases_in_interpret_mode():
@@ -128,6 +131,18 @@ def test_vgg_phase_asks_for_the_cell_s_configuration():
                            "frcnn_vgg16_voc.json")) as f:
         cell_argv = json.load(f)["train_argv"]
     assert chip_smoke.TRAIN_VGG_BF16_ARGV[:4] == cell_argv
+    # what the compiled step must hold: the NMS and the pooling's pair
+    assert chip_smoke.VGG_STEP_KERNELS == (
+        "pallas_nms_mask", "pallas_roi_pool_fwd", "pallas_roi_pool_bwd")
+
+
+def test_roi_pool_kernel_case_is_the_vgg_cell_s_shape():
+    from mx_rcnn_tpu.ops.pallas.roi_pool import fits_vmem
+
+    b, h, w, c = chip_smoke.ROI_POOL_TRAIN_MAP
+    assert (b, h * 16, w * 16, c) == (8, 608, 1024, 512)
+    for shape in (chip_smoke.ROI_POOL_TRAIN_MAP, chip_smoke.ROI_POOL_TINY_MAP):
+        assert fits_vmem(*shape[1:], (7, 7), 4)
 
 
 def test_streaming_train_shapes_are_the_pyramid_s_p2_and_p3():
