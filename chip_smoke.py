@@ -2,12 +2,12 @@
 """Chip smoke: the flagship trainer and the serving engine, through their
 normal entry points, once, on the TPU.
 
-    python chip_smoke.py               # one chip: kernels, train (C4 bf16 b8, f32 b1; pyramid bf16 b8, f32 b1; VGG-16 bf16 b8), serve
+    python chip_smoke.py               # one chip: kernels, train (C4 bf16 b8, f32 b1; pyramid bf16 b8, f32 b1; VGG-16 bf16 b8; Deformable ConvNets bf16 b8), serve
     python chip_smoke.py --multichip   # four chips: DP train + DP-vs-single check
 
-Full width (ResNet-101 C4, the ResNet-50 pyramid and VGG-16 on the
-(608, 1024) bucket, the default serve ladder), random weights from a seed, synthetic
-data from a seed; depth of the RUN is cut (a handful of steps, 16 requests), not the model.  Every
+Full width (ResNet-101 C4, the ResNet-50 pyramid, VGG-16 and the
+Deformable ConvNets detector on the (608, 1024) bucket, the default serve
+ladder), random weights from a seed, synthetic data from a seed; depth of the RUN is cut (a handful of steps, 16 requests), not the model.  Every
 phase checks its own output by the repo's means — the kernels against
 their jnp/numpy references on a small input, the trainer's guard counters,
 the engine's snapshot, the compile-cache audit — and raises on the first
@@ -75,6 +75,14 @@ TRAIN_VGG_BF16_ARGV = [
 VGG_STEP_KERNELS = (
     "pallas_nms_mask", "pallas_roi_pool_fwd", "pallas_roi_pool_bwd",
 )
+#: Deformable ConvNets (Faster R-CNN ResNet-101 on VOC) as the cell
+#: dcn_train_b8 runs it: the deformable conv5 and the deformable ROI
+#: pooling, forward and differentiated, in plain jnp
+TRAIN_DCN_BF16_ARGV = [
+    "--network", "resnet_dcn", "--dataset", "PascalVOC", "--synthetic", "64",
+    "--epochs", "1", "--frequent", "1", "--lr", "1e-05",
+    "--batch_images", "8", "--compute_dtype", "bfloat16", "--max_steps", "6",
+]
 #: tools/serve.py without --small: flagship, default ladder, f32
 SERVE_ARGV = [
     "--network", "resnet", "--max_batch", "4", "--requests", "16",
@@ -453,12 +461,18 @@ def train_phase(argv, name: str = "train", kernels=(), scopes=()):
     held = {k: compiled["text"].count(f"%{k}") for k in kernels}
     held.update({f"/{s}/": compiled["text"].count(f"/{s}/") for s in scopes})
     losses = [loss for _step, loss in report["losses"]]
+    deform = report.get("deform") or {}
     say(phase=name, wall_s=round(wall, 1), steps=report["steps"],
         steps_applied=report["steps_applied"], losses=losses,
         skipped_batches=report["skipped_batches"],
         retried_steps=report["retried_steps"],
         rollbacks=report["rollbacks"],
-        roi_levels=report.get("roi_levels"), kernels=held)
+        roi_levels=report.get("roi_levels"), kernels=held,
+        # Deformable ConvNets: each deformable layer's sampling points
+        # inside the map, as a share
+        deform_inside={k[len("deform_inside_"):]: v / deform["deform_points"]
+                       for k, v in deform.items()
+                       if k.startswith("deform_inside_")})
     if not all(held.values()):
         raise RuntimeError(f"{name}: compiled step holds {held}")
     if kernels and not max(losses) <= 2 * losses[0]:
@@ -760,6 +774,9 @@ def main(argv=None) -> int:
             phase(train_phase, TRAIN_VGG_BF16_ARGV + prefix("vgg_bf16"),
                   "train_vgg_bf16_b8", kernels=VGG_STEP_KERNELS,
                   scopes=("roi_pool",))
+            phase(train_phase, TRAIN_DCN_BF16_ARGV + prefix("dcn_bf16"),
+                  "train_dcn_bf16_b8", kernels=("pallas_nms_mask",),
+                  scopes=("deform_conv", "deform_roi_pool"))
             phase(serve_phase, SERVE_ARGV, "serve")
 
     stats = devices[0].memory_stats() or {}

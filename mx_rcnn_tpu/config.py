@@ -138,8 +138,10 @@ class NetworkConfig:
     ANCHOR_SCALES: Tuple[int, ...] = (8, 16, 32)
     ANCHOR_RATIOS: Tuple[float, ...] = (0.5, 1.0, 2.0)
     NUM_ANCHORS: int = 9
-    # ROI feature extraction: 'roi_align' (TPU-native default) or 'roi_pool'
-    # compat mode matching MXNet ROIPooling max-pool semantics
+    # ROI feature extraction: 'roi_align' (TPU-native default), 'roi_pool'
+    # compat mode matching MXNet ROIPooling max-pool semantics, or
+    # 'deform_roi_pool' (Deformable ConvNets, MXNet's DeformablePSROIPooling
+    # at group_size 1; ROI_SAMPLE_RATIO is its sample_per_part)
     ROI_MODE: str = "roi_align"
     POOLED_SIZE: Tuple[int, int] = (14, 14)
     ROI_SAMPLE_RATIO: int = 2
@@ -164,6 +166,13 @@ class NetworkConfig:
     # No cell of the benchmark turns it on; the serve runner's bf16/int8
     # rungs do (serve/runner.py), behind their parity gate.
     FOLD_BN: bool = False
+
+    @property
+    def deformable(self) -> bool:
+        """Deformable ConvNets: its pooling reads the deformable conv5 of
+        ``models/resnet.py::DCNBackbone`` through the ``roi_offset`` fc and
+        the 2-fc head; the one switch for all of it."""
+        return self.ROI_MODE == "deform_roi_pool"
 
 
 @dataclass(frozen=True)
@@ -212,6 +221,16 @@ NETWORKS: Dict[str, NetworkConfig] = {
         ROI_MODE="roi_pool",
     ),
     "resnet": NetworkConfig(name="resnet", depth=101),
+    # Deformable ConvNets (Dai et al. 2017), the public Faster R-CNN: its
+    # pooling mode selects the dilated deformable conv5 on the map and the
+    # 2-fc head (NetworkConfig.deformable)
+    "resnet_dcn": NetworkConfig(
+        name="resnet",
+        depth=101,
+        ROI_MODE="deform_roi_pool",
+        POOLED_SIZE=(7, 7),
+        ROI_SAMPLE_RATIO=4,
+    ),
     "resnet50": NetworkConfig(name="resnet", depth=50),
     "resnet152": NetworkConfig(name="resnet", depth=152),
     "resnet_fpn": NetworkConfig(

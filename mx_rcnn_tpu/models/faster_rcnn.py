@@ -18,6 +18,7 @@ avoid).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -28,8 +29,10 @@ import numpy as np
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.models.heads import RCNNHead
 from mx_rcnn_tpu.models.layers import per_image
+from mx_rcnn_tpu.models.resnet import offset_init
 from mx_rcnn_tpu.models.rpn import RPNHead
 from mx_rcnn_tpu.ops.anchors import shifted_anchors
+from mx_rcnn_tpu.ops.deform_roi_pool import deform_roi_pool_batched, empty_bins
 from mx_rcnn_tpu.ops.losses import (
     accuracy,
     smooth_l1,
@@ -39,6 +42,12 @@ from mx_rcnn_tpu.ops.losses import (
 from mx_rcnn_tpu.ops.proposal import _NEG_INF, anchor_grid_mask, propose
 from mx_rcnn_tpu.ops.roi_align import extract_roi_features_batched
 from mx_rcnn_tpu.ops.targets import assign_anchor, bbox_denorm_vectors, sample_rois
+
+
+#: the ``offset`` fc's draw: lecun-normal times this, so that at the
+#: initial weights the bins move by about a tenth of the roi (the public
+#: initialisation is zero: the first steps would pool on the fixed grid)
+ROI_OFFSET_INIT = 0.04
 
 
 def _dtype_of(cfg: Config):
@@ -67,6 +76,13 @@ class FasterRCNN(nn.Module):
             num_anchors=cfg.network.NUM_ANCHORS, channels=512, dtype=dtype
         )
         self.rcnn = RCNNHead(num_classes=cfg.dataset.NUM_CLASSES, dtype=dtype)
+        if cfg.network.deformable:
+            ph, pw = cfg.network.POOLED_SIZE
+            # Deformable ConvNets' ``offset`` fc: the first pass's pooled
+            # rois → a (dx, dy) for every bin of the second
+            self.roi_offset = nn.Dense(
+                2 * ph * pw, kernel_init=offset_init(ROI_OFFSET_INIT),
+                dtype=dtype, param_dtype=jnp.float32)
         if cfg.network.USE_MASK:
             raise NotImplementedError(
                 "USE_MASK: mask targets/loss are not wired into the C4 "
@@ -85,32 +101,76 @@ class FasterRCNN(nn.Module):
             )
         )
 
+    def _backbone(self, images: jnp.ndarray, pad_mask=None):
+        """→ (the RPN's map, the map rois are pooled from, counters for
+        ``aux``): one map for both but under Deformable ConvNets, whose
+        backbone pools from its conv5 (``DCNBackbone``)."""
+        if self.cfg.network.deformable:
+            return self.backbone(images, pad_mask=pad_mask)
+        feat = self.backbone(images, pad_mask=pad_mask)
+        return feat, feat, {}
+
+    def _deform_pool(self, feat: jnp.ndarray, rois: jnp.ndarray,
+                     valid_hw=None):
+        """Deformable ConvNets' pooling: a pass on the fixed grid, the
+        ``roi_offset`` fc on it, then a second pass with each bin moved by
+        those offsets (in units of γ × the roi's extent) → ((B, R, ph, pw,
+        C), counters for ``aux``: the second pass's bins that kept no
+        sample, beside their total)."""
+        net = self.cfg.network
+        pool = functools.partial(
+            deform_roi_pool_batched, feat, rois, pooled=net.POOLED_SIZE,
+            spatial_scale=1.0 / net.RCNN_FEAT_STRIDE,
+            sample_per_part=net.ROI_SAMPLE_RATIO, valid_hw=valid_hw)
+        b, r = rois.shape[0], rois.shape[1]
+        first = pool()
+        t = self.roi_offset(first.reshape(b * r, -1)).reshape(
+            (b, r, 2) + tuple(net.POOLED_SIZE))
+        pooled = pool(offsets=t)
+        counts = {
+            "deform_pool_empty_bins": jax.vmap(
+                lambda rr, tt, v: empty_bins(
+                    feat.shape[1:3], rr, tt, net.POOLED_SIZE,
+                    1.0 / net.RCNN_FEAT_STRIDE, net.ROI_SAMPLE_RATIO,
+                    valid_hw=v)
+            )(rois, t, valid_hw).sum(),
+            "deform_pool_bins": jnp.asarray(
+                b * r * net.POOLED_SIZE[0] * net.POOLED_SIZE[1], jnp.int32),
+        }
+        return pooled, counts
+
     def _roi_features(
         self, feat: jnp.ndarray, rois: jnp.ndarray, fwd_only: bool = False,
         valid_hw=None, sample_keys=None,
-    ) -> jnp.ndarray:
-        """(B, Hf, Wf, C) × (B, R, 4) → (B*R, D) head trunk features.
-        ``sample_keys`` (B,): the images' roi-sampling keys, given in
-        training: a head that drops units draws its masks from them."""
+    ):
+        """(B, Hf, Wf, C) × (B, R, 4) → ((B*R, D) head trunk features,
+        counters for ``aux``).  ``sample_keys`` (B,): the images'
+        roi-sampling keys, given in training: a head that drops units
+        draws its masks from them."""
         net = self.cfg.network
+        counts = {}
         # closes before top_head: the scope's device time is the pooling's.
-        # Named after the mode (``roi_align`` | ``roi_pool``): a trace says
-        # which pooling it timed
+        # Named after the mode (``roi_align`` | ``roi_pool`` |
+        # ``deform_roi_pool``): a trace says which pooling it timed
         with jax.named_scope(net.ROI_MODE):
-            pooled = extract_roi_features_batched(
-                feat,
-                rois,
-                net.ROI_MODE,
-                net.POOLED_SIZE,
-                1.0 / net.RCNN_FEAT_STRIDE,
-                net.ROI_SAMPLE_RATIO,
-                fwd_only=fwd_only,
-                valid_hw=valid_hw,
-            )
+            if net.deformable:
+                pooled, counts = self._deform_pool(feat, rois, valid_hw)
+            else:
+                pooled = extract_roi_features_batched(
+                    feat,
+                    rois,
+                    net.ROI_MODE,
+                    net.POOLED_SIZE,
+                    1.0 / net.RCNN_FEAT_STRIDE,
+                    net.ROI_SAMPLE_RATIO,
+                    fwd_only=fwd_only,
+                    valid_hw=valid_hw,
+                )
         b, r = pooled.shape[0], pooled.shape[1]
-        return self.top_head(
+        trunk = self.top_head(
             pooled.reshape((b * r,) + pooled.shape[2:]), sample_keys
         )
+        return trunk, counts
 
     def __call__(
         self,
@@ -143,7 +203,7 @@ class FasterRCNN(nn.Module):
         t = cfg.TRAIN
         b = images.shape[0]
 
-        feat = self.backbone(images)
+        feat, roi_feat, counts = self._backbone(images)
         rpn_logits, rpn_deltas = self.rpn(feat)           # (B, N, 2/4)
         anchors = self._anchors(feat.shape[1], feat.shape[2])
 
@@ -194,8 +254,8 @@ class FasterRCNN(nn.Module):
         # --- second stage (the ROIAlign kernels stay innermost-scoped by
         # flax's ``FasterRCNN._roi_features``: the benchmark finds them so)
         with jax.named_scope("roi_head"):
-            trunk = self._roi_features(
-                feat, samples.rois, sample_keys=keys[:, 1]
+            trunk, pool_counts = self._roi_features(
+                roi_feat, samples.rois, sample_keys=keys[:, 1]
             )                                                   # (B*R, D)
             cls_logits, bbox_pred_out = self.rcnn(trunk)       # (B*R, K), (B*R, 4K)
 
@@ -237,6 +297,11 @@ class FasterRCNN(nn.Module):
             # silently contributes nothing) — watch this on tiny inputs
             "num_fg_anchors": (atgt.labels == 1).sum(),
         }
+        # Deformable ConvNets: each deformable layer's sampling points
+        # inside the map and the pooled bins that kept no sample, beside
+        # their constant totals (``train_net`` sums them into
+        # ``report["deform"]``)
+        aux.update(counts, **pool_counts)
         return total, aux
 
     # ------------------------------------------------------------------- test
@@ -258,7 +323,9 @@ class FasterRCNN(nn.Module):
         # detections depend on the bucket).  Inference-only — the train
         # graph keeps its original arithmetic.
         pad_mask = make_pad_mask(im_info, (images.shape[1], images.shape[2]))
-        feat = pad_mask(self.backbone(images, pad_mask=pad_mask))
+        rpn_feat, roi_feat, _counts = self._backbone(images, pad_mask=pad_mask)
+        feat = pad_mask(rpn_feat)
+        roi_feat = feat if roi_feat is rpn_feat else pad_mask(roi_feat)
         rpn_logits, rpn_deltas = self.rpn(feat)
         anchors = self._anchors(feat.shape[1], feat.shape[2])
 
@@ -293,12 +360,12 @@ class FasterRCNN(nn.Module):
 
         # one ladder-wide shape into roi_align so the second stage is the
         # SAME program for every bucket (see layers.pad_feat_to_ladder)
-        feat = pad_feat_to_ladder(
-            feat, cfg.network.RCNN_FEAT_STRIDE, cfg.SHAPE_BUCKETS
+        roi_feat = pad_feat_to_ladder(
+            roi_feat, cfg.network.RCNN_FEAT_STRIDE, cfg.SHAPE_BUCKETS
         )
         with jax.named_scope("roi_head"):
-            trunk = self._roi_features(
-                feat, props.rois, fwd_only=True, valid_hw=im_info[:, :2]
+            trunk, _counts = self._roi_features(
+                roi_feat, props.rois, fwd_only=True, valid_hw=im_info[:, :2]
             )
             cls_logits, bbox_deltas = self.rcnn(trunk)
         b, r = images.shape[0], te.RPN_POST_NMS_TOP_N

@@ -238,6 +238,7 @@ def conv(
     name: str | None = None,
     use_bias: bool = False,
     dilation: int = 1,
+    kernel_init=nn.initializers.lecun_normal(),
 ) -> nn.Conv:
     """3x3/1x1/7x7 conv helper, NHWC, f32 params.
 
@@ -254,6 +255,7 @@ def conv(
         padding=((pad, pad), (pad, pad)),
         use_bias=use_bias,
         kernel_dilation=(dilation, dilation),
+        kernel_init=kernel_init,
         dtype=dtype,
         param_dtype=jnp.float32,
         name=name,

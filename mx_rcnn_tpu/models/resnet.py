@@ -22,9 +22,28 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from mx_rcnn_tpu.models.layers import conv, make_conv_bn
+from mx_rcnn_tpu.models.layers import (
+    FrozenBatchNorm,
+    _ConvKernel,
+    conv,
+    make_conv_bn,
+)
+from mx_rcnn_tpu.ops.deform_conv import TAPS, deform_conv, inside_count
 
 _BLOCKS = {50: (3, 4, 6, 3), 101: (3, 4, 23, 3), 152: (3, 8, 36, 3)}
+
+# Deformable ConvNets' conv5 (Dai et al. 2017, the public
+# ``resnet_v1_101_rcnn_dcn.py``): first unit at stride 1, every 3×3
+# dilated by 2 and deformable with 4 offset groups, then ``conv_new_1``
+# 2048 → 256 + ReLU, the map the rois are pooled from
+DCN_DILATION = 2
+DCN_GROUPS = 4
+DCN_CHANNELS = 256
+#: the offset convolutions' draw: lecun-normal times this, so that at the
+#: initial weights the offsets spread about one map cell (the public
+#: initialisation is zero, under which the first steps of training are a
+#: plain dilated convolution and nothing deformable would be exercised)
+DCN_OFFSET_INIT = 0.01
 
 # leading-block order used for the frozen-prefix stop_gradient boundary;
 # must match the module names in ResNetBackbone.__call__
@@ -62,6 +81,12 @@ def frozen_prefix_len(
         else:
             break
     return n
+
+
+def offset_init(scale: float = DCN_OFFSET_INIT):
+    """lecun-normal times ``scale``: the offset layers' kernels."""
+    return nn.initializers.variance_scaling(
+        scale * scale, "fan_in", "truncated_normal")
 
 
 class Bottleneck(nn.Module):
@@ -180,3 +205,108 @@ class ResNetTopHead(nn.Module):
         x = ResNetStage(512, blocks[3], 2, self.dtype,
                         fold_bn=self.fold_bn, name="stage4")(rois_feat)
         return jnp.mean(x, axis=(1, 2))
+
+
+class DeformBottleneck(nn.Module):
+    """Deformable ConvNets' conv5 unit: a stride-1 :class:`Bottleneck`
+    whose 3×3 is deformable (``ops/deform_conv.py``), dilated by
+    ``DCN_DILATION``, its offsets from a 3×3 convolution of the same input
+    (``conv2_offset``) → ``(y, points of the 3×3 inside the map)``."""
+
+    filters: int
+    dtype: Any = jnp.float32
+    fold_bn: bool = False
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, pad_mask=None):
+        pm = pad_mask if pad_mask is not None else (lambda v: v)
+        cbn = make_conv_bn(self.fold_bn, self.dtype)
+        y = nn.relu(cbn(x, self.filters, 1, 1, "conv1", "bn1"))
+        # the offsets, the sampling and the product: one scope a layer
+        with jax.named_scope("deform_conv"):
+            y = pm(y)
+            offsets = conv(
+                2 * len(TAPS) * DCN_GROUPS, 3, 1, self.dtype,
+                name="conv2_offset", use_bias=True, dilation=DCN_DILATION,
+                kernel_init=offset_init())(y)
+            kernel = _ConvKernel(self.filters, 3, name="conv2")(y.shape[-1])
+            inside = inside_count(offsets, DCN_DILATION, DCN_GROUPS)
+            y = deform_conv(y, offsets, kernel.astype(self.dtype),
+                            DCN_DILATION, DCN_GROUPS)
+        y = nn.relu(FrozenBatchNorm(dtype=self.dtype, name="bn2")(y))
+        y = cbn(y, self.filters * 4, 1, 1, "conv3", "bn3")
+        residual = x
+        if residual.shape != y.shape:
+            residual = cbn(x, self.filters * 4, 1, 1, "sc", "sc_bn")
+        return nn.relu(y + residual), inside
+
+
+class DCNConv5(nn.Module):
+    """Deformable ConvNets' ``stage4``: :class:`DeformBottleneck` units on
+    the whole map → ``(y, [points inside the map, a unit])``."""
+
+    num_units: int
+    dtype: Any = jnp.float32
+    fold_bn: bool = False
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, pad_mask=None):
+        inside = []
+        for i in range(self.num_units):
+            x, n = DeformBottleneck(512, self.dtype, self.fold_bn,
+                                    name=f"unit{i + 1}")(x, pad_mask)
+            inside.append(n)
+        return x, inside
+
+
+class DCNBackbone(nn.Module):
+    """Deformable ConvNets' backbone: :class:`ResNetBackbone`'s conv1..conv4
+    (its parameters at this module's own level, as every ResNet's are),
+    then :class:`DCNConv5` on the C4 map at stride 1 and ``conv_new_1``
+    2048 → ``DCN_CHANNELS`` + ReLU → (C4 for the RPN, that map for the roi
+    pooling, counters for ``aux``: each deformable layer's points inside
+    the map as ``deform_inside_u<i>``, of ``deform_points`` a layer)."""
+
+    depth: int = 101
+    dtype: Any = jnp.float32
+    frozen_prefix: int = 0
+    fold_bn: bool = False
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, pad_mask=None):
+        trunk = ResNetBackbone(self.depth, self.dtype,
+                               frozen_prefix=self.frozen_prefix,
+                               fold_bn=self.fold_bn, name="trunk")
+        nn.share_scope(self, trunk)
+        c4 = trunk(x, pad_mask)
+        c5, inside = DCNConv5(_BLOCKS[self.depth][3], self.dtype,
+                              self.fold_bn, name="stage4")(c4, pad_mask)
+        feat = conv(DCN_CHANNELS, 1, 1, self.dtype, name="conv_new_1",
+                    use_bias=True, kernel_init=nn.initializers.normal(0.01))(c5)
+        counts = {f"deform_inside_u{i + 1}": n for i, n in enumerate(inside)}
+        # the points a layer samples: the constant beside those counts
+        counts["deform_points"] = jnp.asarray(
+            c5.shape[0] * c5.shape[1] * c5.shape[2] * len(TAPS) * DCN_GROUPS,
+            jnp.int32)
+        return c4, nn.relu(feat), counts
+
+
+class DCNTopHead(nn.Module):
+    """Deformable ConvNets' 2-fc head on pooled rois: (R, 7, 7, 256) →
+    (R, 1024), ``fc_new_1`` and ``fc_new_2`` with ReLU, no dropout (the
+    public symbol has none), drawn normal(0.01) as it initialises them.
+
+    ``drop_keys``: the top heads' one signature (``build_backbone``); this
+    head drops nothing and reads none."""
+
+    width: int = 1024
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, rois_feat: jnp.ndarray, drop_keys=None) -> jnp.ndarray:
+        x = rois_feat.reshape(rois_feat.shape[0], -1)
+        for name in ("fc_new_1", "fc_new_2"):
+            x = nn.relu(nn.Dense(
+                self.width, kernel_init=nn.initializers.normal(0.01),
+                dtype=self.dtype, param_dtype=jnp.float32, name=name)(x))
+        return x

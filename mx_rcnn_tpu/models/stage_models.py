@@ -26,6 +26,8 @@ from mx_rcnn_tpu.models.heads import RCNNHead
 from mx_rcnn_tpu.models.layers import per_image
 from mx_rcnn_tpu.models.resnet import (
     RESNET_BLOCK_ORDER,
+    DCNBackbone,
+    DCNTopHead,
     ResNetBackbone,
     ResNetTopHead,
     frozen_prefix_len,
@@ -57,18 +59,34 @@ def build_backbone(
     mask either way, so XLA skipping their backward pass is free speed.
     ``fixed_params`` must name the set the optimizer actually freezes
     (stage-2 alternate training passes FIXED_PARAMS_SHARED); defaults to
-    ``cfg.network.FIXED_PARAMS``."""
+    ``cfg.network.FIXED_PARAMS``.
+
+    Deformable ConvNets (``cfg.network.deformable``): the backbone runs
+    the deformable conv5 on the map and the top head is the 2-fc head."""
     fixed = cfg.network.FIXED_PARAMS if fixed_params is None else fixed_params
     if cfg.network.name == "vgg":
         n = frozen_prefix_len(fixed, VGG_BLOCK_ORDER)
         return VGGBackbone(dtype=dtype, frozen_prefix=n), VGGTopHead(dtype=dtype)
     n = frozen_prefix_len(fixed, RESNET_BLOCK_ORDER, requires=("bn",))
     fold = cfg.network.FOLD_BN
+    if cfg.network.deformable:
+        return (DCNBackbone(depth=cfg.network.depth, dtype=dtype,
+                            frozen_prefix=n, fold_bn=fold),
+                DCNTopHead(dtype=dtype))
     return (
         ResNetBackbone(depth=cfg.network.depth, dtype=dtype, frozen_prefix=n,
                        fold_bn=fold),
         ResNetTopHead(depth=cfg.network.depth, dtype=dtype, fold_bn=fold),
     )
+
+
+def _single_map_only(cfg: Config) -> None:
+    """The stage graphs pool rois from the RPN's own map; Deformable
+    ConvNets pools from its conv5 and trains end to end only."""
+    if cfg.network.deformable:
+        raise NotImplementedError(
+            "resnet_dcn trains end to end (FasterRCNN): the alternate "
+            "training's stage graphs have no deformable conv5")
 
 
 class RPNOnly(nn.Module):
@@ -88,6 +106,7 @@ class RPNOnly(nn.Module):
 
     def setup(self):
         cfg = self.cfg
+        _single_map_only(cfg)
         dtype = _dtype_of(cfg)
         self.backbone, _ = build_backbone(cfg, dtype, self.fixed_params)
         self.rpn = RPNHead(
@@ -185,6 +204,7 @@ class FastRCNN(nn.Module):
 
     def setup(self):
         cfg = self.cfg
+        _single_map_only(cfg)
         dtype = _dtype_of(cfg)
         self.backbone, self.top_head = build_backbone(cfg, dtype, self.fixed_params)
         self.rcnn = RCNNHead(num_classes=cfg.dataset.NUM_CLASSES, dtype=dtype)

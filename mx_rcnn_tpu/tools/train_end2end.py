@@ -59,7 +59,8 @@ logger = logging.getLogger(__name__)
 def parse_args(argv=None):
     p = argparse.ArgumentParser(description="Train Faster R-CNN end-to-end")
     p.add_argument("--network", default="resnet",
-                   choices=["vgg", "resnet", "resnet50", "resnet152", "resnet_fpn", "mask_resnet_fpn"])
+                   choices=["vgg", "resnet", "resnet_dcn", "resnet50", "resnet152",
+                            "resnet_fpn", "mask_resnet_fpn"])
     p.add_argument("--dataset", default="PascalVOC",
                    choices=["PascalVOC", "PascalVOC0712", "coco"])
     p.add_argument("--image_set", default=None)
@@ -187,8 +188,13 @@ def train_net(args, report=None):
     ``roi_levels`` (a pyramid's sampled rois by pooling level,
     ``num_rois_p2`` .., and where its streaming ROIAlign kernels run their
     live and walked (roi block, image) steps, ``roi_steps_live_p2`` /
-    ``roi_steps_p2`` ..; summed over the fetched steps; empty otherwise)
-    and on an elastic run ``elastic`` / ``degraded``."""
+    ``roi_steps_p2`` ..; summed over the fetched steps; empty otherwise),
+    ``deform`` (Deformable ConvNets: each deformable layer's sampling
+    points inside the map, ``deform_inside_u1`` .., of ``deform_points``
+    a layer, and the second pooling pass's bins that kept no sample,
+    ``deform_pool_empty_bins`` of ``deform_pool_bins``; summed over the
+    fetched steps; empty otherwise) and on an elastic run ``elastic`` /
+    ``degraded``."""
     import collections
 
     from mx_rcnn_tpu.utils.platform import cli_bootstrap
@@ -461,6 +467,8 @@ def train_net(args, report=None):
     # ``roi_steps_live_p2`` / ``roi_steps_p2`` .. in the step's aux),
     # summed over the fetched steps
     roi_level_totals: collections.Counter = collections.Counter()
+    # Deformable ConvNets' in-map counters (``deform_*`` in the aux)
+    deform_totals: collections.Counter = collections.Counter()
 
     def deliver(ready):
         for idx, aux in ready:
@@ -470,6 +478,8 @@ def train_net(args, report=None):
             roi_level_totals.update(
                 {k: v for k, v in values.items()
                  if k.startswith(("num_rois_p", "roi_steps_"))})
+            deform_totals.update(
+                {k: v for k, v in values.items() if k.startswith("deform_")})
 
     def flush_pipeline(state):
         # force the deferred aux checks before any checkpoint/summary:
@@ -595,6 +605,19 @@ def train_net(args, report=None):
                         f"({v / roi_level_totals[f'roi_steps_{lv}']:.1%})"
                         for lv, v in sorted(live.items())),
                 )
+        if deform_totals:
+            d = deform_totals
+            logger.info(
+                "host side: deformable sampling points inside the map: %s; "
+                "pooled bins with no sample %.0f of %.0f (%.1f%%)",
+                ", ".join(
+                    f"{k[len('deform_inside_'):]} "
+                    f"{v / d['deform_points']:.1%}"
+                    for k, v in sorted(d.items())
+                    if k.startswith("deform_inside_")),
+                d["deform_pool_empty_bins"], d["deform_pool_bins"],
+                100.0 * d["deform_pool_empty_bins"] / d["deform_pool_bins"],
+            )
         if report is not None:
             report.update(
                 steps=total_steps,
@@ -606,6 +629,7 @@ def train_net(args, report=None):
                 pipeline=pipe_stats,
                 loader=dict(loader_totals),
                 roi_levels=dict(roi_level_totals),
+                deform=dict(deform_totals),
             )
         if use_elastic:
             if eloop.monitor.shrinks:

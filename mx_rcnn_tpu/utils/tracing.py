@@ -56,13 +56,18 @@ SERVE_SPANS = (
 # component a level, ``roi_align/p2`` .. ``roi_align/p5`` (models/fpn.py).
 # The pooling's scope is named after ``ROI_MODE``: under ROI max pooling
 # (VGG-16) the single-map graph opens ``roi_pool`` where ``roi_align``
-# stands here, and flax's ``top_head`` holds fc6 / fc7.
+# stands here, and flax's ``top_head`` holds fc6 / fc7.  Under Deformable
+# ConvNets it is ``deform_roi_pool`` (both passes and the ``offset`` fc
+# between them), and each of conv5's three deformable layers opens
+# ``deform_conv`` inside flax's ``backbone/stage4/unit<i>`` (its offsets,
+# its sampling and its product).
 TRAIN_SCOPES = (
     "backbone", "rpn", "anchor_targets", "proposal", "roi_sample",
     "roi_head", "roi_align", "losses", "update",
 )
 FPN_SCOPES = ("neck",) + tuple(f"roi_align/p{lv}" for lv in range(2, 6))
 ROI_POOL_SCOPES = ("roi_pool", "top_head")
+DCN_SCOPES = ("deform_conv", "deform_roi_pool", "top_head")
 SERVE_SCOPES = (
     "postprocess/decode", "postprocess/class_nms", "postprocess/cap",
     "postprocess/mask_select", "postprocess/mask_paste",

@@ -145,6 +145,26 @@ def test_roi_pool_kernel_case_is_the_vgg_cell_s_shape():
         assert fits_vmem(*shape[1:], (7, 7), 4)
 
 
+def test_dcn_phase_asks_for_the_cell_s_configuration():
+    """The Deformable ConvNets phase's argv through the CLI's own parser:
+    the program ``frcnn_r101_dcn_voc`` names in its ``train_argv``, batch
+    8 in bf16 as the cell ``dcn_train_b8``, the deformable pooling."""
+    from mx_rcnn_tpu.tools import train_end2end as cli
+
+    args = cli.parse_args(chip_smoke.TRAIN_DCN_BF16_ARGV
+                          + ["--prefix", "/nowhere"])
+    cfg = cli.config_from_args(args)
+    assert cfg.network.deformable
+    assert cfg.network.ROI_MODE == "deform_roi_pool"
+    assert cfg.dataset.NUM_CLASSES == 21 and cfg.TRAIN.BATCH_IMAGES == 8
+    assert cfg.network.COMPUTE_DTYPE == "bfloat16"
+    assert args.max_steps == 6 and args.lr == 1e-05
+    with open(os.path.join(REPO_ROOT, "benchmark", "configs",
+                           "frcnn_r101_dcn_voc.json")) as f:
+        cell_argv = json.load(f)["train_argv"]
+    assert chip_smoke.TRAIN_DCN_BF16_ARGV[:4] == cell_argv
+
+
 def test_streaming_train_shapes_are_the_pyramid_s_p2_and_p3():
     from mx_rcnn_tpu.ops.pallas.roi_align import fits_vmem
 

@@ -327,6 +327,34 @@ def test_roi_max_pooling_opens_its_own_scope_and_no_roi_align():
             assert json.load(f)["args"]["scope"] in tracing.ROI_POOL_SCOPES
 
 
+def test_deformable_layers_and_pooling_open_their_scopes():
+    """Deformable ConvNets (``resnet_dcn``): each of conv5's three
+    deformable layers opens ``deform_conv`` inside flax's
+    ``backbone/stage4/unit<i>``; the two pooling passes and the offset fc
+    between them lie under ``deform_roi_pool`` inside ``roi_head``, closed
+    before ``top_head``; nothing is named ``roi_align``.  The cell's four
+    new metric files read these scopes."""
+    names = _scoped_names(_lowered_step(_tiny_generate_config(
+        "resnet_dcn", "PascalVOC")))
+    for scope in tracing.DCN_SCOPES:
+        assert any(f"/{scope}/" in n + "/" for n in names), scope
+    assert not any("/roi_align/" in n + "/" for n in names)
+    conv = [n for n in names if "/deform_conv/" in n + "/"]
+    assert {re.search(r"/backbone/stage4/(unit\d)/", n).group(1)
+            for n in conv} == {"unit1", "unit2", "unit3"}
+    pool = [n for n in names if "/deform_roi_pool/" in n + "/"]
+    assert all("/roi_head/" in n for n in pool)
+    assert not any("/top_head/" in n + "/" for n in pool)
+    assert any("/roi_offset/" in n for n in pool)
+    for name in ("deform_conv_device_ms.train",
+                 "deform_roi_pool_device_ms.train",
+                 "deform_conv_roofline.dcn_train",
+                 "deform_roi_pool_roofline.dcn_train"):
+        with open(os.path.join(REPO_ROOT, "benchmark", "metrics",
+                               name + ".json")) as f:
+            assert json.load(f)["args"]["scope"] in tracing.DCN_SCOPES
+
+
 @pytest.mark.parametrize("scope", tracing.TRAIN_SCOPES)
 def test_train_step_names_the_scope(lowered_train_step, scope):
     assert any(f"/{scope}/" in n + "/" for n in
