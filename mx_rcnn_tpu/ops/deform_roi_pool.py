@@ -24,9 +24,11 @@ R-CNN runs it.  For one roi ``(x1, y1, x2, y2)`` in image coordinates at
 
 Without offsets (``t = 0``) it is the first pass of DCN's two: its pooled
 rois feed the fully connected layer that computes ``t`` for the second.
-Gradients come from ``jax.grad``: with respect to the map (scatter-adds of
-the samples' bilinear weights) and to the offsets (the weights' slopes;
-zero for a clamped coordinate, whose position no longer moves).
+A pass is a matrix product of every bin's weight row over the map with
+the map (:func:`deform_roi_pool`); gradients come from ``jax.grad``: with
+respect to the map (the transposed product) and to the offsets (the
+weights' slopes; zero for a clamped coordinate, whose position no longer
+moves).
 
 ``valid_hw`` (the image's true ``(h, w)``) makes the border rule the
 image's own: the map's extent is the valid one, ``ceil(h·s)`` cells, the
@@ -34,6 +36,8 @@ limit ``ops/roi_align.py::_feat_limits`` gives ROIAlign.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +81,22 @@ def sample_grid(rois, offsets, pooled, spatial_scale: float,
     return y, x, keep
 
 
+def _weight_rows(coord, keep, lim_f, lim_i, size: int):
+    """One axis of a bin's samples: (..., n) coordinates on the map
+    (before the clamp) and whether each is kept → (..., size), the kept
+    samples' two-corner bilinear weights summed over the samples.  A
+    sample clamped onto the last cell puts both weights on it."""
+    c = jnp.clip(coord, 0.0, lim_f - 1.0)
+    lo = jnp.floor(c)
+    frac = c - lo
+    lo = lo.astype(jnp.int32)
+    hi = jnp.minimum(lo + 1, lim_i - 1)
+    cells = jnp.arange(size, dtype=jnp.int32)
+    rows = ((1.0 - frac)[..., None] * (cells == lo[..., None])
+            + frac[..., None] * (cells == hi[..., None]))
+    return (keep[..., None] * rows).sum(axis=-2)
+
+
 def deform_roi_pool(feat: jnp.ndarray, rois: jnp.ndarray, offsets=None,
                     pooled=(7, 7), spatial_scale: float = 1.0 / 16.0,
                     sample_per_part: int = 4, trans_std: float = TRANS_STD,
@@ -84,52 +104,63 @@ def deform_roi_pool(feat: jnp.ndarray, rois: jnp.ndarray, offsets=None,
     """(H, W, C) map × (R, 4) rois [× (R, 2, ph, pw) offsets] → (R, ph,
     pw, C) in the map's dtype (the module docstring has the semantics).
 
-    One sub-bin sample at a time over every roi: its four corners are
-    gathered as rows of the map, weighted in float32 and summed; each
-    sample's gather is recomputed in the backward pass
-    (``jax.checkpoint``), so no (R, ph, pw, n, n, C) tensor is kept."""
+    A bin's samples form a product grid: a sample's y hangs on its row
+    of the grid alone, its x on its column, and the skip rule is a test
+    of each.  So the bin's value is ``Σ_h Σ_w u[h]·v[w]·F[h, w] / (k_y ·
+    k_x)``, with ``u`` (H,) and ``v`` (W,) the kept samples' weights along
+    each axis (:func:`_weight_rows`) and ``k_y · k_x`` the kept samples:
+    one weight row ``u ⊗ v`` over the whole map a bin (an empty bin's is
+    zero), and one (R·ph·pw, H·W) × (H·W, C) product for the pass.
+    ``jax.grad`` gives the map's gradient as the transposed product and
+    the offsets' through the weights' slopes.  Precision follows the
+    map's dtype: a bfloat16 map meets bfloat16 weights in one MXU pass
+    with float32 accumulation (the training graph's contract), a float32
+    one ``HIGHEST``.
+
+    The row is formed flat, ``u`` repeated times ``v`` tiled: formed as
+    (…, H, W) and reshaped, a 38×64 map's rows are laid out 64 lanes of
+    128 and copied.  At ``dcn_train_b8``'s shape (8 images, 128 rois) the
+    rows are 8 × 6272 × 2432, 244 MB in bfloat16 a pass, and the weights'
+    gradient 488 MB in float32; :func:`deform_roi_pool_batched` forms
+    them again in the backward pass rather than keep them."""
     hf, wf, c = feat.shape
     limits = _feat_limits((hf, wf), valid_hw, spatial_scale)
     y, x, keep = sample_grid(rois, offsets, pooled, spatial_scale,
                              sample_per_part, trans_std, limits)
     (lh, lh_i), (lw, lw_i) = limits
-    y = jnp.clip(y, 0.0, lh - 1.0)
-    x = jnp.clip(x, 0.0, lw - 1.0)
-    rows = feat.reshape(hf * wf, c)
-
-    @jax.checkpoint
-    def sample(y, x, keep):
-        y0, x0 = jnp.floor(y), jnp.floor(x)
-        ly, lx = y - y0, x - x0
-        y0, x0 = y0.astype(jnp.int32), x0.astype(jnp.int32)
-        y1, x1 = jnp.minimum(y0 + 1, lh_i - 1), jnp.minimum(x0 + 1, lw_i - 1)
-        keep = keep.astype(jnp.float32)
-        out = 0.0
-        for yy, xx, wgt in ((y0, x0, (1 - ly) * (1 - lx)),
-                            (y0, x1, (1 - ly) * lx),
-                            (y1, x0, ly * (1 - lx)), (y1, x1, ly * lx)):
-            out = out + (wgt * keep)[..., None] * rows[yy * wf + xx].astype(
-                jnp.float32)
-        return out
-
-    n = sample_per_part
-    total = sum(sample(y[..., i, j], x[..., i, j], keep[..., i, j])
-                for i in range(n) for j in range(n))
     count = keep.sum(axis=(-2, -1)).astype(jnp.float32)          # (R, ph, pw)
-    return (total / jnp.maximum(count, 1.0)[..., None]).astype(feat.dtype)
+    # keep = (row kept) & (column kept): where a bin keeps any sample the
+    # two tests are its any() along the other axis, elsewhere both zero
+    u = _weight_rows(y[..., :, 0], keep.any(axis=-1), lh, lh_i, hf)
+    v = _weight_rows(x[..., 0, :], keep.any(axis=-2), lw, lw_i, wf)
+    u = (u / jnp.maximum(count, 1.0)[..., None]).reshape(-1, hf)
+    a = jnp.repeat(u, wf, axis=-1) * jnp.tile(v.reshape(-1, wf), (1, hf))
+    if feat.dtype == jnp.bfloat16:
+        a, prec = a.astype(jnp.bfloat16), jax.lax.Precision.DEFAULT
+    else:
+        prec = jax.lax.Precision.HIGHEST
+    out = jnp.dot(a, feat.reshape(hf * wf, c), precision=prec,
+                  preferred_element_type=feat.dtype)
+    return out.reshape(y.shape[:3] + (c,))
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "pooled", "spatial_scale", "sample_per_part"))
 def deform_roi_pool_batched(feat: jnp.ndarray, rois: jnp.ndarray,
                             offsets=None, pooled=(7, 7),
                             spatial_scale: float = 1.0 / 16.0,
                             sample_per_part: int = 4, valid_hw=None):
     """(B, H, W, C) × (B, R, 4) [× (B, R, 2, ph, pw) offsets, (B, 2)
-    ``valid_hw``] → (B, R, ph, pw, C): :func:`deform_roi_pool` one image
-    after the other (``lax.map``): batched, its sixteen samples' gathers
-    took 0.9 GB of temporaries a pass at the cell's shape."""
-    return jax.lax.map(lambda a: deform_roi_pool(
-        a[0], a[1], a[2], pooled, spatial_scale, sample_per_part,
-        valid_hw=a[3]), (feat, rois, offsets, valid_hw))
+    ``valid_hw``] → (B, R, ph, pw, C): :func:`deform_roi_pool` over the
+    batch, one product with the images as its batch dimension, its
+    weight rows formed again for the backward pass (``jax.checkpoint``:
+    kept, they took the cell's step from 3.97 to 5.00 GB of temporaries
+    by the compiler's count).  Jitted, so that ``train_net``'s op-by-op
+    ``model.init`` compiles a pass as one program and not each of its
+    some sixty operations as a program of its own."""
+    return jax.vmap(jax.checkpoint(lambda f, r, t, v: deform_roi_pool(
+        f, r, t, pooled, spatial_scale, sample_per_part, valid_hw=v)))(
+            feat, rois, offsets, valid_hw)
 
 
 def empty_bins(feat_hw, rois, offsets=None, pooled=(7, 7),

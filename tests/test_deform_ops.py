@@ -27,7 +27,9 @@ import numpy as np
 import pytest
 
 from mx_rcnn_tpu.ops.deform_conv import deform_conv, inside_count
-from mx_rcnn_tpu.ops.deform_roi_pool import deform_roi_pool, empty_bins
+from mx_rcnn_tpu.ops.deform_roi_pool import (deform_roi_pool,
+                                             deform_roi_pool_batched,
+                                             empty_bins, sample_grid)
 
 _BENCH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
@@ -109,14 +111,14 @@ def _c_round(v):
     return math.copysign(math.floor(abs(v) + 0.5), v)
 
 
-def loop_deform_roi_pool(fmap, rois, trans=None, pooled=POOLED, spp=SPP,
-                         gamma=GAMMA, scale=SCALE):
-    """MXNet's ``DeformablePSROIPoolForwardKernel`` at ``group_size`` 1,
-    bin by bin and sample by sample → (values, bins with no sample)."""
-    h, w, c = fmap.shape
+def loop_bins(h, w, rois, trans=None, pooled=POOLED, spp=SPP, gamma=GAMMA,
+              scale=SCALE):
+    """MXNet's ``DeformablePSROIPoolForwardKernel`` at ``group_size`` 1 on
+    an h × w map, bin by bin and sample by sample: yields ``(n, p, q,
+    corners)`` a bin, ``corners`` the (row, column, weight) of every kept
+    sample's four corners, the weights divided by the bin's kept count
+    (an empty bin has none)."""
     ph, pw = pooled
-    out = np.zeros((len(rois), ph, pw, c))
-    empty = 0
     for n, roi in enumerate(rois):
         sw = _c_round(roi[0]) * scale - 0.5
         sh = _c_round(roi[1]) * scale - 0.5
@@ -130,7 +132,7 @@ def loop_deform_roi_pool(fmap, rois, trans=None, pooled=POOLED, spp=SPP,
                 ty = 0.0 if trans is None else trans[n, 1, p, q] * gamma
                 ws = q * bw + sw + tx * rw
                 hs = p * bh + sh + ty * rh
-                total, count = np.zeros(c), 0
+                corners = []
                 for ih in range(spp):
                     for iw in range(spp):
                         xx = ws + iw * bw / spp
@@ -142,14 +144,38 @@ def loop_deform_roi_pool(fmap, rois, trans=None, pooled=POOLED, spp=SPP,
                         x1, x2 = math.floor(xx), math.ceil(xx)
                         y1, y2 = math.floor(y), math.ceil(y)
                         dx, dy = xx - x1, y - y1
-                        total += ((1 - dx) * (1 - dy) * fmap[y1, x1]
-                                  + (1 - dx) * dy * fmap[y2, x1]
-                                  + dx * (1 - dy) * fmap[y1, x2]
-                                  + dx * dy * fmap[y2, x2])
-                        count += 1
-                out[n, p, q] = total / count if count else 0.0
-                empty += count == 0
+                        corners.append([(y1, x1, (1 - dx) * (1 - dy)),
+                                        (y2, x1, (1 - dx) * dy),
+                                        (y1, x2, dx * (1 - dy)),
+                                        (y2, x2, dx * dy)])
+                count = len(corners)
+                yield n, p, q, [(yy, xx, wgt / count)
+                                for sample in corners
+                                for yy, xx, wgt in sample]
+
+
+def loop_deform_roi_pool(fmap, rois, trans=None):
+    """The bins' values from :func:`loop_bins` → (values, bins with no
+    sample)."""
+    h, w, c = fmap.shape
+    out = np.zeros((len(rois),) + POOLED + (c,))
+    empty = 0
+    for n, p, q, corners in loop_bins(h, w, rois, trans):
+        for yy, xx, wgt in corners:
+            out[n, p, q] += wgt * fmap[yy, xx]
+        empty += not corners
     return out, empty
+
+
+def loop_map_gradient(fmap_shape, rois, trans, cot):
+    """The gradient of ``Σ cot · values`` with respect to the map: each
+    bin's cotangent sent back to its samples' corners by their weights."""
+    h, w, _c = fmap_shape
+    grad = np.zeros(fmap_shape)
+    for n, p, q, corners in loop_bins(h, w, rois, trans):
+        for yy, xx, wgt in corners:
+            grad[yy, xx] += wgt * cot[n, p, q]
+    return grad
 
 
 # ------------------------------------------------------------------ inputs
@@ -363,14 +389,117 @@ def test_the_empty_bins_counter():
         loop_deform_roi_pool(fmap, ROIS)[1])
 
 
-def test_valid_hw_is_the_cropped_map():
+@pytest.mark.parametrize("kind", ["border", "last_cell"])
+def test_valid_hw_is_the_cropped_map(kind):
     """An image 100×130 pixels on the 9×11 map: its valid extent is 7×9
     cells (ceil at 1/16), and pooling with ``valid_hw`` is pooling the map
-    cropped to it."""
+    cropped to it, values and map gradient (none past the crop).
+    ``last_cell``: one roi's bins moved 1.1 cells down and right, so that
+    samples in (6, 6.5] × (8, 8.5] are kept and clamped onto the last valid
+    row and column, where both corners of a sample fall on one cell."""
     fmap, trans = _pool_inputs("moved", seed=4)
     rois = np.minimum(ROIS, [[129, 99, 129, 99]])
+    if kind == "last_cell":
+        rois[2] = [120, 90, 129, 99]          # 0.625 cells a side at 1/16
+        trans[2] = 1.1 / (GAMMA * 0.625)
+        y, x, keep = sample_grid(
+            jnp.asarray(rois[2:3], jnp.float32),
+            jnp.asarray(trans[2:3], jnp.float32), POOLED, SCALE, SPP, GAMMA,
+            [(7.0, 7), (9.0, 9)])
+        assert bool((keep & (y > 6) & (x > 8)).any()) and not bool(keep.all())
+    valid_hw = jnp.asarray([100.0, 130.0])
     want, _ = loop_deform_roi_pool(fmap[:7, :9], rois, trans)
-    _close(_program_pool(fmap, rois, trans, valid_hw=[100.0, 130.0]), want)
+    _close(_program_pool(fmap, rois, trans, valid_hw=valid_hw), want)
+    cot = np.random.RandomState(5).randn(len(rois), *POOLED, MC)
+    got = jax.grad(lambda f: jnp.sum(deform_roi_pool(
+        f, jnp.asarray(rois, jnp.float32), jnp.asarray(trans, jnp.float32),
+        POOLED, SCALE, SPP, GAMMA, valid_hw=valid_hw) * cot))(
+            jnp.asarray(fmap, jnp.float32))
+    want = np.zeros_like(fmap)
+    want[:7, :9] = loop_map_gradient((7, 9, MC), rois, trans, cot)
+    _close(np.asarray(got), want)
+
+
+#: a bfloat16 rounding is at most 2^-9 of a value; the weights, the
+#: product and, for the offsets, the difference of two rows of the weights'
+#: gradient round, against the output's largest value rather than the sum
+#: of the magnitudes behind it: eight roundings' room
+BF16_TOL = 2.0 ** -6
+
+
+def test_a_bf16_map_is_within_its_rounding_of_the_loop():
+    """The training graph's dtype at a mid size (a 19×32×128 map, 32
+    rois, offsets moved): the weights meet the map in bfloat16 with
+    float32 accumulation.  Values, map gradient (the loop's weights
+    sending the cotangent back) and offsets' gradient (central
+    differences of the loop, one roi at a time) against the loop on the
+    same bfloat16 map and cotangent."""
+    rng = np.random.RandomState(12)
+    h, w, c, r = 19, 32, 128, 32
+    x1, y1 = rng.uniform(0, w * 16 - 40, r), rng.uniform(0, h * 16 - 40, r)
+    rois = np.stack([x1, y1, np.minimum(x1 + rng.uniform(8, 300, r),
+                                        w * 16 - 1),
+                     np.minimum(y1 + rng.uniform(8, 200, r), h * 16 - 1)], 1)
+    trans = rng.uniform(-1.5, 1.5, size=(r, 2) + POOLED)
+
+    def bf16(a):
+        return np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32),
+                          np.float64)
+
+    fmap, cot = bf16(rng.randn(h, w, c)), bf16(rng.randn(r, *POOLED, c))
+
+    def loss(f, t):
+        out = deform_roi_pool(f, jnp.asarray(rois, jnp.float32), t, POOLED,
+                              SCALE, SPP, GAMMA)
+        return jnp.sum(out.astype(jnp.float32) * cot), out
+
+    (_l, out), (gf, gt) = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+        jnp.asarray(fmap, jnp.bfloat16), jnp.asarray(trans, jnp.float32))
+    assert out.dtype == gf.dtype == jnp.bfloat16
+    _close(np.asarray(out.astype(jnp.float32)),
+           loop_deform_roi_pool(fmap, rois, trans)[0], BF16_TOL)
+    _close(np.asarray(gf.astype(jnp.float32)),
+           loop_map_gradient(fmap.shape, rois, trans, cot), BF16_TOL)
+    pick, eps = np.random.RandomState(5), 1e-4
+    for _ in range(30):
+        n, idx = pick.randint(r), tuple(pick.randint(s) for s in (2,) + POOLED)
+        vals = []
+        for sign in (1, -1):
+            moved = trans[n:n + 1].copy()
+            moved[(0,) + idx] += sign * eps
+            vals.append((loop_deform_roi_pool(fmap, rois[n:n + 1], moved)[0][0]
+                         * cot[n]).sum())
+        want = (vals[0] - vals[1]) / (2 * eps)
+        got = float(gt[(n,) + idx])
+        assert abs(got - want) <= BF16_TOL * max(1.0, abs(want)), (
+            n, idx, got, want)
+
+
+def test_both_passes_lower_to_products_alone():
+    """The two passes with an fc between them, as ``FasterRCNN.
+    _deform_pool`` runs them on a bfloat16 batch, differentiated with
+    respect to the map and the fc: the lowered program holds no gather,
+    no scatter and no loop; every read of the map and every gradient sent
+    back to it is a matrix product."""
+    b, r = 2, 5
+    rng = np.random.RandomState(0)
+    feat = jnp.asarray(rng.randn(b, MH, MW, MC), jnp.bfloat16)
+    rois = jnp.asarray(np.stack([ROIS[:r]] * b), jnp.float32)
+    valid_hw = jnp.asarray([[MH * 16.0, MW * 16.0], [100.0, 130.0]])
+    fc = jnp.asarray(rng.randn(49 * MC, 2 * 49) * 0.01, jnp.float32)
+
+    def loss(f, k):
+        first = deform_roi_pool_batched(f, rois, valid_hw=valid_hw)
+        t = (first.reshape(b * r, -1).astype(jnp.float32) @ k).reshape(
+            (b, r, 2) + POOLED)
+        return jnp.sum(deform_roi_pool_batched(
+            f, rois, t, valid_hw=valid_hw).astype(jnp.float32))
+
+    hlo = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        feat, fc).as_text(dialect="hlo")
+    assert " dot(" in hlo
+    for op in ("gather", "scatter", "while"):
+        assert hlo.count(f" {op}(") == 0, op
 
 
 @pytest.mark.parametrize("side", sorted(POOLS))
