@@ -33,7 +33,10 @@ module closes the gap with three cooperating pieces:
   and re-runs the suffix that had executed on the poisoned lineage.
   ``aux_interval=1`` delegates to the guard directly and is
   byte-identical to the synchronous path (pinned by
-  ``tests/test_pipeline.py``).
+  ``tests/test_pipeline.py``).  Each flush cycle is timed on the host
+  (``stats()["cycles"]``): how long the chip has no work between the
+  window's last step and the next window's first, and where that time
+  goes.
 
 Placement is unified across entry points through :func:`make_place_fn`:
 single chip → ``jax.device_put`` (optionally into the compiled step's
@@ -46,6 +49,7 @@ input layouts, killing the input relayout copy), DP mesh →
 
 from __future__ import annotations
 
+import collections
 import logging
 import queue
 import threading
@@ -65,6 +69,9 @@ from mx_rcnn_tpu.core.resilience import (
 from mx_rcnn_tpu.utils import faults, tracing
 
 logger = logging.getLogger(__name__)
+
+#: flush cycles :meth:`PipelinedLoop.stats` hands out: the newest this many
+CYCLE_RECORDS = 64
 
 
 # ----------------------------------------------------------------- placement
@@ -300,6 +307,10 @@ class AsyncAuxSink:
     results still materializing (detected via ``Array.is_ready`` where
     the runtime exposes it) and ``fetch_stall_s`` accumulates the wait —
     the per-step host gap becomes a measured, regression-checked number.
+    ``last_fetch`` holds the newest fetch's ``(stalled, ready_at,
+    got_at)``: whether a result was still being computed, and the
+    ``time.perf_counter()`` at which every result was ready and at which
+    the copy to the host returned.
     """
 
     def __init__(self):
@@ -308,6 +319,7 @@ class AsyncAuxSink:
         self.fetched_trees = 0
         self.fetch_stalls = 0
         self.fetch_stall_s = 0.0
+        self.last_fetch: Optional[Tuple[bool, float, float]] = None
 
     def defer(self, n: int = 1) -> None:
         self.pushes += n
@@ -336,11 +348,14 @@ class AsyncAuxSink:
         stalled = not self._ready(trees)
         t0 = time.perf_counter()
         with tracing.span(tracing.GUARD_FETCH, n=len(trees)):
+            jax.block_until_ready(trees)
+            ready_at = time.perf_counter()
             out = jax.device_get(list(trees))
-        dt = time.perf_counter() - t0
+        got_at = time.perf_counter()
         if stalled:
             self.fetch_stalls += 1
-            self.fetch_stall_s += dt
+            self.fetch_stall_s += got_at - t0
+        self.last_fetch = (stalled, ready_at, got_at)
         return out
 
     def stats(self) -> Dict[str, Any]:
@@ -387,6 +402,21 @@ class PipelinedLoop:
     ``(state, ready, ok)`` where ``ready`` is a list of
     ``(step_index, host_aux)`` for newly verified steps (empty between
     flush points) and ``ok`` is False when a poison batch was skipped.
+
+    Each flush cycle, from a window's flush to the return of the next
+    window's first dispatch, is timed on the host and kept in ``cycles``
+    (the newest :data:`CYCLE_RECORDS`): ``step`` (the window's first
+    index, the ``rcnn.guard.flush`` span's id), ``n`` (its entries),
+    ``stalled`` (a result was still being computed when the flush began)
+    and the parts in ms, each starting where the one before ends:
+    ``drain_ms`` (waiting for the window's results, while the device is
+    busy), ``aux_get_ms``, ``check_ms``, ``snapshot_get_ms`` and
+    ``snapshot_copy_ms`` (the state's device→host transfer, then
+    ``host_copy``'s host-side copy of it), ``resume_ms`` (the caller's work, the next batch and the
+    dispatch call).  ``idle_ms`` is the time the chip has no work: from
+    the drain's end, or from the flush's start where nothing was left to
+    drain (a lower bound).  A flush that rolled back, one that no
+    dispatch follows and the head-of-stream snapshot get no record.
     """
 
     def __init__(
@@ -418,6 +448,10 @@ class PipelinedLoop:
         self.flushes = 0
         self.snapshots = 0  # host copies of the state (window snapshots)
         self.snapshot_s = 0.0
+        self.cycles: "collections.deque[Dict[str, Any]]" = collections.deque(
+            maxlen=CYCLE_RECORDS)
+        # the newest flush's times, until the next dispatch returns
+        self._cycle: Optional[Tuple[Any, ...]] = None
 
     # -- delegated counters / snapshot surface (watchdog dumps, summaries)
     @property
@@ -490,6 +524,7 @@ class PipelinedLoop:
         the rebound step, so the coordinates line up again."""
         self._entries = []
         self._win_snapshot = None
+        self._cycle = None
         self._idx = idx
         self.guard.step_index = idx
         self.guard._snapshot = None
@@ -497,13 +532,20 @@ class PipelinedLoop:
 
     # -- step execution
     def _snapshot(self, state, idx: int):
-        """Owning host copy of ``state``: the window's rollback point."""
+        """Owning host copy of ``state``: the window's rollback point.
+        → ``(snap, got_at, done_at)``: the ``time.perf_counter()`` at which
+        the device→host transfer and the host-side copy returned."""
+        import jax
+
         t0 = time.perf_counter()
         with tracing.span(tracing.GUARD_SNAPSHOT, step=idx):
-            snap = host_copy(state)
+            got = jax.device_get(state)
+            got_at = time.perf_counter()
+            snap = host_copy(got)  # host arrays: the same owning copy
+        done_at = time.perf_counter()
         self.snapshots += 1
-        self.snapshot_s += time.perf_counter() - t0
-        return snap
+        self.snapshot_s += done_at - t0
+        return snap, got_at, done_at
 
     def _dispatch(self, state, batch, rng, idx: int, tag: str):
         wd = self.guard.watchdog
@@ -530,9 +572,11 @@ class PipelinedLoop:
         if self._win_snapshot is None:
             # BEFORE the first dispatch of a window, as an owning copy:
             # the step may donate the buffers a device_get view aliases
-            self._win_snapshot = self._snapshot(state, idx)
+            self._win_snapshot, _got, _done = self._snapshot(state, idx)
         faults.stall(idx)  # test injection, no-op in production
         state, aux = self._dispatch(state, batch, rng, idx, tag="step")
+        if self._cycle is not None:
+            self._close_cycle(time.perf_counter())
         self._entries.append(_Entry(idx, batch, rng, aux))
         self.sink.defer()
         if len(self._entries) >= self.aux_interval:
@@ -550,9 +594,51 @@ class PipelinedLoop:
 
     def _flush(self, state):
         self.flushes += 1
+        entries, self._entries = self._entries, []
+        start = time.perf_counter()
+        rollbacks = self.window_rollbacks
+        with tracing.span(tracing.GUARD_FLUSH, step=entries[0].idx,
+                          n=len(entries)):
+            state, ready, ok = self._verify(state, entries)
+            # window verified end-to-end: retain its snapshot for the next
+            checked_at = time.perf_counter()
+            self._win_snapshot, got_at, done_at = self._snapshot(
+                state, self._idx)
+        # a window that rolled back is the guard's recovery, not a cycle
+        self._cycle = None if self.window_rollbacks != rollbacks else (
+            entries[0].idx, len(entries), start, self.sink.last_fetch,
+            (checked_at, got_at, done_at))
+        return state, ready, ok
+
+    def _close_cycle(self, resumed_at: float) -> None:
+        """File the open flush cycle: the next window's first dispatch has
+        returned at ``resumed_at``."""
+        (step, n, start, (stalled, ready_at, got_at),
+         (checked_at, snap_got_at, snap_done_at)) = self._cycle
+        self._cycle = None
+
+        def ms(a, b):
+            return round((b - a) * 1e3, 3)
+
+        self.cycles.append({
+            "step": step,
+            "n": n,
+            "stalled": stalled,
+            "drain_ms": ms(start, ready_at),
+            "aux_get_ms": ms(ready_at, got_at),
+            "check_ms": ms(got_at, checked_at),
+            "snapshot_get_ms": ms(checked_at, snap_got_at),
+            "snapshot_copy_ms": ms(snap_got_at, snap_done_at),
+            "resume_ms": ms(snap_done_at, resumed_at),
+            "idle_ms": ms(ready_at if stalled else start, resumed_at),
+        })
+
+    def _verify(self, state, entries: List[_Entry]):
+        """Fetch and check the window's losses in stream order; on a
+        flagged step roll back, replay, retry and re-run the suffix.
+        → ``(state, ready, ok)``."""
         ready: List[Tuple[int, Dict[str, Any]]] = []
         ok = True
-        entries, self._entries = self._entries, []
         while entries:
             wd = self.guard.watchdog
             if wd is not None:
@@ -609,8 +695,6 @@ class PipelinedLoop:
                                             tag="redo")
                 self.replayed_steps += 1
                 entries.append(_Entry(e.idx, e.batch, e.rng, aux))
-        # window verified end-to-end: retain its snapshot for the next one
-        self._win_snapshot = self._snapshot(state, self._idx)
         return state, ready, ok
 
     def stats(self) -> Dict[str, Any]:
@@ -625,4 +709,5 @@ class PipelinedLoop:
             "retried_steps": self.guard.retried_steps,
             "skipped_batches": self.guard.skipped_batches,
             **self.sink.stats(),
+            "cycles": list(self.cycles),
         }

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import argparse
 import logging
+import statistics
 import time
 
 import jax
@@ -171,6 +172,22 @@ def config_from_args(args):
             network=dataclasses.replace(cfg.network, **net_overrides)
         )
     return cfg
+
+
+def flush_cycles_line(cycles) -> str:
+    """The "host side:" line's account of the guard's flush cycles
+    (``PipelinedLoop.stats()["cycles"]``): the median of each part."""
+    if not cycles:
+        return ""
+    med = {k: statistics.median(c[k] for c in cycles)
+           for k in cycles[0] if k.endswith("_ms")}
+    return (
+        f"; flush cycle, median of {len(cycles)}: chip without work "
+        f"{med['idle_ms']:.0f} ms (drain {med['drain_ms']:.0f}, aux get "
+        f"{med['aux_get_ms']:.0f}, check {med['check_ms']:.0f}, snapshot get "
+        f"{med['snapshot_get_ms']:.0f} + copy {med['snapshot_copy_ms']:.0f}, "
+        f"resume {med['resume_ms']:.0f} ms)"
+    )
 
 
 def train_net(args, report=None):
@@ -578,12 +595,13 @@ def train_net(args, report=None):
         logger.info(
             "host side: feed starved %d of %d gets after the first (waited "
             "%.2fs), assembly pool starved %d of %d (waited %.2fs), %d state "
-            "snapshot(s) %.0f ms, %d fetch stall(s) %.0f ms",
+            "snapshot(s) %.0f ms, %d fetch stall(s) %.0f ms%s",
             feed_totals["feed_starved_after_first"], feed_totals["fed"],
             feed_totals["wait_s"], loader_totals["starved_after_first"],
             loader_totals["yielded"], loader_totals["wait_s"],
             pipe_stats["snapshots"], pipe_stats["snapshot_ms"],
             pipe_stats["fetch_stalls"], pipe_stats["fetch_stall_ms"],
+            flush_cycles_line(pipe_stats["cycles"]),
         )
         if roi_level_totals:
             logger.info(

@@ -28,6 +28,7 @@ FEED_WAIT = "rcnn.feed.wait"              # train loop starved by the feed
 STEP_DISPATCH = "rcnn.step.dispatch"      # PipelinedLoop: one step_fn call
 GUARD_SNAPSHOT = "rcnn.guard.snapshot"    # host_copy(state) of a window
 GUARD_FETCH = "rcnn.guard.fetch"          # batched aux device_get
+GUARD_FLUSH = "rcnn.guard.flush"          # all of a flush: fetch .. snapshot
 
 # serving, host side
 SERVE_PREPARE = "rcnn.serve.prepare"          # submit, caller's thread
@@ -41,11 +42,7 @@ SERVE_POSTPROCESS = "rcnn.serve.postprocess"  # detections_for .. resolve
 
 TRAIN_SPANS = (
     LOADER_ASSEMBLE, LOADER_WAIT, FEED_PLACE, FEED_WAIT, STEP_DISPATCH,
-    GUARD_SNAPSHOT, GUARD_FETCH,
-)
-SERVE_SPANS = (
-    SERVE_PREPARE, SERVE_BATCH_WAIT, SERVE_PICKUP, SERVE_ASSEMBLE,
-    SERVE_SLOT_WAIT, SERVE_DISPATCH, SERVE_FETCH, SERVE_POSTPROCESS,
+    GUARD_SNAPSHOT, GUARD_FETCH, GUARD_FLUSH,
 )
 
 # device side: jax.named_scope components (metadata only).  ``backbone``,

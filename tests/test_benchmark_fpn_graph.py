@@ -20,3 +20,6 @@ test_fpn_graph.FPN_METRICS.add("roi_align_p3_device_ms.train")
 # ``workloads`` list (both model families open the scope), so it attaches
 # to every train cell like the accepted unlisted ``.train`` metrics.
 test_fpn_graph.UNLISTED_TRAIN.add("anchor_targets_device_ms.train")
+# ``guard_flush_idle_ms.train`` reads the guard's flush cycles and has
+# no ``workloads`` list either.
+test_fpn_graph.UNLISTED_TRAIN.add("guard_flush_idle_ms.train")

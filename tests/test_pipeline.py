@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mx_rcnn_tpu.core import pipeline as pipeline_mod
 from mx_rcnn_tpu.core.pipeline import (
     AsyncAuxSink,
     DeviceFeed,
@@ -353,6 +354,67 @@ def test_aux_sink_counts_stalls():
     assert sink.fetches == 1 and sink.fetched_trees == 1
     assert sink.fetch([]) == []
     assert sink.fetches == 1  # empty fetch not counted
+    stalled, ready_at, got_at = sink.last_fetch
+    assert not stalled and ready_at <= got_at
+
+
+# ------------------------------------------------------- flush cycle records
+AFTER_DRAIN = ("aux_get_ms", "check_ms", "snapshot_get_ms",
+               "snapshot_copy_ms", "resume_ms")
+
+
+@pytest.mark.parametrize("stalled", [False, True])
+def test_one_record_per_completed_flush_cycle(monkeypatch, stalled):
+    """10 steps at K=3: the flushes of windows 0, 3 and 6 are each
+    followed by a dispatch and filed; the closing flush (window 9) and the
+    head-of-stream snapshot are not.  The parts follow each other, so
+    they sum to ``idle_ms``: from the drain's end where a result was still
+    being computed, from the flush's start (the drain included) where
+    none was.  The chip has no work at least while the state is copied."""
+    monkeypatch.setattr(AsyncAuxSink, "_ready",
+                        staticmethod(lambda trees: not stalled))
+    _state, ready, loop, _ = run_pipelined(toy_batches(10), k=3)
+    assert [i for i, _ in ready] == list(range(10))
+    assert loop.flushes == 4 and loop.snapshots == 5
+    cycles = loop.stats()["cycles"]
+    assert [(c["step"], c["n"], c["stalled"]) for c in cycles] == [
+        (0, 3, stalled), (3, 3, stalled), (6, 3, stalled)]
+    for c in cycles:
+        parts = [c[k] for k in AFTER_DRAIN]
+        if not stalled:
+            parts.append(c["drain_ms"])
+        assert min(parts) >= 0
+        assert sum(parts) == pytest.approx(c["idle_ms"], abs=0.01)
+        assert c["idle_ms"] >= c["snapshot_get_ms"] + c["snapshot_copy_ms"]
+
+
+def test_rolled_back_flush_files_no_cycle(monkeypatch):
+    """nan_loss@4 under K=3: the window of steps 3-5 rolls back and is
+    the guard's recovery (``window_rollbacks``), not a flush cycle."""
+    monkeypatch.setenv("MX_RCNN_FAULTS", "nan_loss@4")
+    _state, ready, loop, _ = run_pipelined(toy_batches(10), k=3)
+    assert loop.window_rollbacks == 1 and loop.skipped_batches == 1
+    assert [i for i, _ in ready] == [0, 1, 2, 3, 5, 6, 7, 8, 9]
+    assert [c["step"] for c in loop.stats()["cycles"]] == [0, 6]
+
+
+def test_cycle_records_are_bounded(monkeypatch):
+    monkeypatch.setattr(pipeline_mod, "CYCLE_RECORDS", 2)
+    _state, _ready, loop, _ = run_pipelined(toy_batches(10), k=2)
+    assert loop.flushes == 5
+    assert [c["step"] for c in loop.stats()["cycles"]] == [4, 6]
+
+
+def test_rewind_drops_the_open_cycle():
+    """After a device fault the elastic loop rewinds: the flush before it
+    has no next dispatch on the same lineage, so it is not filed."""
+    loop = PipelinedLoop(make_toy_step(), aux_interval=2)
+    state, rng = fresh_state(), jax.random.key(0)
+    for b in toy_batches(2):
+        state, _ready, _ok = loop.step(state, b, rng)
+    loop.rewind(2)
+    state, _ready, _ok = loop.step(state, toy_batches(3)[2], rng)
+    assert loop.stats()["cycles"] == []
 
 
 # ------------------------------------------------------- render cache (LRU)

@@ -119,7 +119,8 @@ def test_step_dispatch_carries_consecutive_steps(traced_train):
 
 @pytest.mark.parametrize("key,fields", [
     ("feed", ("fed", "feed_starved_after_first", "wait_s")),
-    ("pipeline", ("snapshots", "snapshot_ms", "fetch_stall_ms", "flushes")),
+    ("pipeline", ("snapshots", "snapshot_ms", "fetch_stall_ms", "flushes",
+                  "cycles")),
     ("loader", ("workers", "submitted", "wait_s")),
 ])
 def test_report_carries_the_host_sides_counters(traced_train, key, fields):
@@ -134,8 +135,25 @@ def test_counters_agree_with_the_spans_they_sit_beside(traced_train):
     # interval 2 over 6 steps: the head-of-stream snapshot and one a flush
     assert pipe["snapshots"] == len(spans[tracing.GUARD_SNAPSHOT]) == 4
     assert pipe["fetches"] == len(spans[tracing.GUARD_FETCH]) == 3
+    assert pipe["flushes"] == len(spans[tracing.GUARD_FLUSH]) == 3
     assert report["feed"]["feed_starved"] == len(spans[tracing.FEED_WAIT])
     assert report["feed"]["fed"] == 6 <= len(spans[tracing.FEED_PLACE])
+
+
+def test_flush_spans_join_the_cycle_records(traced_train):
+    """A flush span's ``step`` is its window's first index, the key of
+    the host's record of the cycle; the closing flush (steps 4-5, no
+    dispatch after it) has a span and no record."""
+    spans, report = traced_train
+    flushes = sorted((ids["step"], ids["n"]) for _t, _s, ids in
+                     spans[tracing.GUARD_FLUSH])
+    assert flushes == [(0, 2), (2, 2), (4, 2)]
+    cycles = report["pipeline"]["cycles"]
+    assert [(c["step"], c["n"]) for c in cycles] == flushes[:-1]
+    assert all(c["idle_ms"] >= c["snapshot_get_ms"] + c["snapshot_copy_ms"]
+               for c in cycles)
+    assert {t for t, _s, _i in spans[tracing.GUARD_FLUSH]} == {
+        t for t, _s, _i in spans[tracing.STEP_DISPATCH]}
 
 
 # -------------------------------------------------------- (b) serving spans
