@@ -75,9 +75,14 @@ TRAIN_VGG_BF16_ARGV = [
 VGG_STEP_KERNELS = (
     "pallas_nms_mask", "pallas_roi_pool_fwd", "pallas_roi_pool_bwd",
 )
+#: Pallas kernels the compiled DCN step must hold: the proposal NMS and
+#: the deformable convolution's pair (no ``lax.map`` loop in its place)
+DCN_STEP_KERNELS = (
+    "pallas_nms_mask", "pallas_deform_conv_fwd", "pallas_deform_conv_bwd",
+)
 #: Deformable ConvNets (Faster R-CNN ResNet-101 on VOC) as the cell
-#: dcn_train_b8 runs it: the deformable conv5 and the deformable ROI
-#: pooling, forward and differentiated, in plain jnp
+#: dcn_train_b8 runs it: the deformable conv5 through the Pallas pair and
+#: the deformable ROI pooling as products, forward and differentiated
 TRAIN_DCN_BF16_ARGV = [
     "--network", "resnet_dcn", "--dataset", "PascalVOC", "--synthetic", "64",
     "--epochs", "1", "--frequent", "1", "--lr", "1e-05",
@@ -472,7 +477,13 @@ def train_phase(argv, name: str = "train", kernels=(), scopes=()):
         # inside the map, as a share
         deform_inside={k[len("deform_inside_"):]: v / deform["deform_points"]
                        for k, v in deform.items()
-                       if k.startswith("deform_inside_")})
+                       if k.startswith("deform_inside_")},
+        # and the chunks their bands read over the zero-offset count
+        deform_window={
+            k[len("deform_window_chunks_"):]:
+                v / deform["deform_window_chunks_min"]
+            for k, v in deform.items()
+            if k.startswith("deform_window_chunks_u")})
     if not all(held.values()):
         raise RuntimeError(f"{name}: compiled step holds {held}")
     if kernels and not max(losses) <= 2 * losses[0]:
@@ -775,7 +786,7 @@ def main(argv=None) -> int:
                   "train_vgg_bf16_b8", kernels=VGG_STEP_KERNELS,
                   scopes=("roi_pool",))
             phase(train_phase, TRAIN_DCN_BF16_ARGV + prefix("dcn_bf16"),
-                  "train_dcn_bf16_b8", kernels=("pallas_nms_mask",),
+                  "train_dcn_bf16_b8", kernels=DCN_STEP_KERNELS,
                   scopes=("deform_conv", "deform_roi_pool"))
             phase(serve_phase, SERVE_ARGV, "serve")
 

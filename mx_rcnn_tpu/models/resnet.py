@@ -28,7 +28,11 @@ from mx_rcnn_tpu.models.layers import (
     conv,
     make_conv_bn,
 )
-from mx_rcnn_tpu.ops.deform_conv import TAPS, deform_conv, inside_count
+from mx_rcnn_tpu.ops.deform_conv import (
+    TAPS,
+    deform_conv_counted,
+    inside_count,
+)
 
 _BLOCKS = {50: (3, 4, 6, 3), 101: (3, 4, 23, 3), 152: (3, 8, 36, 3)}
 
@@ -211,7 +215,8 @@ class DeformBottleneck(nn.Module):
     """Deformable ConvNets' conv5 unit: a stride-1 :class:`Bottleneck`
     whose 3×3 is deformable (``ops/deform_conv.py``), dilated by
     ``DCN_DILATION``, its offsets from a 3×3 convolution of the same input
-    (``conv2_offset``) → ``(y, points of the 3×3 inside the map)``."""
+    (``conv2_offset``) → ``(y, points of the 3×3 inside the map, chunks
+    the Pallas pair's bands read)``."""
 
     filters: int
     dtype: Any = jnp.float32
@@ -231,19 +236,21 @@ class DeformBottleneck(nn.Module):
                 kernel_init=offset_init())(y)
             kernel = _ConvKernel(self.filters, 3, name="conv2")(y.shape[-1])
             inside = inside_count(offsets, DCN_DILATION, DCN_GROUPS)
-            y = deform_conv(y, offsets, kernel.astype(self.dtype),
-                            DCN_DILATION, DCN_GROUPS)
+            y, chunks = deform_conv_counted(
+                y, offsets, kernel.astype(self.dtype), DCN_DILATION,
+                DCN_GROUPS)
         y = nn.relu(FrozenBatchNorm(dtype=self.dtype, name="bn2")(y))
         y = cbn(y, self.filters * 4, 1, 1, "conv3", "bn3")
         residual = x
         if residual.shape != y.shape:
             residual = cbn(x, self.filters * 4, 1, 1, "sc", "sc_bn")
-        return nn.relu(y + residual), inside
+        return nn.relu(y + residual), inside, chunks
 
 
 class DCNConv5(nn.Module):
     """Deformable ConvNets' ``stage4``: :class:`DeformBottleneck` units on
-    the whole map → ``(y, [points inside the map, a unit])``."""
+    the whole map → ``(y, [points inside the map, a unit], [chunks the
+    bands read, a unit])``."""
 
     num_units: int
     dtype: Any = jnp.float32
@@ -251,12 +258,13 @@ class DCNConv5(nn.Module):
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, pad_mask=None):
-        inside = []
+        inside, chunks = [], []
         for i in range(self.num_units):
-            x, n = DeformBottleneck(512, self.dtype, self.fold_bn,
-                                    name=f"unit{i + 1}")(x, pad_mask)
+            x, n, c = DeformBottleneck(512, self.dtype, self.fold_bn,
+                                       name=f"unit{i + 1}")(x, pad_mask)
             inside.append(n)
-        return x, inside
+            chunks.append(c)
+        return x, inside, chunks
 
 
 class DCNBackbone(nn.Module):
@@ -265,7 +273,9 @@ class DCNBackbone(nn.Module):
     then :class:`DCNConv5` on the C4 map at stride 1 and ``conv_new_1``
     2048 → ``DCN_CHANNELS`` + ReLU → (C4 for the RPN, that map for the roi
     pooling, counters for ``aux``: each deformable layer's points inside
-    the map as ``deform_inside_u<i>``, of ``deform_points`` a layer)."""
+    the map as ``deform_inside_u<i>``, of ``deform_points`` a layer, and
+    the chunks its bands read as ``deform_window_chunks_u<i>``, of
+    ``deform_window_chunks_min`` at zero offsets)."""
 
     depth: int = 101
     dtype: Any = jnp.float32
@@ -274,19 +284,28 @@ class DCNBackbone(nn.Module):
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, pad_mask=None):
+        from mx_rcnn_tpu.ops.pallas.deform_conv import window_chunks_min
+
         trunk = ResNetBackbone(self.depth, self.dtype,
                                frozen_prefix=self.frozen_prefix,
                                fold_bn=self.fold_bn, name="trunk")
         nn.share_scope(self, trunk)
         c4 = trunk(x, pad_mask)
-        c5, inside = DCNConv5(_BLOCKS[self.depth][3], self.dtype,
-                              self.fold_bn, name="stage4")(c4, pad_mask)
+        conv5 = DCNConv5(_BLOCKS[self.depth][3], self.dtype, self.fold_bn,
+                         name="stage4")
+        c5, inside, chunks = conv5(c4, pad_mask)
         feat = conv(DCN_CHANNELS, 1, 1, self.dtype, name="conv_new_1",
                     use_bias=True, kernel_init=nn.initializers.normal(0.01))(c5)
         counts = {f"deform_inside_u{i + 1}": n for i, n in enumerate(inside)}
-        # the points a layer samples: the constant beside those counts
+        counts.update({f"deform_window_chunks_u{i + 1}": n
+                       for i, n in enumerate(chunks)})
+        # the points a layer samples, and the chunks its bands would read
+        # at zero offsets: the constants beside those counts
         counts["deform_points"] = jnp.asarray(
             c5.shape[0] * c5.shape[1] * c5.shape[2] * len(TAPS) * DCN_GROUPS,
+            jnp.int32)
+        counts["deform_window_chunks_min"] = jnp.asarray(
+            window_chunks_min(*c5.shape[:3], DCN_DILATION, DCN_GROUPS),
             jnp.int32)
         return c4, nn.relu(feat), counts
 

@@ -1,5 +1,6 @@
 """Deformable convolution, v1 (Dai et al., "Deformable Convolutional
-Networks", ICCV 2017, arXiv:1703.06211 §2.1), in plain jnp.
+Networks", ICCV 2017, arXiv:1703.06211 §2.1): a Pallas pair on a TPU,
+plain jnp elsewhere.
 
 A 3×3 convolution whose taps read the map at positions the network
 computes:
@@ -25,17 +26,31 @@ the operator of the public ``msracver/Deformable-ConvNets``):
   edge row (or column) itself.  With every offset 0 this is
   ``lax.conv_general_dilated`` with ``rhs_dilation`` ``d`` and padding
   ``d``.
-- **Gradients** come from ``jax.grad`` through the bilinear weights: with
-  respect to the map (a scatter-add of the weighted columns), the kernel,
-  and the offsets (the weights' slopes; zero where a point is outside the
-  map or on the clamped edge, as in MXNet's backward).
+- **Gradients** are ``jax.grad``'s through the bilinear weights: with
+  respect to the map (the weighted columns sent back to their corners),
+  the kernel, and the offsets (the weights' slopes; zero where a point is
+  outside the map or on the clamped edge, as in MXNet's backward).
 
-The form: the four corners' clamped indices and bilinear weights for every
-(position, tap, group), the corners gathered as rows of the map, the
-weighted ``(H·W, 9·C)`` columns formed in float32 and cast to the
-compute dtype, then one product with the ``(9·C, Cout)`` kernel; one
-image after the other, each image's columns recomputed in the backward
-pass.
+Two forms compute the same columns — every (position, tap, group)'s
+bilinear sample, ``(H·W, 9·C)`` in float32, cast to the compute dtype —
+and then one product with the ``(9·C, Cout)`` kernel:
+
+- **The Pallas pair** (``ops/pallas/deform_conv.py``) runs where
+  ``deform_conv_kernel`` holds: on a TPU (``use_pallas``), for a map of
+  whole 128-channel groups whose one group, with its float32 gradient,
+  fits the pair's VMEM budget (the pair pads each map row to a multiple
+  of 16 positions with zero columns, so both orientation buckets' conv5
+  maps, 38 × 64 and 64 × 38, take it).  The points are this module's
+  (``sample_points``, clipped to the map, ``inside_map``); each image's
+  map stays in VMEM, the columns
+  are products of bilinear weights with bands of map rows on the MXU,
+  and the backward accumulates the map's gradient there; the columns are
+  kept from the forward for the kernel's gradient.
+- **The jnp form** runs everywhere else, and is the pair's reference:
+  the four corners' clamped indices and bilinear weights, the corners
+  gathered as rows of the map, one image after the other under
+  ``lax.map``, each image's columns recomputed in the backward pass, the
+  map's gradient a scatter-add of the weighted columns.
 
 Training reads the whole canvas.  A forward-only caller that serves
 images padded into a shape bucket would need the valid extent
@@ -81,11 +96,38 @@ def inside_count(offsets: jnp.ndarray, dilation: int, groups: int):
     return inside_map(y, x, offsets.shape[1:3]).sum()
 
 
+def deform_conv_kernel(x: jnp.ndarray, groups: int) -> bool:
+    """Whether the Pallas pair (``ops/pallas/deform_conv.py``) forms this
+    (B, H, W, C) map's columns: on a TPU, and the map within the pair's
+    own shapes and VMEM bound.  Elsewhere the jnp form does."""
+    from mx_rcnn_tpu.ops.pallas.deform_conv import fits
+    from mx_rcnn_tpu.utils.platform import use_pallas
+
+    return use_pallas() and fits(*x.shape[1:], groups, x.dtype.itemsize)
+
+
 def deform_conv(x: jnp.ndarray, offsets: jnp.ndarray, kernel: jnp.ndarray,
                 dilation: int, groups: int) -> jnp.ndarray:
     """(B, H, W, C) map, (B, H, W, 2·9·G) offsets, (3, 3, C, Cout) kernel
     → (B, H, W, Cout) in the kernel's dtype (the module docstring has the
-    semantics).  ``C`` must divide by ``groups``."""
+    semantics and the two forms).  ``C`` must divide by ``groups``."""
+    return deform_conv_counted(x, offsets, kernel, dilation, groups)[0]
+
+
+def deform_conv_counted(x: jnp.ndarray, offsets: jnp.ndarray,
+                        kernel: jnp.ndarray, dilation: int, groups: int):
+    """:func:`deform_conv` → (y, the chunks of map rows the Pallas pair's
+    bands read, int32: from the pair's own bands where it runs, what they
+    would read where the jnp form runs)."""
+    from mx_rcnn_tpu.ops.pallas import deform_conv as pair
+
+    if deform_conv_kernel(x, groups):
+        return pair.deform_conv_pallas(x, offsets, kernel, dilation, groups)
+    return (_deform_conv_jnp(x, offsets, kernel, dilation, groups),
+            pair.window_chunks(offsets, dilation, groups))
+
+
+def _deform_conv_jnp(x, offsets, kernel, dilation: int, groups: int):
     _b, h, w, c = x.shape
     k = kernel.reshape(len(TAPS) * c, kernel.shape[-1])
 

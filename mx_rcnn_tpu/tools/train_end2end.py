@@ -208,7 +208,9 @@ def train_net(args, report=None):
     ``roi_steps_p2`` ..; summed over the fetched steps; empty otherwise),
     ``deform`` (Deformable ConvNets: each deformable layer's sampling
     points inside the map, ``deform_inside_u1`` .., of ``deform_points``
-    a layer, and the second pooling pass's bins that kept no sample,
+    a layer, the chunks of map rows its bands read,
+    ``deform_window_chunks_u1`` .., of ``deform_window_chunks_min`` at
+    zero offsets, and the second pooling pass's bins that kept no sample,
     ``deform_pool_empty_bins`` of ``deform_pool_bins``; summed over the
     fetched steps; empty otherwise) and on an elastic run ``elastic`` /
     ``degraded``."""
@@ -627,12 +629,18 @@ def train_net(args, report=None):
             d = deform_totals
             logger.info(
                 "host side: deformable sampling points inside the map: %s; "
+                "window chunks over their zero-offset count: %s; "
                 "pooled bins with no sample %.0f of %.0f (%.1f%%)",
                 ", ".join(
                     f"{k[len('deform_inside_'):]} "
                     f"{v / d['deform_points']:.1%}"
                     for k, v in sorted(d.items())
                     if k.startswith("deform_inside_")),
+                ", ".join(
+                    f"{k[len('deform_window_chunks_'):]} "
+                    f"{v / d['deform_window_chunks_min']:.3f}"
+                    for k, v in sorted(d.items())
+                    if k.startswith("deform_window_chunks_u")),
                 d["deform_pool_empty_bins"], d["deform_pool_bins"],
                 100.0 * d["deform_pool_empty_bins"] / d["deform_pool_bins"],
             )

@@ -24,6 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from mx_rcnn_tpu.ops.nms import batched_class_nms
+from mx_rcnn_tpu.ops.pallas.deform_conv import deform_conv_pallas
 from mx_rcnn_tpu.ops.pallas.nms import nms_mask_sorted_pallas
 from mx_rcnn_tpu.ops.pallas.roi_align import fits_vmem, roi_align_pallas
 from mx_rcnn_tpu.ops.pallas.roi_align_stream import roi_align_stream
@@ -239,6 +240,32 @@ def test_vgg_step_holds_the_roi_pool_pair_and_no_sweep(one_chip, monkeypatch):
     loops = [line[:80] for line in text.splitlines()
              if " while(" in line and "/roi_pool/" in line]
     assert not loops, loops
+
+
+@pytest.mark.parametrize("hw", [(38, 64), (64, 38)],
+                         ids=["landscape", "portrait"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_deform_conv_pair_compiles(one_chip, dtype, hw):
+    """The deformable convolution's pair at the DCN train step's conv5
+    (cell ``dcn_train_b8``): eight 38x64x512 maps, and the portrait
+    bucket's 64x38 (laid out 48 wide), 4 groups, dilation 2,
+    differentiated in the map, the offsets and the kernel.  Dynamic row
+    slices of the resident map, a product with a transposed operand and
+    the scalar-prefetched bands are what Mosaic could refuse."""
+    def fwd_bwd(x, off, k):
+        def loss(a, o, kk):
+            return deform_conv_pallas(a, o, kk, 2, 4)[0].astype(
+                jnp.float32).sum()
+
+        return jax.value_and_grad(loss, (0, 1, 2))(x, off, k)
+
+    text = _compiled_text(
+        fwd_bwd, one_chip, ((8, *hw, 512), dtype), ((8, *hw, 72), dtype),
+        ((3, 3, 512, 512), dtype),
+    )
+    assert "pallas_deform_conv_fwd" in text and "pallas_deform_conv_bwd" in text
+    assert text.count("tpu_custom_call") == 2
 
 
 def test_pyramid_top_k_compiles_at_batch_one(one_chip):

@@ -165,6 +165,13 @@ def test_dcn_phase_asks_for_the_cell_s_configuration():
     assert chip_smoke.TRAIN_DCN_BF16_ARGV[:4] == cell_argv
 
 
+def test_dcn_phase_holds_the_deform_conv_pair():
+    """The DCN phase fails where its compiled step stops calling the
+    deformable convolution's pair (or the NMS)."""
+    assert chip_smoke.DCN_STEP_KERNELS == (
+        "pallas_nms_mask", "pallas_deform_conv_fwd", "pallas_deform_conv_bwd")
+
+
 def test_streaming_train_shapes_are_the_pyramid_s_p2_and_p3():
     from mx_rcnn_tpu.ops.pallas.roi_align import fits_vmem
 

@@ -173,6 +173,28 @@ class TestTheGraph:
         assert aux["deform_pool_bins"] == RB * 16 * 49
         assert 0 <= aux["deform_pool_empty_bins"] < aux["deform_pool_bins"]
 
+    def test_the_window_counters(self, program_model, program_side):
+        """Each layer's chunks of map rows, ``deform_window_chunks_u<i>``,
+        beside ``deform_window_chunks_min``; with the offset layers zeroed
+        every layer reads the zero-offset count exactly."""
+        _loss, aux, _grads = program_side
+        assert aux["deform_window_chunks_min"] > 0
+        for u in (1, 2, 3):
+            assert aux[f"deform_window_chunks_u{u}"] > 0
+        model, params = program_model
+        zeroed = jax.tree_util.tree_map_with_path(
+            lambda path, v: (jnp.zeros_like(v)
+                             if "conv2_offset" in jax.tree_util.keystr(path)
+                             else v), params)
+        _loss0, aux0 = jax.jit(lambda p: model.apply(
+            {"params": p}, train=True, rngs={"sampling": jax.random.key(9)},
+            **_batch()))(zeroed)
+        assert int(aux0["deform_window_chunks_min"]) == int(
+            aux["deform_window_chunks_min"])
+        for u in (1, 2, 3):
+            assert int(aux0[f"deform_window_chunks_u{u}"]) == int(
+                aux0["deform_window_chunks_min"])
+
     def test_test_forward_runs(self, program_model):
         model, params = program_model
         batch = _batch()
@@ -260,9 +282,9 @@ class TestDCNAgainstItsReference:
         """The program with every offset forced to 0 (a plain dilated
         conv5 and a fixed-grid pooling): the offset layers' gradients
         vanish, so their leaves read a gap of 1."""
-        conv = program_resnet.deform_conv
+        conv = program_resnet.deform_conv_counted
         pool = program_frcnn.deform_roi_pool_batched
-        monkeypatch.setattr(program_resnet, "deform_conv",
+        monkeypatch.setattr(program_resnet, "deform_conv_counted",
                             lambda x, o, k, d, g: conv(x, o * 0, k, d, g))
         monkeypatch.setattr(program_frcnn, "deform_roi_pool_batched",
                             lambda *a, **kw: pool(*a, **dict(kw, offsets=None)))

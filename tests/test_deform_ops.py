@@ -27,6 +27,7 @@ import numpy as np
 import pytest
 
 from mx_rcnn_tpu.ops.deform_conv import deform_conv, inside_count
+from mx_rcnn_tpu.ops.pallas import deform_conv as pallas_deform_conv
 from mx_rcnn_tpu.ops.deform_roi_pool import (deform_roi_pool,
                                              deform_roi_pool_batched,
                                              empty_bins, sample_grid)
@@ -327,6 +328,74 @@ def test_the_in_map_counter():
     _x, off, _k = _conv_inputs("off_map", seed=9)
     assert int(inside_count(jnp.asarray(off, jnp.float32), D, G)) == (
         loop_inside(off, H, W))
+
+
+def loop_window_chunks(off, h, w, d=D, groups=G):
+    """The chunks the Pallas pair's bands read, point by point: for every
+    image, group, block of the pair's positions (row-major, the last one
+    short) and tap, the kept points' corner rows from the first to the
+    last, in chunks of the pair's rows."""
+    block, rows = pallas_deform_conv._P, pallas_deform_conv._CR
+    total = 0
+    for n in range(off.shape[0]):
+        for g in range(groups):
+            for first in range(0, h * w, block):
+                for i in range(3):
+                    for j in range(3):
+                        ch = 2 * (9 * g + 3 * i + j)
+                        corners = []
+                        for pos in range(first, min(first + block, h * w)):
+                            r, s = divmod(pos, w)
+                            y = r - d + i * d + off[n, r, s, ch]
+                            xx = s - d + j * d + off[n, r, s, ch + 1]
+                            if 0 <= y < h and 0 <= xx < w:
+                                y0 = min(math.floor(y), h - 1)
+                                corners += [y0, min(y0 + 1, h - 1)]
+                        if corners:
+                            total += math.ceil(
+                                (max(corners) - min(corners) + 1) / rows)
+    return total
+
+
+def _window_chunks(off):
+    return int(pallas_deform_conv.window_chunks(
+        jnp.asarray(off, jnp.float32), D, G))
+
+
+def test_the_window_chunk_counter():
+    """At zero offsets the count is ``deform_window_chunks_min``, as the
+    loop counts it.  Every offset of a block's first output row moved up
+    by ``n`` whole rows and of its last row down by ``n`` widens the
+    block's bands by ``2n`` rows, and the count rises by what the loop
+    counts, ``2n`` rows' worth of chunks on every inner band at least;
+    every offset moved by ``n`` rows, and a random field, as the loop
+    counts them."""
+    h, w = 24, 16                       # three blocks of eight map rows
+    zero = np.zeros((1, h, w, 2 * 9 * G))
+    at_zero = _window_chunks(zero)
+    assert at_zero == loop_window_chunks(zero, h, w)
+    rows, per = pallas_deform_conv._CR, pallas_deform_conv._P // w
+    edge = np.arange(h) % per
+    sign = ((edge == per - 1).astype(float) - (edge == 0))[None, :, None,
+                                                             None]
+    for n in (1, 2, 4):
+        moved = zero.copy()
+        moved[..., 0::2] = n * sign
+        count = _window_chunks(moved)
+        assert count == loop_window_chunks(moved, h, w)
+        assert count > at_zero
+        # the middle block, middle tap row: its rows + 1 at zero offsets,
+        # 2n more when moved, for each of the row's 3 taps and G groups
+        inner = 3 * G * (math.ceil((per + 1 + 2 * n) / rows)
+                         - math.ceil((per + 1) / rows))
+        assert count - at_zero >= inner
+        # every offset moved the same n rows: the bands move, and change
+        # only where they meet the map's border, as the loop counts
+        down = zero.copy()
+        down[..., 0::2] = n
+        assert _window_chunks(down) == loop_window_chunks(down, h, w)
+    _x, off, _k = _conv_inputs("off_map", seed=9)
+    assert _window_chunks(off) == loop_window_chunks(off, H, W)
 
 
 # ------------------------------------------------- deformable ROI pooling

@@ -373,6 +373,51 @@ def test_deformable_layers_and_pooling_open_their_scopes():
             assert json.load(f)["args"]["scope"] in tracing.DCN_SCOPES
 
 
+def _kernel_paths(lowered_text, kernel):
+    """The scoped names of every call of the Pallas kernel ``kernel``:
+    inside a private function, the name of each call into it joined with
+    the kernel's own, as the compiled step's op names join them; in the
+    step's own body, the kernel's name as it stands."""
+    locs = dict(re.findall(r'(#loc\d+) = loc\("([^"]+)"', lowered_text))
+    own, inline, fn = {}, [], None
+    for line in lowered_text.splitlines():
+        head = re.match(r"\s*func\.func (?:\w+ )?@([\w.]+)\(", line)
+        fn = head.group(1) if head else fn
+        call = re.search(r"@tpu_custom_call\(.*loc\((#loc\d+)\)$", line)
+        name = locs[call.group(1)] if call else ""
+        if name.startswith(kernel + "/"):
+            own.setdefault(fn, []).append(name)
+        elif f"/{kernel}/" in name:
+            inline.append(name)
+    joined = [
+        f"{locs[site]}/{name}"
+        for f, names in own.items()
+        for site in re.findall(rf"call @{re.escape(f)}\(.*loc\((#loc\d+)\)$",
+                               lowered_text, re.M)
+        for name in names]
+    return sorted(path for name in joined + inline
+                  for path in _scoped_names(f'loc("{name}")'))
+
+
+def test_the_deformable_pair_lies_under_deform_conv():
+    """Where conv5's map fits the Pallas pair (a 64 × 256 canvas: a 4 × 16
+    map of 512 channels), each layer's forward and backward kernel lie
+    inside its ``deform_conv`` scope, which ``deform_conv_device_ms.train``
+    reads (the compiled step joins the two paths: ``…/unit<i>/deform_conv/
+    jit(deform_conv_pallas)/pallas_deform_conv_fwd/…``)."""
+    cfg = _tiny_generate_config("resnet_dcn", "PascalVOC")
+    cfg = cfg.replace(SHAPE_BUCKETS=((64, 256),), dataset=dataclasses.replace(
+        cfg.dataset, SCALES=((64, 256),)))
+    text = _lowered_step(cfg)
+    for kernel in ("pallas_deform_conv_fwd", "pallas_deform_conv_bwd"):
+        paths = _kernel_paths(text, kernel)
+        units = [re.search(r"/backbone/stage4/(unit\d)/(?:[^/]+/)*deform_conv/",
+                           p) for p in paths]
+        assert all(units), (kernel, paths)
+        assert sorted(u.group(1) for u in units) == [
+            "unit1", "unit2", "unit3"], (kernel, paths)
+
+
 @pytest.mark.parametrize("scope", tracing.TRAIN_SCOPES)
 def test_train_step_names_the_scope(lowered_train_step, scope):
     assert any(f"/{scope}/" in n + "/" for n in
